@@ -17,7 +17,7 @@ after every case.  The verdict field names the winner per S — the data
 that either flips SeqAttention=auto to flash in a measured regime or
 formally demotes the kernels to reference status.
 
-Run (the watcher battery does): python scripts/bench_flash_sweep.py
+Run: python scripts/bench_flash_sweep.py
 """
 
 from __future__ import annotations
@@ -33,11 +33,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
                       os.path.join(REPO, ".jax_cache"))
-
-if os.environ.get("JAX_PLATFORMS", "").strip() == "cpu":
-    from shifu_tensorflow_tpu.utils.jaxenv import force_cpu_backend
-
-    force_cpu_backend()
 
 SEQ_LENS = tuple(int(s) for s in os.environ.get(
     "FLASH_SWEEP_LENS", "4096,8192,16384").split(","))
@@ -90,7 +85,7 @@ def run_case(seq_len: int, variant: str) -> dict:
 
     gq, gk, gv = grad_step(q, k, v)
     true_sync(gq)
-    # value-fetch sync (docs/benchmarks.md "Measurement integrity"):
+    # value-fetch sync (utils/profiling.true_sync):
     # chain one element per rep so one final fetch proves all executed
     acc = jnp.zeros((), jnp.float32)
     t0 = time.perf_counter()
@@ -99,12 +94,15 @@ def run_case(seq_len: int, variant: str) -> dict:
         acc = acc + gq.reshape(-1)[0].astype(jnp.float32)
     true_sync(acc)
     dt = time.perf_counter() - t0
+    dev = jax.devices()[0]
     return {
         "seq_len": seq_len,
         "variant": variant,
         "batch": batch,
         "fwdbwd_per_sec": round(REPS / dt, 3),
         "tokens_per_sec": round(REPS * batch * seq_len / dt),
+        "platform": dev.platform,
+        "device": str(dev.device_kind),
     }
 
 
@@ -140,12 +138,12 @@ def main() -> None:
                     default=os.path.join(REPO, "BENCH_FLASH_SWEEP.json"))
     args = ap.parse_args()
 
-    import jax
-
-    dev = jax.devices()[0]
+    # the parent NEVER touches the device: a chip belongs to one
+    # process, and acquiring it here would starve every case
+    # subprocess.  platform/device come from the first successful case.
     artifact: dict = {
-        "platform": dev.platform,
-        "device": str(dev.device_kind),
+        "platform": "unknown",
+        "device": "unknown",
         "tokens_per_step": TOKENS,
         "heads": HEADS, "dim": DIM, "reps": REPS,
         "cases": [],
@@ -174,6 +172,9 @@ def main() -> None:
     for s in SEQ_LENS:
         for variant in VARIANTS:
             case = case_or_error(s, variant)
+            if artifact["platform"] == "unknown" and case.get("platform"):
+                artifact["platform"] = case["platform"]
+                artifact["device"] = case.get("device", "unknown")
             print(json.dumps(case), flush=True)
             artifact["cases"].append(case)
             flush()

@@ -1,4 +1,4 @@
-"""Host-side ingest measurements behind two docs/benchmarks.md claims.
+"""Host-side ingest measurements behind two design claims.
 
 No jax, no device — this isolates the HOST half of the streaming path so
 the numbers are reproducible on any machine:
@@ -39,7 +39,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 # the SAME generator the end-to-end bench uses, so this artifact measures
 # the identical workload (shard format, gzip level, block layout) and the
-# cross-artifact comparisons in docs/benchmarks.md stay valid
+# cross-artifact comparisons stay valid
 from bench import NUM_FEATURES, _write_stream_shards  # noqa: E402
 
 

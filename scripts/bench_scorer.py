@@ -24,11 +24,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-if os.environ.get("JAX_PLATFORMS", "").strip() == "cpu":
-    from shifu_tensorflow_tpu.utils.jaxenv import force_cpu_backend
-
-    force_cpu_backend()
-
 import numpy as np
 
 NUM_FEATURES = 30

@@ -103,7 +103,7 @@ PREFETCH_DEPTH = TPU_PREFIX + "prefetch-depth"
 DEFAULT_PREFETCH_DEPTH = 2
 # chunked-scan epochs: batches per lax.scan dispatch (1 = per-step path).
 # Amortizes per-step dispatch latency; worth raising when steps are much
-# shorter than dispatch (small models, tunneled/driven-from-Python hosts)
+# shorter than dispatch (small models driven from a Python loop)
 SCAN_STEPS = TPU_PREFIX + "scan-steps"
 DEFAULT_SCAN_STEPS = 1
 # gradient accumulation: microbatches per optimizer update (1 = off).
@@ -363,7 +363,10 @@ DEFAULT_EXPORT_AOT_ROWS = DEFAULT_SERVE_QUEUE_ROWS
 # jax persistent compilation cache dir — the middle tier of the AOT
 # fallback ladder (shipped executable -> this cache -> live compile): a
 # fingerprint-mismatched bucket that live-compiles populates it, so the
-# NEXT worker/restart on this host still skips XLA.  Empty = off.
+# NEXT worker/restart on this host still skips XLA.  The cache is always
+# on: JAX_COMPILATION_CACHE_DIR places it where set (this key is then
+# ignored), else this key, else <checkout>/.jax_cache
+# (obs/compile.py apply_persistent_cache).
 COMPILE_CACHE_DIR = TPU_PREFIX + "compile-cache-dir"
 DEFAULT_COMPILE_CACHE_DIR = ""
 

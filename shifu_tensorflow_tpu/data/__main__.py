@@ -183,9 +183,9 @@ def main(argv: list[str] | None = None) -> int:
     if args.cmd == "build":
         # the only subcommand that can touch jax (dataset internals);
         # status/prune stay jax-free and fast
-        from shifu_tensorflow_tpu.utils.jaxenv import honor_cpu_pin
+        from shifu_tensorflow_tpu.obs.compile import apply_persistent_cache
 
-        honor_cpu_pin()
+        apply_persistent_cache()
     return {"build": _build, "status": _status, "prune": _prune}[args.cmd](args)
 
 

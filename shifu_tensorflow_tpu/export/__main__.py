@@ -54,11 +54,10 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv: list[str] | None = None) -> int:
     args = build_parser().parse_args(argv)
-    # after parse_args (--help must not pay a jax import), before any
-    # jax-touching work
-    from shifu_tensorflow_tpu.utils.jaxenv import honor_cpu_pin
+    # after parse_args: --help must not pay for it
+    from shifu_tensorflow_tpu.obs.compile import apply_persistent_cache
 
-    honor_cpu_pin()
+    apply_persistent_cache()
     paths = list_data_files(args.data_path)
     if not paths:
         print(f"no data files under {args.data_path}", file=sys.stderr)

@@ -107,21 +107,15 @@ def compile_env_fingerprint(mesh_shape: str | None = None) -> dict:
     sharded — or unsharded — bundle).  Stamped into ``aot_meta.json``
     at export; compared at load."""
     import jax
+    import jaxlib
 
-    fp = {"jax": getattr(jax, "__version__", "?"),
-          "mesh_shape": mesh_shape or "unsharded"}
-    try:
-        import jaxlib
-
-        fp["jaxlib"] = getattr(jaxlib, "__version__", "?")
-    except Exception:
-        fp["jaxlib"] = "?"
-    try:
-        fp["backend"] = jax.default_backend()
-        fp["device_kind"] = jax.devices()[0].device_kind
-    except Exception:
-        fp["backend"] = fp["device_kind"] = "?"
-    return fp
+    # a process that cannot name its device raises here: an unknown
+    # device must never compare equal to another unknown device
+    return {"jax": jax.__version__,
+            "jaxlib": jaxlib.__version__,
+            "backend": jax.default_backend(),
+            "device_kind": jax.devices()[0].device_kind,
+            "mesh_shape": mesh_shape or "unsharded"}
 
 
 def fingerprint_mismatch(
@@ -364,10 +358,17 @@ class AotIndex:
         if (zlib.crc32(blob) & 0xFFFFFFFF) != int(entry.get("crc32", -1)):
             raise AotLoadError(f"{entry.get('file')}: CRC32 mismatch")
         try:
+            import jax
             from jax.experimental import serialize_executable as se
 
             payload, in_tree, out_tree = pickle.loads(blob)
-            return se.deserialize_and_load(payload, in_tree, out_tree)
+            # the scorer program is compiled for ONE device (the default
+            # one, where EvalModel puts the weights); left to its
+            # default, the loader binds it to every device of the host
+            # and the first call fails for want of one shard per device
+            return se.deserialize_and_load(
+                payload, in_tree, out_tree,
+                execution_devices=jax.local_devices()[:1])
         except AotLoadError:
             raise
         except Exception as e:

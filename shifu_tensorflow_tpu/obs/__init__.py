@@ -104,12 +104,11 @@ def install_obs(cfg: ObsConfig, *, worker_index: int | None = None,
     from shifu_tensorflow_tpu.obs import slo as slo_mod
     from shifu_tensorflow_tpu.obs import trace as trace_mod
 
-    # persistent compilation cache (shifu.tpu.compile-cache-dir): a
-    # compile-plane knob riding this config for the key-resolve + JSON
-    # bridge, applied regardless of whether observability itself is on
-    # (best-effort, no-op on jax-free hosts)
-    if getattr(cfg, "compile_cache_dir", ""):
-        compile_mod.apply_persistent_cache(cfg.compile_cache_dir)
+    # persistent compilation cache: placed for every plane, whether or
+    # not observability itself is on.  shifu.tpu.compile-cache-dir rides
+    # this config for the key-resolve + JSON bridge and only names the
+    # directory when JAX_COMPILATION_CACHE_DIR does not
+    compile_mod.apply_persistent_cache(cfg.compile_cache_dir)
     if not cfg.enabled:
         slo_mod.uninstall()
         compile_mod.uninstall()

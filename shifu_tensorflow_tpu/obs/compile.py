@@ -13,9 +13,10 @@ benchmarked.  This module is that leg.
 
 How a compilation is *detected*: jax publishes per-compile durations
 through ``jax.monitoring`` (``.../backend_compile_duration`` events fire
-once per XLA backend compile, and never on a dispatch-cache hit — probed
-on jax 0.4.37).  The recorder registers ONE process-wide listener; the
-instrumented seams (:func:`observe`-wrapped jitted callables,
+once per XLA backend compile — a persistent-cache hit included, which
+additionally fires ``.../compilation_cache/cache_hits`` — and never on a
+dispatch-cache hit; jax 0.9.0).  The recorder registers ONE
+process-wide listener pair; the instrumented seams (:func:`observe`-wrapped jitted callables,
 :func:`attribute` regions around Pallas entry points) push a
 thread-local attribution frame around each call, so whatever the
 listener hears lands on the *named callable that caused it*.  A call
@@ -29,6 +30,8 @@ and a list push/pop; a call that DID compile additionally journals one
   tenant, ``warm`` vs request-path);
 - ``compile_s`` (the listener's backend-compile seconds) and ``wall_s``
   (the whole call, i.e. compile + first execution);
+- ``cache_hits`` — how many of the call's ``parts`` the persistent
+  compilation cache served (present only when some were);
 - cost/memory analysis where the backend provides it: ``flops`` and
   ``bytes_accessed`` from ``Lowered.cost_analysis()`` (cheap — the
   jaxpr is already cached, nothing recompiles), and argument/output/
@@ -60,6 +63,7 @@ recorder installed every seam is one module-global ``is None`` check.
 from __future__ import annotations
 
 import contextlib
+import os
 import threading
 import time
 from typing import Any, Callable
@@ -94,15 +98,23 @@ ADMISSION_KINDS = frozenset({"warm", "aot_load", "aot_fallback",
 _perf = time.perf_counter
 _mono = time.monotonic
 
+#: the directory that holds the package: a source checkout's root
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
 #: jax.monitoring event-name suffix that marks one XLA backend compile
-#: (jax 0.4.x: "/jax/core/compile/backend_compile_duration"; matched by
-#: suffix so a renamed prefix in a later jax keeps reporting)
+#: ("/jax/core/compile/backend_compile_duration"; matched by suffix so
+#: a renamed prefix keeps reporting)
 _COMPILE_EVENT_SUFFIX = "backend_compile_duration"
+#: the event jax records when such a compile was served from the
+#: persistent compilation cache instead of running XLA
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
 
 class _Tls(threading.local):
     def __init__(self):
-        self.stack: list[list] = []  # frames: [compile_s, n_compiles]
+        # frames: [compile_s, n_compiles, persistent_cache_hits]
+        self.stack: list[list] = []
         self.kinds: list[tuple] = []  # kind_section() stack: (kind, fields)
         self.suppress = 0            # self-inflicted compiles (analysis)
 
@@ -131,6 +143,14 @@ def _on_duration_event(name: str, duration: float, **_kw) -> None:
         rec._note_unattributed(duration)
 
 
+def _on_event(name: str, **_kw) -> None:
+    """The second process-wide listener: a compile served from the
+    persistent cache lands on the frame of the call that asked for it
+    (the duration event for the same compile follows on this thread)."""
+    if name == _CACHE_HIT_EVENT and _tls.stack and not _tls.suppress:
+        _tls.stack[-1][2] += 1
+
+
 def _ensure_listener() -> bool:
     """Register the monitoring listener (idempotent).  Called from the
     seams, which by definition run inside jax code paths — never at
@@ -146,6 +166,7 @@ def _ensure_listener() -> bool:
 
             monitoring.register_event_duration_secs_listener(
                 _on_duration_event)
+            monitoring.register_event_listener(_on_event)
         except Exception as e:  # jax absent / API moved: degrade silently
             log.warning("compile recorder cannot listen for compile "
                         "events (%s: %s); compile journaling disabled",
@@ -242,7 +263,7 @@ class CompileRecorder:
 
     # ---- attribution frames (hot path) ----
     def _push(self) -> list:
-        frame = [0.0, 0]
+        frame = [0.0, 0, 0]
         _tls.stack.append(frame)
         return frame
 
@@ -301,7 +322,8 @@ class CompileRecorder:
         section_kind, extra = _section(kind)
         self.record(name=name, signature=sig, compile_s=frame[0],
                     parts=frame[1], wall_s=wall_s, bucket=bucket,
-                    model=model, kind=section_kind, **extra, **fields)
+                    model=model, kind=section_kind, **_hits(frame),
+                    **extra, **fields)
 
     def _analyze(self, fn, args, kw) -> dict:
         """Cost/memory analysis fields, degrading to {} wherever the
@@ -630,10 +652,16 @@ def attribute(name: str, *, kind: str | None = None,
                 section_kind, extra = _section(kind)
                 rec.record(name=name, compile_s=frame[0], parts=frame[1],
                            wall_s=wall, model=model, kind=section_kind,
-                           **extra)
+                           **_hits(frame), **extra)
             except Exception as e:
                 log.warning("compile event for %s dropped (%s: %s)",
                             name, type(e).__name__, e)
+
+
+def _hits(frame: list) -> dict:
+    """``cache_hits`` event field: how many of the frame's ``parts``
+    the persistent cache served — present only when some were."""
+    return {"cache_hits": frame[2]} if frame[2] else {}
 
 
 def _section(default: str | None) -> tuple[str | None, dict]:
@@ -666,60 +694,52 @@ def warm_section():
     return kind_section("warm")
 
 
-def apply_persistent_cache(cache_dir: str) -> bool:
-    """Point jax's persistent compilation cache at ``cache_dir``
-    (``shifu.tpu.compile-cache-dir``) — the middle tier of the AOT
-    fallback ladder: a bucket that live-compiles (AOT mismatch, or no
-    AOT shipped) writes its program here, so the NEXT worker/restart on
-    this host deserializes from the cache instead of re-running XLA.
-    The min-compile-time floor drops to 0 because serve-plane scorer
-    programs compile in well under jax's 1s default — exactly the
-    programs whose re-compilation scales as tenants x buckets.
+def apply_persistent_cache(configured: str = "") -> str:
+    """Place jax's persistent compilation cache and return its directory.
 
-    Best-effort by contract: returns False (logged) on a host without
-    jax or a jax without the config knobs — the caller's plane must
-    come up regardless.
+    One rule for every entry point (train, serve, export, score, data,
+    the fleet worker): where ``JAX_COMPILATION_CACHE_DIR`` is set, that
+    directory is used and code sets no other — ``configured``
+    (``shifu.tpu.compile-cache-dir`` / ``--compile-cache-dir``) only
+    fills the variable when it is unset; with neither, the cache lives
+    at ``<checkout>/.jax_cache``.  The directory is part of jax's cache
+    key, so it is never derived from a pid, the time or ``tempfile``: a
+    cache that moves never hits.
 
-    In a process that has NOT imported jax yet (the serve supervisor,
-    the coordinator — planes that deliberately stay jax-free), the
-    settings land as environment variables instead: jax reads them at
-    import, and child processes (SO_REUSEPORT workers, subprocess
-    fleets) inherit them for free — install time stays jax-free, per
-    this module's contract."""
-    import os
+    The cache is the middle tier of the AOT fallback ladder: a bucket
+    that live-compiles (AOT mismatch, or no AOT shipped) writes its
+    program here, so the NEXT worker/restart on this host deserializes
+    instead of re-running XLA.  The min-compile-time floor drops to 0
+    because serve-plane scorer programs compile in well under jax's 1s
+    default — exactly the programs whose re-compilation scales as
+    tenants x buckets.
+
+    The settings land as environment variables, which jax reads at
+    import and child processes (serve workers, subprocess fleets)
+    inherit — so a process that has NOT imported jax yet (the serve
+    supervisor, the coordinator) stays jax-free; one that has is
+    updated through ``jax.config`` as well."""
     import sys
 
-    if "jax" not in sys.modules:
-        os.environ["JAX_COMPILATION_CACHE_DIR"] = str(cache_dir)
-        os.environ["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0"
-        os.environ["JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES"] = "0"
-        return True
-    try:
+    cache_dir = (os.environ.get("JAX_COMPILATION_CACHE_DIR") or configured
+                 or os.path.join(_CHECKOUT, ".jax_cache"))
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = cache_dir
+    os.environ["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0"
+    os.environ["JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES"] = "0"
+    if "jax" in sys.modules:
         import jax
+        from jax.experimental.compilation_cache import (
+            compilation_cache as _cc,
+        )
 
-        jax.config.update("jax_compilation_cache_dir", str(cache_dir))
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
                           0.0)
-        try:
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                              0)
-        except Exception:
-            pass  # knob absent on older jax: the default (0) matches
-        try:
-            # the cache object initializes lazily at the FIRST compile
-            # and then sticks: a process that compiled anything before
-            # this call (an earlier model load, a probe) would silently
-            # keep the old (usually disabled) cache — reset so the new
-            # dir takes effect regardless of call order
-            from jax.experimental.compilation_cache import (
-                compilation_cache as _cc,
-            )
-
-            _cc.reset_cache()
-        except Exception:
-            pass
-        return True
-    except Exception as e:
-        log.warning("persistent compile cache at %s not applied (%s: %s)",
-                    cache_dir, type(e).__name__, e)
-        return False
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+        # the cache object initializes lazily at the FIRST compile and
+        # then sticks: a process that compiled anything before this
+        # call (an earlier model load, a probe) would silently keep the
+        # old cache — reset so the directory takes effect regardless of
+        # call order
+        _cc.reset_cache()
+    return cache_dir

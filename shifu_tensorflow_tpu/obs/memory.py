@@ -234,28 +234,18 @@ class MemoryAccountant:
 
     @staticmethod
     def _backend_stats(jax) -> dict:
-        """Allocator-view totals summed over local devices; {} when the
-        backend doesn't implement memory_stats (CPU) — the signal is
-        then absent, never zero."""
-        in_use = peak = limit = 0
-        seen = False
-        try:
-            for d in jax.local_devices():
-                ms = d.memory_stats()
-                if not ms:
-                    continue
-                seen = True
-                in_use += int(ms.get("bytes_in_use", 0))
-                peak += int(ms.get("peak_bytes_in_use", 0))
-                limit += int(ms.get("bytes_limit", 0))
-        except Exception:
-            return {}
-        if not seen:
-            return {}
-        out = {"bytes_in_use": in_use, "peak_bytes_in_use": peak}
-        if limit:
-            out["bytes_limit"] = limit
-        return out
+        """Allocator-view totals summed over the local devices that
+        report them.  A figure is present only when every reporting
+        device gives it: a device that does not report its peak or its
+        limit leaves that signal absent, never an assumed zero.  {} when
+        the backend doesn't implement memory_stats at all (CPU)."""
+        per_device = [ms for d in jax.local_devices()
+                      if (ms := d.memory_stats())]
+        return {
+            key: sum(int(ms[key]) for ms in per_device)
+            for key in ("bytes_in_use", "peak_bytes_in_use", "bytes_limit")
+            if per_device and all(key in ms for ms in per_device)
+        }
 
     def _set_gauges(self, out: dict) -> None:
         r = self.registry

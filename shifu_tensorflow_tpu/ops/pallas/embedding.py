@@ -74,12 +74,6 @@ def _scatter_kernel(ids_ref, g_ref, dtable_ref, *, h_tile: int):
     ).astype(dtable_ref.dtype)
 
 
-def _resolve_interpret(interpret: bool | None) -> bool:
-    if interpret is not None:
-        return interpret
-    return jax.default_backend() != "tpu"
-
-
 def _block_shapes(n_rows: int, hash_size: int, block_rows: int, h_tile: int):
     rb = min(block_rows, _round_up(max(n_rows, 1), 8))
     ht = min(h_tile, _round_up(hash_size, 128))
@@ -92,12 +86,12 @@ def embedding_gather(
     table: jax.Array,
     block_rows: int = 1024,
     h_tile: int = 512,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """(N,) int32 bucket ids, (H, D) table -> (N, D) rows, on the MXU.
 
-    ``interpret=None`` auto-selects interpreter mode off-TPU so the same
-    call runs (slowly, for tests) on the CPU mesh.
+    ``interpret=True`` runs the kernel in the Pallas interpreter — for
+    tests on the CPU mesh only; the program never picks it.
     """
     return _gather_impl(ids, table, block_rows, h_tile, interpret)
 
@@ -128,7 +122,7 @@ def _gather_impl(ids, table, block_rows, h_tile, interpret):
             ],
             out_specs=pl.BlockSpec((rb, dim), lambda i, j: (i, 0)),
             out_shape=jax.ShapeDtypeStruct((n_pad, dim), table.dtype),
-            interpret=_resolve_interpret(interpret),
+            interpret=interpret,
         )(idp, tp)
     return out[:n]
 
@@ -158,7 +152,7 @@ def _gather_bwd(block_rows, h_tile, interpret, res, g):
         ],
         out_specs=pl.BlockSpec((ht, dim), lambda j, i: (j, 0)),
         out_shape=jax.ShapeDtypeStruct((h_pad, dim), tdtype),
-        interpret=_resolve_interpret(interpret),
+        interpret=interpret,
     )(idp, gp)
     # integer ids carry a float0 tangent
     return (np.zeros(ids.shape, jax.dtypes.float0), dtable[:hash_size])
@@ -172,7 +166,7 @@ def hashed_embedding_lookup(
     table: jax.Array,
     block_rows: int = 1024,
     h_tile: int = 512,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """(B, C) float categories, (H, D) table -> (B, C*D) embeddings.
 

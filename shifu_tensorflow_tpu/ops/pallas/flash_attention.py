@@ -1,9 +1,8 @@
 """Flash attention as a Pallas TPU kernel.
 
-The sequence family's measured bottleneck is attention-score
-materialization: BENCH_SEQUENCE_TPU.json shows a 7× tokens/s falloff
-from S=256 to S=4096 at a fixed token budget (full attention builds the
-(S, S) score matrix in HBM; at S=4096 that is gigabytes).  The reference
+The sequence family's long-S cost is attention-score materialization:
+full attention builds the (S, S) score matrix in HBM; at S=4096 that is
+gigabytes.  The reference
 has no attention at all (fixed-width tabular vectors — SURVEY.md §5.7);
 this kernel serves the beyond-parity sequence/long-context family.
 
@@ -26,7 +25,13 @@ blocks, P reconstructed per tile from the saved logsumexp — no S×S
 matrix in either pass.  ``STPU_FLASH_BWD=chunked`` selects the previous
 chunked-XLA-scan gradient for A/B measurement
 (scripts/bench_flash_sweep.py).  Parity vs full attention is asserted
-in tests/test_flash.py (interpret mode on CPU, real kernel on TPU).
+in tests/test_flash.py in interpret mode, which only a test asks for
+(``interpret=True``): the program never picks it, so on a host without
+a TPU the kernel fails instead of running in the interpreter.  That it
+lowers for the v5e is pinned in tests/test_tpu_compile.py.  The
+per-row logsumexp and D vectors are carried as (B·H, Sp, 1) arrays:
+Mosaic wants a block's last two dimensions divisible by (8, 128) or
+equal to the array's, which a (1, BQ) block of a (B·H, Sp) array is not.
 """
 
 from __future__ import annotations
@@ -40,12 +45,6 @@ from jax.experimental import pallas as pl
 
 def _round_up(n: int, m: int) -> int:
     return -(-n // m) * m
-
-
-def _resolve_interpret(interpret: bool | None) -> bool:
-    if interpret is not None:
-        return interpret
-    return jax.default_backend() != "tpu"
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
@@ -101,7 +100,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
         # exp(S - L) reconstructs P = 0 there instead of NaN
         lse = jnp.where(l_ref[:] > 0.0,
                         m_ref[:] + jnp.log(l_ref[:]), jnp.inf)
-        lse_ref[0, :] = lse[:, 0]
+        lse_ref[0] = lse
 
 
 def _pad_geom(q, block_q: int, block_k: int):
@@ -129,8 +128,8 @@ def _unprep(xp, b, s, h, d, dp, sp):
 
 
 def _flash_forward_with_stats(q, k, v, *, causal: bool, block_q: int,
-                              block_k: int, interpret: bool | None):
-    """Returns (out (B,S,H,D), lse (B*H, Sp) padded-layout logsumexp)."""
+                              block_k: int, interpret: bool):
+    """Returns (out (B,S,H,D), lse (B*H, Sp, 1) padded-layout logsumexp)."""
     from shifu_tensorflow_tpu.obs import compile as obs_compile
 
     b, s, h, d, dp, sp, bq, bk = _pad_geom(q, block_q, block_k)
@@ -155,24 +154,24 @@ def _flash_forward_with_stats(q, k, v, *, causal: bool, block_q: int,
             ],
             out_specs=[
                 pl.BlockSpec((1, bq, dp), lambda bh, qi, ki: (bh, qi, 0)),
-                pl.BlockSpec((1, bq), lambda bh, qi, ki: (bh, qi)),
+                pl.BlockSpec((1, bq, 1), lambda bh, qi, ki: (bh, qi, 0)),
             ],
             out_shape=[
                 jax.ShapeDtypeStruct((b * h, sp, dp), q.dtype),
-                jax.ShapeDtypeStruct((b * h, sp), jnp.float32),
+                jax.ShapeDtypeStruct((b * h, sp, 1), jnp.float32),
             ],
             scratch_shapes=[
                 _vmem((bq, dp)),
                 _vmem((bq, 1)),
                 _vmem((bq, 1)),
             ],
-            interpret=_resolve_interpret(interpret),
+            interpret=interpret,
         )(qp, kp, vp)
     return _unprep(out, b, s, h, d, dp, sp), lse
 
 
 def _flash_forward(q, k, v, *, causal: bool, block_q: int, block_k: int,
-                   interpret: bool | None):
+                   interpret: bool):
     out, _ = _flash_forward_with_stats(
         q, k, v, causal=causal, block_q=block_q, block_k=block_k,
         interpret=interpret)
@@ -227,8 +226,8 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref,
     kf = k_ref[0].astype(jnp.float32)
     vf = v_ref[0].astype(jnp.float32)
     dof = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0, :][:, None]   # (bq, 1)
-    dvec = d_ref[0, :][:, None]    # (bq, 1)
+    lse = lse_ref[0]   # (bq, 1)
+    dvec = d_ref[0]    # (bq, 1)
     valid = _bwd_masks(pl.program_id(1), ki, block_q, block_k, s_real,
                        causal)
     _, ds = _bwd_p_ds(qf, kf, vf, dof, lse, dvec, valid, scale)
@@ -258,8 +257,8 @@ def _flash_bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, d_ref,
     kf = k_ref[0].astype(jnp.float32)
     vf = v_ref[0].astype(jnp.float32)
     dof = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0, :][:, None]
-    dvec = d_ref[0, :][:, None]
+    lse = lse_ref[0]
+    dvec = d_ref[0]
     valid = _bwd_masks(qi, pl.program_id(1), block_q, block_k, s_real,
                        causal)
     p, ds = _bwd_p_ds(qf, kf, vf, dof, lse, dvec, valid, scale)
@@ -280,7 +279,7 @@ def _flash_bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, d_ref,
 
 
 def _flash_backward(q, k, v, out, lse, g, *, causal: bool, block_q: int,
-                    block_k: int, interpret: bool | None):
+                    block_k: int, interpret: bool):
     """True Pallas flash backward: P is reconstructed per tile from the
     forward's logsumexp (no S×S matrix anywhere), dQ accumulates over key
     blocks, dK/dV over query blocks — the FlashAttention-2 decomposition.
@@ -294,8 +293,7 @@ def _flash_backward(q, k, v, out, lse, g, *, causal: bool, block_q: int,
     outp = _prep(out, b, s, h, d, dp, sp)
     # D_i = sum_d dO_i * O_i — cheap elementwise+reduce, XLA does it well
     dvec = jnp.sum(dop.astype(jnp.float32) * outp.astype(jnp.float32),
-                   axis=-1)  # (BH, Sp)
-    interp = _resolve_interpret(interpret)
+                   axis=-1, keepdims=True)  # (BH, Sp, 1)
 
     dq = pl.pallas_call(
         partial(_flash_bwd_dq_kernel, scale=scale, causal=causal,
@@ -306,13 +304,13 @@ def _flash_backward(q, k, v, out, lse, g, *, causal: bool, block_q: int,
             pl.BlockSpec((1, bk, dp), lambda bh, qi, ki: (bh, ki, 0)),
             pl.BlockSpec((1, bk, dp), lambda bh, qi, ki: (bh, ki, 0)),
             pl.BlockSpec((1, bq, dp), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, bq), lambda bh, qi, ki: (bh, qi)),
-            pl.BlockSpec((1, bq), lambda bh, qi, ki: (bh, qi)),
+            pl.BlockSpec((1, bq, 1), lambda bh, qi, ki: (bh, qi, 0)),
+            pl.BlockSpec((1, bq, 1), lambda bh, qi, ki: (bh, qi, 0)),
         ],
         out_specs=pl.BlockSpec((1, bq, dp), lambda bh, qi, ki: (bh, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, sp, dp), q.dtype),
         scratch_shapes=[_vmem((bq, dp))],
-        interpret=interp,
+        interpret=interpret,
     )(qp, kp, vp, dop, lse, dvec)
 
     dk, dv = pl.pallas_call(
@@ -324,8 +322,8 @@ def _flash_backward(q, k, v, out, lse, g, *, causal: bool, block_q: int,
             pl.BlockSpec((1, bk, dp), lambda bh, ki, qi: (bh, ki, 0)),
             pl.BlockSpec((1, bq, dp), lambda bh, ki, qi: (bh, qi, 0)),
             pl.BlockSpec((1, bq, dp), lambda bh, ki, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, bq), lambda bh, ki, qi: (bh, qi)),
-            pl.BlockSpec((1, bq), lambda bh, ki, qi: (bh, qi)),
+            pl.BlockSpec((1, bq, 1), lambda bh, ki, qi: (bh, qi, 0)),
+            pl.BlockSpec((1, bq, 1), lambda bh, ki, qi: (bh, qi, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, bk, dp), lambda bh, ki, qi: (bh, ki, 0)),
@@ -336,7 +334,7 @@ def _flash_backward(q, k, v, out, lse, g, *, causal: bool, block_q: int,
             jax.ShapeDtypeStruct((b * h, sp, dp), v.dtype),
         ],
         scratch_shapes=[_vmem((bk, dp)), _vmem((bk, dp))],
-        interpret=interp,
+        interpret=interpret,
     )(kp, vp, qp, dop, lse, dvec)
 
     un = lambda xp: _unprep(xp, b, s, h, d, dp, sp)  # noqa: E731
@@ -345,10 +343,10 @@ def _flash_backward(q, k, v, out, lse, g, *, causal: bool, block_q: int,
 
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention(q, k, v, causal: bool = False, block_q: int = 128,
-                    block_k: int = 128, interpret: bool | None = None):
+                    block_k: int = 128, interpret: bool = False):
     """Fused flash attention, shapes (B, S, H, D).
 
-    Forward: the Pallas kernel above (interpret mode off-TPU).
+    Forward: the Pallas kernel above.
     Backward: the Pallas FlashAttention-2 backward (_flash_backward) —
     P reconstructed per tile from the forward's saved logsumexp, dQ/dK/dV
     accumulated blockwise, no S×S matrix in either pass.  Set
@@ -383,9 +381,9 @@ def _flash_bwd(causal, block_q, block_k, interpret, res, g):
     if os.environ.get("STPU_FLASH_BWD", "pallas") == "chunked":
         from shifu_tensorflow_tpu.parallel.ring import chunked_attention
 
-        # chunked fallback: never SMALLER than 512 — the sweet spot
-        # measured in BENCH_SEQUENCE_TPU.json (default callers pass
-        # block_q=block_k=128, which must not shrink the backward chunk)
+        # chunked fallback: never SMALLER than 512, chunked_attention's
+        # own default (default callers pass block_q=block_k=128, which
+        # must not shrink the backward chunk)
         block = max(512, block_q, block_k)
         _, vjp = jax.vjp(
             lambda q_, k_, v_: chunked_attention(

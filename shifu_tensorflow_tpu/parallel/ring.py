@@ -81,9 +81,9 @@ def chunked_attention(
     no S×S materialization, in EITHER direction.
 
     Forward: ``lax.scan`` over K/V blocks with the same online softmax
-    the ring path uses (`_block_update`) — the measured motivation is
-    BENCH_SEQUENCE_TPU.json's 7× tokens/s falloff from S=256 to S=4096
-    at a fixed token budget, where score materialization takes over.
+    the ring path uses (`_block_update`) — the motivation is full
+    attention's (S, S) score matrix, which takes over the step as S
+    grows at a fixed token budget.
     Backward: a custom VJP (the standard flash decomposition) that
     saves only ``out`` and the per-row logsumexp — O(B·S·H·D) residuals
     — and recomputes each block's softmax weights inside a second scan.

@@ -1,14 +1,7 @@
-"""``shard_map`` resolved across jax versions — the one shim every
-shard_map call site in the framework shares.
+"""``jax.shard_map`` behind the one wrapper every shard_map call site in
+the framework shares.
 
-Three API generations are covered: ``jax.shard_map`` (new), the
-``jax.experimental.shard_map.shard_map`` it graduated from (jax <= 0.4.x,
-where ``jax.shard_map`` raises an accelerated-deprecation AttributeError),
-and the replication-check kwarg rename ``check_rep`` → ``check_vma``
-(jax 0.9).  Resolving here keeps a jax upgrade or downgrade from taking
-out every SAGN/ring call site at import time.
-
-Being the one chokepoint also makes it the obs plane's collective seam:
+Being the one chokepoint makes it the obs plane's collective seam:
 every returned callable runs under an ``obs.fleet.comm_region`` —
 ``comm.shmap.<label>`` tracer span plus a PR-10 compile-attribution
 frame, so an eager shard_map call's wall time lands in the epoch's span
@@ -22,30 +15,17 @@ Pallas seams follow).  Pass ``comm_label=None`` to skip the wrapper
 
 from __future__ import annotations
 
-import inspect
-
 import jax
-
-try:
-    _shard_map = jax.shard_map
-except AttributeError:  # jax <= 0.4.x: still under jax.experimental
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_CHECK_KW = (
-    "check_vma"
-    if "check_vma" in inspect.signature(_shard_map).parameters
-    else "check_rep"
-)
 
 
 def shard_map(fn, mesh, in_specs, out_specs, *, check_replication=False,
               comm_label: str | None = "auto"):
-    mapped = _shard_map(
+    mapped = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=out_specs,
-        **{_CHECK_KW: check_replication},
+        check_vma=check_replication,
     )
     if comm_label is None:
         return mapped

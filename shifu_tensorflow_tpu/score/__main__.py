@@ -121,6 +121,11 @@ def cmd_worker(args) -> int:
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
+    # the coordinator process stays jax-free: the setting reaches its
+    # scoring workers through the environment
+    from shifu_tensorflow_tpu.obs.compile import apply_persistent_cache
+
+    apply_persistent_cache()
     if args.cmd == "run":
         return cmd_run(args)
     return cmd_worker(args)

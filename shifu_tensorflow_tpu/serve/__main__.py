@@ -159,7 +159,9 @@ def build_parser() -> argparse.ArgumentParser:
                         "tier of the AOT fallback ladder: a bucket "
                         "that live-compiles (AOT mismatch, no AOT "
                         "shipped) persists its program here, so the "
-                        "next worker/restart skips XLA")
+                        "next worker/restart skips XLA.  "
+                        "JAX_COMPILATION_CACHE_DIR wins where set; with "
+                        "neither, <checkout>/.jax_cache")
     p.add_argument("--obs-baseline", default=None, dest="obs_baseline",
                    help="pinned baseline rollup (a .rollup.jsonl sidecar "
                         "or a journal base) for the cross-run regression "
@@ -172,11 +174,6 @@ def build_parser() -> argparse.ArgumentParser:
 def main(argv: list[str] | None = None) -> int:
     argv = list(argv) if argv is not None else sys.argv[1:]
     args = build_parser().parse_args(argv)
-    # after parse_args (--help must not pay a jax import), before any
-    # jax-touching work
-    from shifu_tensorflow_tpu.utils.jaxenv import honor_cpu_pin
-
-    honor_cpu_pin()
     conf = Conf()
     for path in args.globalconfig:
         conf.add_resource(path)
@@ -206,7 +203,16 @@ def main(argv: list[str] | None = None) -> int:
             # multi-process scale-out: this invocation becomes the
             # supervisor, each scoring process is a re-exec of this CLI
             # with --worker-index set (and the SAME argv otherwise, so
-            # every knob — conf layers included — reaches the workers)
+            # every knob — conf layers included — reaches the workers).
+            # Every worker loads the model onto the device, the shared
+            # lane's siblings included, so the fleet is a CPU topology.
+            from shifu_tensorflow_tpu.utils.jaxenv import (
+                refuse_processes_sharing_a_chip,
+            )
+
+            refuse_processes_sharing_a_chip(
+                max(config.workers, config.workers_max or 0),
+                "--serve-workers")
             return _supervise(argv, config, obs_cfg, job_id)
         install_obs(obs_cfg, plane="serve",
                     worker_index=args.serve_worker_index, job=job_id)

@@ -187,7 +187,9 @@ def build_parser() -> argparse.ArgumentParser:
                    help="jax persistent compilation cache dir "
                         "(shifu.tpu.compile-cache-dir): programs that "
                         "do compile persist here, so the next "
-                        "process/restart on this host skips XLA")
+                        "process/restart on this host skips XLA.  "
+                        "JAX_COMPILATION_CACHE_DIR wins where set; with "
+                        "neither, <checkout>/.jax_cache")
     p.add_argument("--board-path", default=None,
                    help="metrics board file (reference console-board parity)")
     p.add_argument("--profile-dir", default=None,
@@ -832,15 +834,18 @@ def run_single(args, conf, model_config: ModelConfig, schema: RecordSchema) -> i
         print(f"exported to {args.export_dir}: {wrote}", flush=True)
     import jax as _jax
 
+    devices = _jax.devices()
     summary = {
         "state": "finished",
         "epochs_run": len(history),
         "wall_time_s": round(wall, 2),
         "final_valid_loss": history[-1].valid_loss if history else None,
         "final_ks": history[-1].ks if history else None,
-        # which backend actually trained — scripts wrapping the CLI (e.g.
-        # bench_e2e) record it in their artifacts
-        "platform": _jax.devices()[0].platform,
+        # which device actually trained — scripts wrapping the CLI (e.g.
+        # bench_e2e, chip_smoke) record it in their artifacts
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "device_count": len(devices),
     }
     if trainer.stop_reason:
         summary["stopped_early"] = trainer.stop_reason
@@ -916,6 +921,16 @@ def run_multi(args, conf, model_config: ModelConfig, schema: RecordSchema) -> in
     # launches — the reference's defining capability; thread workers can't
     # host it (one process cannot be N jax.distributed participants)
     use_spmd = args.spmd if args.spmd is not None else args.launcher == "process"
+    if args.launcher == "process":
+        # every worker is a JAX process on THIS host (one process per
+        # host is the multi-host shape; hosts are joined by the API's
+        # ssh launcher, not by this flag)
+        from shifu_tensorflow_tpu.utils.jaxenv import (
+            refuse_processes_sharing_a_chip,
+        )
+
+        refuse_processes_sharing_a_chip(
+            n_workers, "--workers with --launcher process")
     # merged dict (not two ** expansions): early-stop forces sync_epochs
     # True over whatever the conf key says — a keyword collision otherwise
     spec_kw = {**job_spec_kwargs(conf), **elastic_spec_kwargs(args, conf),
@@ -1087,11 +1102,6 @@ def run_multi(args, conf, model_config: ModelConfig, schema: RecordSchema) -> in
 
 def main(argv: list[str] | None = None) -> int:
     args = build_parser().parse_args(argv)
-    # after parse_args (--help must not pay a jax import), before any
-    # jax-touching work
-    from shifu_tensorflow_tpu.utils.jaxenv import honor_cpu_pin
-
-    honor_cpu_pin()
     conf = load_conf(args)
     # install the conf-resolved retry envelope as the process default so
     # the fs backends / RPC client / checkpointer (which auto-construct

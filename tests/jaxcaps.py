@@ -1,60 +1,34 @@
 """Environment-capability gates for tests, shared across files.
 
 The cross-process SPMD drills (test_spmd, test_cli multi-worker,
-test_convergence, test_eval_cli fleet, test_netns_spmd) need
-CROSS-PROCESS collectives on the CPU backend: each worker is its own
-jax process and gradients all-reduce over loopback.  jaxlib 0.4.x's CPU
-PJRT client cannot form them — the fleets hang or fail inside
-jax.distributed initialization, not in framework code (known-broken at
-seed, CHANGES.md PR 2).  Skipping with this explicit reason makes
-tier-1 output distinguish "environment can't run this" from a real
-regression, and stops the broken fleets from burning the suite's
-wall-clock budget on doomed subprocess timeouts.
+test_convergence, test_eval_cli fleet, test_netns_spmd) run each worker as
+its own jax process, with gradients all-reduced over loopback by the CPU
+backend's cross-process collectives — which the one installation there is
+(jax/jaxlib 0.9.0) has, so they carry no gate.
 
-In-process SPMD (the conftest's 8-device virtual CPU mesh) is
-unaffected and runs everywhere.
+In-process SPMD (the conftest's 8-device virtual CPU mesh) runs
+everywhere.
 """
 
 from __future__ import annotations
 
 import os
 
-import jaxlib
 import pytest
 
-JAXLIB_VERSION = tuple(
-    int(p) for p in jaxlib.__version__.split(".")[:3]
-)
-
-needs_multiprocess_collectives = pytest.mark.skipif(
-    JAXLIB_VERSION < (0, 5, 0),
-    reason=(
-        "jaxlib %s CPU backend lacks multiprocess collectives "
-        "(known-broken at seed, see CHANGES.md PR 2); needs jaxlib>=0.5"
-        % jaxlib.__version__
-    ),
-)
-
 # The ssh-launcher drills additionally bind the jax coordination service
-# to this machine's non-loopback interface — on top of the cross-process
-# collective requirement, the containerized CI network cannot route
-# worker<->chief traffic over it (verified failing identically on a
-# pristine seed checkout, PR 4 notes).  That network limitation is
-# INDEPENDENT of the jaxlib version, so a jaxlib bump alone must not
-# lift the skip into a guaranteed environment failure: these tests run
-# only when jaxlib has the collectives AND the operator asserts the
-# network can route the non-loopback plane by setting
-# STPU_NONLOOPBACK_SPMD_TESTS=1.  Tier-1 then reads
+# to this machine's non-loopback interface, and the containerized CI
+# network cannot route worker<->chief traffic over it (verified failing
+# identically on a pristine seed checkout, PR 4 notes).  These tests run
+# only when the operator asserts the network can route the non-loopback
+# plane by setting STPU_NONLOOPBACK_SPMD_TESTS=1.  Tier-1 then reads
 # green-or-real-regression instead of known-red.
 needs_nonloopback_spmd = pytest.mark.skipif(
-    JAXLIB_VERSION < (0, 5, 0)
-    or not os.environ.get("STPU_NONLOOPBACK_SPMD_TESTS"),
+    not os.environ.get("STPU_NONLOOPBACK_SPMD_TESTS"),
     reason=(
-        "non-loopback cross-process SPMD: needs jaxlib>=0.5 "
-        "multiprocess collectives (have %s) AND a network that routes "
+        "non-loopback cross-process SPMD needs a network that routes "
         "the non-loopback coordination plane — opt in with "
         "STPU_NONLOOPBACK_SPMD_TESTS=1 (container failure pre-existing "
         "at seed, see CHANGES.md PR 4)"
-        % jaxlib.__version__
     ),
 )

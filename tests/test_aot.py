@@ -429,34 +429,73 @@ def test_rollup_folds_aot_kinds(tmp_path):
 # -------------------------------------------- persistent cache satellite
 
 
-def test_persistent_compile_cache_populates_and_applies(tmp_path):
+_CACHE_KNOBS = ("jax_compilation_cache_dir",
+                "jax_persistent_cache_min_compile_time_secs",
+                "jax_persistent_cache_min_entry_size_bytes",
+                "jax_enable_compilation_cache")
+
+
+@pytest.fixture
+def restore_cache_config():
+    """apply_persistent_cache writes jax's process-wide config: put it
+    back, and drop the live cache object too — it initialized against
+    the test's directory and would otherwise serve cache HITS to later
+    tests whose compile-event assertions expect real backend compiles."""
+    import jax
+    from jax.experimental.compilation_cache import (
+        compilation_cache as _cc,
+    )
+
+    before = {k: getattr(jax.config, k) for k in _CACHE_KNOBS}
+    yield
+    for k, v in before.items():
+        jax.config.update(k, v)
+    _cc.reset_cache()
+
+
+def test_persistent_compile_cache_populates_and_applies(
+        tmp_path, monkeypatch, restore_cache_config):
     """apply_persistent_cache points jax's on-disk cache at the dir (the
     AOT fallback ladder's middle tier): compiles land entries there."""
     import jax
     import jax.numpy as jnp
 
     cache = tmp_path / "xla-cache"
-    before = {
-        k: getattr(jax.config, k) for k in
-        ("jax_compilation_cache_dir",
-         "jax_persistent_cache_min_compile_time_secs")
-    }
-    try:
-        assert compile_mod.apply_persistent_cache(str(cache)) is True
-        f = jax.jit(lambda x: jnp.tanh(x) * 3 + 1)
-        np.asarray(f(jnp.ones((7,))))
-        assert any(cache.iterdir())
-    finally:
-        for k, v in before.items():
-            jax.config.update(k, v)
-        # drop the live cache object too: it initialized against the
-        # tmp dir and would otherwise serve cache HITS to later tests
-        # whose compile-event assertions expect real backend compiles
-        from jax.experimental.compilation_cache import (
-            compilation_cache as _cc,
-        )
+    # set-then-delete so monkeypatch restores the variable the helper
+    # is about to fill
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "x")
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    # the conftest keeps the cache off for every other test
+    jax.config.update("jax_enable_compilation_cache", True)
+    assert compile_mod.apply_persistent_cache(str(cache)) == str(cache)
+    f = jax.jit(lambda x: jnp.tanh(x) * 3 + 1)
+    np.asarray(f(jnp.ones((7,))))
+    assert any(cache.iterdir())
 
-        _cc.reset_cache()
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_placed_from_outside(tmp_path, monkeypatch,
+                                           restore_cache_config, from_env):
+    """JAX_COMPILATION_CACHE_DIR set -> that directory, the key ignored;
+    unset (and no key) -> <checkout>/.jax_cache.  Never a moving path."""
+    import jax
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                           str(tmp_path / "outside"))
+        want = str(tmp_path / "outside")
+        got = compile_mod.apply_persistent_cache(str(tmp_path / "key"))
+    else:
+        # set-then-delete so monkeypatch restores the variable the
+        # helper is about to fill
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "x")
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        want = os.path.join(repo, ".jax_cache")
+        got = compile_mod.apply_persistent_cache()
+    assert got == want
+    assert os.environ["JAX_COMPILATION_CACHE_DIR"] == want
+    assert jax.config.jax_compilation_cache_dir == want
 
 
 def test_compile_cache_dir_rides_obs_config(tmp_path):

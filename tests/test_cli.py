@@ -14,10 +14,6 @@ from shifu_tensorflow_tpu.train.__main__ import (
     resolve_schema,
 )
 
-# subprocess fleets need cross-process CPU collectives — an environment
-# capability, not framework logic; see tests/jaxcaps.py for the rationale
-from jaxcaps import needs_multiprocess_collectives
-
 
 def _write_model_config(tmp_path, model_config_json, epochs=2):
     mc = dict(model_config_json)
@@ -141,7 +137,6 @@ def test_cli_single_worker_end_to_end(
     assert (export_dir / "GenericModelConfig.json").exists()
 
 
-@needs_multiprocess_collectives
 def test_cli_multi_worker_end_to_end(
     tmp_path, capsys, psv_dataset, model_config_json
 ):
@@ -166,7 +161,6 @@ def test_cli_multi_worker_end_to_end(
     assert (export_dir / "shifu_tpu_weights.npz").exists()
 
 
-@needs_multiprocess_collectives
 def test_cli_multi_worker_keep_best_exports_chief_snapshot(
     tmp_path, capsys, psv_dataset, model_config_json
 ):
@@ -423,7 +417,6 @@ def test_multi_worker_preflight_rejects_bad_accum_configs(tmp_path):
     with pytest.raises(SystemExit, match="sagn"):
         main(base + ["--model-config", str(mc), "--accum-steps", "4"])
 
-@needs_multiprocess_collectives
 def test_cli_multi_worker_fleet_early_stop(
     tmp_path, capsys, psv_dataset, model_config_json
 ):
@@ -477,3 +470,40 @@ def test_single_process_preflight_rejects_unfireable_configs(tmp_path):
     with pytest.raises(SystemExit, match="validation"):
         main(base + ["--workers", "2", "--keep-best", "ks",
                      "--valid-rate", "0"])
+
+
+@pytest.mark.parametrize("pin,probe_says,refused", [
+    ("cpu", None, False),          # the pin answers: nothing is probed
+    ("", "cpu 1", False),          # a CPU-only host may run N processes
+    ("tpu,cpu", "tpu 1", True),    # the chip machine's own setting
+    ("", "tpu 4", True),
+])
+def test_local_worker_processes_refused_where_they_would_share_a_chip(
+        monkeypatch, pin, probe_says, refused):
+    """``--workers N --launcher process`` / ``--serve-workers N`` start N
+    JAX processes on this host.  A chip belongs to one process, so on a
+    host with an accelerator the entry point exits, naming the cause,
+    before any child starts; the platform is asked of a short-lived
+    probe process so the caller never opens the chip itself."""
+    import subprocess
+
+    from shifu_tensorflow_tpu.utils import jaxenv
+
+    probes = []
+
+    def fake_run(argv, **kw):
+        probes.append(argv)
+        return subprocess.CompletedProcess(argv, 0, stdout=probe_says + "\n",
+                                           stderr="")
+
+    monkeypatch.setenv("JAX_PLATFORMS", pin)
+    monkeypatch.setattr(subprocess, "run", fake_run)
+    if refused:
+        with pytest.raises(SystemExit, match="belongs to the one process"):
+            jaxenv.refuse_processes_sharing_a_chip(2, "--workers")
+    else:
+        jaxenv.refuse_processes_sharing_a_chip(2, "--workers")
+    assert len(probes) == (0 if probe_says is None else 1)
+    # one process is always fine, and asks nothing
+    jaxenv.refuse_processes_sharing_a_chip(1, "--workers")
+    assert len(probes) == (0 if probe_says is None else 1)

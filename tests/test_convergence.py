@@ -32,10 +32,6 @@ from shifu_tensorflow_tpu.data.splitter import split_training_data
 from shifu_tensorflow_tpu.train import make_trainer
 from shifu_tensorflow_tpu.train.checkpoint import NpzCheckpointer
 
-# subprocess fleets need cross-process CPU collectives — an environment
-# capability, not framework logic; see tests/jaxcaps.py for the rationale
-from jaxcaps import needs_multiprocess_collectives
-
 KS_GATE = 0.45  # BASELINE.md north star
 N_FEATURES = 10
 EPOCHS = 6
@@ -175,7 +171,6 @@ def test_round4_training_features_reach_ks_gate(strong_dataset):
 
 
 @pytest.mark.parametrize("algorithm", ["ssgd", "sagn"])
-@needs_multiprocess_collectives
 def test_two_process_spmd_reaches_ks_gate(strong_dataset, tmp_path,
                                           algorithm):
     mc = _model_config(algorithm)

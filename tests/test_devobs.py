@@ -258,7 +258,8 @@ def test_ladder_disabled_knob_reproduces_raw_shape_churn(tmp_path):
     m.release()
 
 
-def test_attribute_region_records_eager_pallas_compiles(tmp_path):
+def test_attribute_region_records_eager_pallas_compiles(tmp_path,
+                                                        pallas_interpret):
     """The attribute() seam catches compiles with no jitted callable to
     lower: an eager Pallas embedding gather journals under the pallas
     name (timing only — no signature/analysis, by contract)."""

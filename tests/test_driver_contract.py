@@ -44,15 +44,15 @@ def _reject(tok):  # json.loads accepts NaN/Infinity by default
 def test_bench_emits_json_lines_with_contract_keys():
     # one retry: on a loaded 1-CPU host the timed child can blow its
     # internal budget and bench (correctly) reports value 0 with
-    # diagnostics — bench working as designed, not a contract break, so
-    # give it one quiet second chance before failing the suite
+    # diagnostics and a non-zero exit — bench working as designed, not a
+    # contract break, so give it one quiet second chance before failing
+    # the suite
     for attempt in (1, 2):
         # outer timeout exceeds bench's own worst-case internal budget
         proc = subprocess.run(
             [sys.executable, os.path.join(REPO, "bench.py")],
             capture_output=True, timeout=500, env=_bench_env(), cwd=REPO,
         )
-        assert proc.returncode == 0, proc.stderr.decode()[-2000:]
         lines = [l for l in proc.stdout.decode().splitlines() if l.strip()]
         assert lines, "bench printed nothing"
         # EVERY line must parse — a caller that truncates the stream at
@@ -66,10 +66,32 @@ def test_bench_emits_json_lines_with_contract_keys():
         # the primary metric must appear EARLY (incremental emission):
         # the first parsed line already carries it
         assert parsed[0].get("value", 0) > 0 or d["value"] == 0
+        # the exit code says whether anything was measured
+        assert (proc.returncode == 0) == (d["value"] > 0), \
+            proc.stderr.decode()[-2000:]
         if d["value"] > 0 or attempt == 2:
             break
     assert d["value"] > 0, f"bench measured nothing twice: {d}"
     assert np.isfinite(d["vs_baseline"])
+    # the platform it ran on is the one the test pinned: no re-run
+    # somewhere else
+    assert d["platform"] == "cpu"
+
+
+def test_bench_that_measures_nothing_exits_nonzero():
+    """A child that cannot even start (here: a platform JAX does not
+    have) leaves nothing measured: the error stub is printed, the exit
+    code is non-zero, and there is no second attempt on the CPU."""
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "bench.py")],
+        capture_output=True, timeout=300, cwd=REPO,
+        env=_bench_env(JAX_PLATFORMS="no_such_platform"),
+    )
+    assert proc.returncode != 0
+    d = json.loads(proc.stdout.decode().strip().splitlines()[-1],
+                   parse_constant=_reject)
+    assert d["value"] == 0.0 and d["error"] == "nothing was measured"
+    assert len(d["diagnostics"]) == 1, "one attempt, no fallback"
 
 
 def test_bench_sigterm_flushes_partial_artifact():

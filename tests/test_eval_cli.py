@@ -10,10 +10,6 @@ import pytest
 from shifu_tensorflow_tpu.export.__main__ import main as eval_main
 from shifu_tensorflow_tpu.train.__main__ import main as train_main
 
-# subprocess fleets need cross-process CPU collectives — an environment
-# capability, not framework logic; see tests/jaxcaps.py for the rationale
-from jaxcaps import needs_multiprocess_collectives
-
 
 def _write_model_config(tmp_path, model_config_json, **params):
     mc = dict(model_config_json)
@@ -105,7 +101,6 @@ def test_score_cli_feature_count_mismatch(tmp_path, capsys, psv_dataset,
     assert rc == 2
 
 
-@needs_multiprocess_collectives
 def test_multi_worker_embedding_checkpoint_matches_export(
     tmp_path, capsys, psv_dataset, model_config_json
 ):

@@ -2,12 +2,12 @@
 
 The chunked path (parallel/ring.py chunked_attention) is the XLA
 online-softmax scan; the flash path (ops/pallas/flash_attention.py) is
-the Pallas TPU kernel, exercised here in interpret mode on CPU (the
-same kernel runs compiled on TPU; on-chip parity is covered by the
-bench's parity preamble and was validated on the real chip — see
-docs/benchmarks.md sequence section).  Tolerances are tight here
-because CPU math is uniform; on the TPU MXU, blocked-vs-monolithic f32
-matmul orderings differ at ~1e-3 and checks must be scale-aware.
+the Pallas TPU kernel, exercised here in interpret mode on CPU (asked
+for through the ``pallas_interpret`` fixture; that the same kernel
+lowers for the v5e is pinned in tests/test_tpu_compile.py, and that it
+runs there by chip_smoke.py).  Tolerances are tight here because CPU
+math is uniform; on the TPU MXU, blocked-vs-monolithic f32 matmul
+orderings differ at ~1e-3 and checks must be scale-aware.
 """
 
 import jax
@@ -22,6 +22,8 @@ from shifu_tensorflow_tpu.parallel.ring import (
     chunked_attention,
     full_attention,
 )
+
+pytestmark = pytest.mark.usefixtures("pallas_interpret")
 
 
 def _qkv(b=2, s=96, h=4, d=24, seed=0):
@@ -120,8 +122,8 @@ def test_flash_under_jit_and_vmapped_model_shapes():
 
 
 def test_make_attention_resolution(monkeypatch):
-    # default: auto on a single device is ALWAYS full (the measured
-    # verdict — chunked loses where it compiled, BENCH_SEQUENCE_TPU.json)
+    # default: auto on a single device is ALWAYS full (no win region
+    # for chunked is measured on the attached chip)
     assert make_attention("auto", None, seq_len=256,
                           num_heads=4) is full_attention
     assert make_attention("auto", None, seq_len=8192,

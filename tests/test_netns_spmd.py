@@ -34,10 +34,6 @@ from shifu_tensorflow_tpu.coordinator.worker import WorkerConfig
 from shifu_tensorflow_tpu.data.reader import RecordSchema
 from shifu_tensorflow_tpu.data.splitter import split_training_data
 
-# subprocess fleets need cross-process CPU collectives — an environment
-# capability, not framework logic; see tests/jaxcaps.py for the rationale
-from jaxcaps import needs_multiprocess_collectives
-
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 WORKER_ENV = {
     "JAX_PLATFORMS": "cpu",
@@ -157,7 +153,6 @@ def _spec_and_cfg(psv_dataset, tmp_path, epochs=2):
     return spec, make_cfg
 
 
-@needs_multiprocess_collectives
 def test_spmd_across_network_namespaces(psv_dataset, tmp_path, netns_ssh,
                                         netns_pair):
     """Two workers with DISTINCT network identities train one model: the
@@ -210,7 +205,6 @@ def test_loopback_chief_guard_fires_against_real_network(
     assert "loopback" in (result.failure_reason or "")
 
 
-@needs_multiprocess_collectives
 def test_netns_worker_logs_carry_distinct_identities(
     psv_dataset, tmp_path, netns_ssh, netns_pair
 ):

@@ -1,7 +1,7 @@
 """Pallas fused hashed-embedding kernel — exact parity with the XLA path.
 
-Runs in interpreter mode on CPU (the kernel auto-selects interpret off-TPU);
-the contract is bit-identical outputs and gradients between the pallas and
+Runs in interpreter mode on CPU (asked for through the ``pallas_interpret``
+fixture — the kernel itself never picks it); the contract is bit-identical outputs and gradients between the pallas and
 XLA implementations for any shape, including non-tile-aligned ones.
 """
 
@@ -13,6 +13,8 @@ import pytest
 from shifu_tensorflow_tpu.models.embeddings import HashedEmbedding
 from shifu_tensorflow_tpu.ops import hashing
 from shifu_tensorflow_tpu.ops.pallas.embedding import hashed_embedding_lookup
+
+pytestmark = pytest.mark.usefixtures("pallas_interpret")
 
 
 def _xla_reference(x, table):

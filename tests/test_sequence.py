@@ -132,7 +132,8 @@ def test_seq_remat_config_parsing():
     # so it runs under -m slow only
     pytest.param("flash", marks=pytest.mark.slow),
 ])
-def test_config_level_memory_safe_attention_trains(attention):
+def test_config_level_memory_safe_attention_trains(attention,
+                                                   pallas_interpret):
     """SeqAttention=chunked|flash resolve from ModelConfig params and
     train end-to-end through the Trainer (the long-S single-device
     paths; parity is pinned in tests/test_flash.py — here the wiring)."""

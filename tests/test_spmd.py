@@ -42,10 +42,6 @@ from shifu_tensorflow_tpu.train.checkpoint import NpzCheckpointer
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-# subprocess fleets need cross-process CPU collectives — an environment
-# capability, not framework logic; see tests/jaxcaps.py for the rationale
-from jaxcaps import needs_multiprocess_collectives  # noqa: E402
-
 #: subprocess workers run on plain CPU (1 device each); 2 procs -> 2-device
 #: global mesh over loopback
 WORKER_ENV = {
@@ -376,7 +372,6 @@ def _emulate_single_process(psv_dataset, mc, shards, batch_size=32):
     return trainer
 
 
-@needs_multiprocess_collectives
 def test_spmd_two_processes_train_one_model(psv_dataset, tmp_path):
     """2 worker processes over jax.distributed == 1 process on the union of
     shards (same global batches), to float tolerance."""
@@ -412,7 +407,6 @@ def test_spmd_two_processes_train_one_model(psv_dataset, tmp_path):
         )
 
 
-@needs_multiprocess_collectives
 def test_spmd_sigkill_recovers_via_fleet_restart(psv_dataset, tmp_path):
     """SIGKILL one worker after its first epoch report: the coordinator
     expires it, bumps the generation, the submitter kills + relaunches the
@@ -443,7 +437,6 @@ def test_spmd_sigkill_recovers_via_fleet_restart(psv_dataset, tmp_path):
     assert ckpt.latest_epoch() == 2
 
 
-@needs_multiprocess_collectives
 def test_spmd_sigkill_keep_best_survives_fleet_restart(psv_dataset, tmp_path):
     """SIGKILL recovery with keep-best on: the chief's persisted best
     snapshot (keep-best.npz) must survive the fleet restart — the
@@ -511,7 +504,6 @@ def test_spmd_sigkill_keep_best_survives_fleet_restart(psv_dataset, tmp_path):
     )
 
 
-@needs_multiprocess_collectives
 def test_spmd_streaming_sigkill_during_cold_cache_build(psv_dataset, tmp_path):
     """SIGKILL a worker while the fleet is streaming its FIRST epoch — the
     cold pass that parses text shards and writes binary cache entries.
@@ -560,7 +552,6 @@ def test_spmd_streaming_sigkill_during_cold_cache_build(psv_dataset, tmp_path):
         assert f"{k}.y.f32" in names and f"{k}.w.f32" in names, k
 
 
-@needs_multiprocess_collectives
 def test_spmd_trains_sequence_family(psv_dataset, tmp_path):
     """The sequence model family composes with cross-process SPMD: a
     2-process fleet trains ONE transformer over jax.distributed and
@@ -586,7 +577,6 @@ def test_spmd_trains_sequence_family(psv_dataset, tmp_path):
     assert ckpt.latest_epoch() == 0
 
 
-@needs_multiprocess_collectives
 def test_spmd_sigkill_recovery_with_async_checkpointing(psv_dataset, tmp_path):
     """Same SIGKILL drill with shifu.tpu.async-checkpoint on: background
     writes must leave either a complete published checkpoint or nothing —
@@ -620,7 +610,6 @@ def test_spmd_sigkill_recovery_with_async_checkpointing(psv_dataset, tmp_path):
     assert ckpt.latest_epoch() == 2
 
 
-@needs_multiprocess_collectives
 def test_spmd_scan_steps_matches_per_step_fleet(psv_dataset, tmp_path):
     """Cross-process chunked scan: a 2-process fleet with scan_steps=2
     (stacked (S, B_local, F) chunks through put_process_local) must match

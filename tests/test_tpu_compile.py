@@ -1,0 +1,186 @@
+"""The chip's compiler, asked without the chip.
+
+libtpu is installed in the sandbox and compiles for a TPU that is
+described (``v5e:2x2``) and not attached, so what Mosaic or XLA:TPU would
+refuse on the machine is refused here, at no chip time: a block shape
+Mosaic cannot tile, a kernel that wants too much VMEM, a program that does
+not fit HBM.  Interpret mode shows none of that — the flash kernel passed
+every interpret-mode test while its logsumexp output could not lower.
+
+Nothing runs, so a pass says nothing about results or times; that the same
+programs RUN on the chip is ``chip_smoke.py``'s job.  Shapes only
+(``jax.ShapeDtypeStruct``): there is no device to hold an array.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, SingleDeviceSharding
+
+from shifu_tensorflow_tpu.ops.pallas.embedding import embedding_gather
+from shifu_tensorflow_tpu.ops.pallas.flash_attention import flash_attention
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu on this host: nothing to ask
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: "
+                    f"{type(e).__name__}: {e}")
+
+
+@pytest.fixture(autouse=True)
+def no_persistent_cache():
+    """A compile for a described device can be written to the persistent
+    cache but not read back without the chip (the next one warns and
+    compiles again), so keep the cache out of these compiles whatever an
+    earlier test left configured."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    cc.reset_cache()
+
+
+def _on(sharding, tree):
+    return jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
+        tree)
+
+
+def _kernels(compiled) -> int:
+    return compiled.as_text().count("tpu_custom_call")
+
+
+# ---------------------------------------------------------------- kernels
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("head_dim", [32, 128])
+@pytest.mark.parametrize("seq_len", [1024, 4096])
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
+def test_flash_attention_lowers_for_v5e(topo, grad, seq_len, head_dim,
+                                        dtype):
+    """Forward (one kernel) and forward+backward (three: fwd, dQ, dK/dV)
+    at the repo's largest sequence setting and at a long one."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    batch = 8 if seq_len == 1024 else 2
+    x = jax.ShapeDtypeStruct((batch, seq_len, 4, head_dim), dtype,
+                             sharding=one_chip)
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, True)
+
+    def loss(q, k, v):
+        return jnp.sum(fwd(q, k, v).astype(jnp.float32) ** 2)
+
+    fn = jax.grad(loss, (0, 1, 2)) if grad else fwd
+    compiled = jax.jit(fn).lower(x, x, x).compile()
+    assert _kernels(compiled) == (3 if grad else 1)
+
+
+def test_embedding_gather_lowers_for_v5e(topo):
+    """81,920 ids (16,384 rows x 5 hashed columns) into the 1,048,576 x 8
+    table of the flagship: gather forward, scatter-add backward."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    ids = jax.ShapeDtypeStruct((81_920,), jnp.int32, sharding=one_chip)
+    table = jax.ShapeDtypeStruct((1_048_576, 8), jnp.float32,
+                                 sharding=one_chip)
+
+    def loss(t, i):
+        return jnp.sum(embedding_gather(i, t) ** 2)
+
+    compiled = jax.jit(jax.grad(loss)).lower(table, ids).compile()
+    assert _kernels(compiled) == 2
+
+
+# ----------------------------------------------------- the flagship step
+
+
+def _flagship_step_and_shapes(mesh):
+    """The trainer's own step body and the abstract TrainState / batch it
+    takes at batch 16,384 — built the way ``Trainer.__init__`` builds
+    them, minus everything that needs a device."""
+    import __graft_entry__ as g  # conftest puts the repo root on sys.path
+    from shifu_tensorflow_tpu.models.factory import build_model
+    from shifu_tensorflow_tpu.train.optimizers import make_optimizer
+    from shifu_tensorflow_tpu.train.trainer import (
+        TrainState,
+        make_train_step_body,
+    )
+
+    mc = g._flagship_model_config(embedding_hash=1_048_576)
+    sharded = mesh is not None and mesh.shape.get("model", 1) > 1
+    model = build_model(mc, tuple(range(g.NUM_FEATURES)),
+                        shard_embeddings=sharded, embedding_impl="xla",
+                        mesh=mesh)
+    tx = make_optimizer(mc.params)
+
+    def init():
+        params = model.init(jax.random.key(0),
+                            jnp.zeros((1, g.NUM_FEATURES)))["params"]
+        state = TrainState.create(apply_fn=model.apply, params=params,
+                                  tx=tx)
+        return state.replace(step=jnp.asarray(state.step, jnp.int32))
+
+    rows = 16_384
+    batch = {"x": jax.ShapeDtypeStruct((rows, g.NUM_FEATURES), jnp.float32),
+             "y": jax.ShapeDtypeStruct((rows, 1), jnp.float32),
+             "w": jax.ShapeDtypeStruct((rows, 1), jnp.float32)}
+    body = make_train_step_body(model.apply, "mse", mc.params.l2_reg)
+    return body, jax.eval_shape(init), batch
+
+
+def test_flagship_train_step_lowers_for_one_v5e_chip(topo):
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    body, state, batch = _flagship_step_and_shapes(None)
+    compiled = jax.jit(body, donate_argnums=(0,)).lower(
+        _on(one_chip, state), _on(one_chip, batch)).compile()
+    mem = compiled.memory_analysis()
+    # table + Adam mirrors is ~100 MB; a step that asked for gigabytes
+    # would mean the gather or its gradient densified somewhere
+    assert mem.temp_size_in_bytes < 2 << 30
+
+
+def test_flagship_train_step_lowers_for_the_2x2_mesh(topo):
+    """The four-chip path of ``chip_smoke.py --chips 4``: data:2 x model:2
+    over the host's chips, the table (and its Adam mirrors) sharded
+    row-wise on ``model``, the batch on ``data``."""
+    from shifu_tensorflow_tpu.parallel.mesh import make_mesh
+    from shifu_tensorflow_tpu.parallel.sharding import (
+        DEFAULT_PARTITION_RULES,
+        _is_partitioned,
+        batch_sharding,
+        params_shardings,
+    )
+
+    mesh = make_mesh("data:2,model:2", devices=list(topo.devices))
+    body, state, batch = _flagship_step_and_shapes(mesh)
+    shardings = params_shardings(state, mesh, rules=DEFAULT_PARTITION_RULES)
+
+    def place(leaf, sh):  # what shard_params does, on shapes
+        if _is_partitioned(leaf):
+            return leaf.replace(value=place(leaf.value, sh))
+        return jax.ShapeDtypeStruct(leaf.shape, leaf.dtype, sharding=sh)
+
+    state = jax.tree_util.tree_map(place, state, shardings,
+                                   is_leaf=_is_partitioned)
+    tables = [sh for sh in jax.tree_util.tree_leaves(shardings)
+              if isinstance(sh, NamedSharding) and "model" in sh.spec]
+    assert len(tables) == 3, "table + Adam mu/nu shard on the model axis"
+    compiled = jax.jit(body, donate_argnums=(0,)).lower(
+        state, _on(batch_sharding(mesh), batch)).compile()
+    text = compiled.as_text()
+    assert "all-reduce" in text, "the data axis must all-reduce gradients"

@@ -920,7 +920,7 @@ def test_npz_checkpoint_arrays_do_not_alias_device_buffers(tmp_path):
     })
     snapshot = jax.tree_util.tree_map(
         lambda l: np.array(l, copy=True), jax.device_get(tr.state.params))
-    step = make_train_step(tr.model.apply, donate=True)
+    step = make_train_step(tr.model.apply)
     with NpzCheckpointer(str(tmp_path), async_save=True) as ck:
         ck.save(0, tr.state)
         # donated steps churn the buffers while the write may be in flight
