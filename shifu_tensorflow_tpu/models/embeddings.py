@@ -104,18 +104,24 @@ class HashedEmbedding(nn.Module):
             self.dtype,
         )
         impl = _resolve_impl(self.impl, self.shard_table, self.hash_size)
-        if impl == "pallas":
-            from shifu_tensorflow_tpu.ops.pallas.embedding import (
-                hashed_embedding_lookup,
-            )
+        # the scopes are the phase names `obs profile --phases` reads a
+        # device trace by: the backward of the gather (the scatter-add of
+        # the gradient rows) carries transpose(jvp(...embed.gather))
+        with jax.named_scope("embed.hash"):
+            # one flat index, not a (B, C) one: the same rows in the same
+            # order, but XLA:TPU takes 22 s to compile the (B, C)-indexed
+            # gather at B=16,384 (super-linear in B) against 2 s for this
+            ids = hashing.salted_bucket_ids(x, self.hash_size).reshape(-1)
+        with jax.named_scope("embed.gather"):
+            if impl == "pallas":
+                from shifu_tensorflow_tpu.ops.pallas.embedding import (
+                    embedding_gather,
+                )
 
-            return hashed_embedding_lookup(x, table)
-        ids = hashing.salted_bucket_ids(x, self.hash_size)
-        # one flat index, not a (B, C) one: the same rows in the same
-        # order, but XLA:TPU takes 22 s to compile the (B, C)-indexed
-        # gather at B=16,384 (super-linear in B) against 2 s for this
-        emb = jnp.take(table, ids.reshape(-1), axis=0)  # (B*C, dim)
-        return emb.reshape(x.shape[0], -1)
+                emb = embedding_gather(ids, table)
+            else:
+                emb = jnp.take(table, ids, axis=0)  # (B*C, dim)
+            return emb.reshape(x.shape[0], -1)
 
 
 class HashedCross(nn.Module):
@@ -136,5 +142,6 @@ class HashedCross(nn.Module):
             (self.hash_size, self.features),
             self.dtype,
         )
-        ids = hashing.crossed_bucket_ids(x, self.hash_size)
-        return jnp.take(table, ids, axis=0)
+        with jax.named_scope("wide.cross"):
+            ids = hashing.crossed_bucket_ids(x, self.hash_size)
+            return jnp.take(table, ids, axis=0)

@@ -33,12 +33,16 @@ class WideDeep(nn.Module):
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
-        deep = DenseTower(self.hidden_nodes, self.activations, self.dtype,
-                          name="deep")(x)
-        deep_logit = nn.Dense(
-            1, kernel_init=nn.initializers.xavier_uniform(),
-            bias_init=_xavier_bias_init, dtype=self.dtype, name="deep_logit",
-        )(deep)
+        # Flax already names the modules on the stack (deep/hidden_layer0
+        # ...); this one name is the phase `obs profile --phases` sums
+        with jax.named_scope("deep.mlp"):
+            deep = DenseTower(self.hidden_nodes, self.activations,
+                              self.dtype, name="deep")(x)
+            deep_logit = nn.Dense(
+                1, kernel_init=nn.initializers.xavier_uniform(),
+                bias_init=_xavier_bias_init, dtype=self.dtype,
+                name="deep_logit",
+            )(deep)
 
         wide_x = x[:, jnp.asarray(self.wide_indices)] if self.wide_indices else x
         wide_logit = nn.Dense(

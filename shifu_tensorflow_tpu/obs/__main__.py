@@ -15,6 +15,7 @@ traces, and a live dashboard.
     python -m shifu_tensorflow_tpu.obs diff --bench
     python -m shifu_tensorflow_tpu.obs profile --journal ... --request \
         --dir /tmp/dump --seconds 5
+    python -m shifu_tensorflow_tpu.obs profile --phases /tmp/dump
 
 Works on a finished or a RUNNING job: readers never lock writers, and a
 torn final line (writer killed mid-event) is skipped, not fatal.  The
@@ -36,13 +37,16 @@ flight recorder's history (per-callable costs, signatures, recompile
 storms — which signature churned and when the storm started and
 cleared), ``mem`` the device-memory accountant's bucket split and
 high-water marks, and ``profile`` lists journaled ``jax.profiler``
-captures or (``--request``) asks the running fleet for one.  Every
+captures, (``--request``) asks the running fleet for one, or
+(``--phases <dump dir>``) splits a capture's train step by the program's
+own phase names.  Every
 reading subcommand takes ``--json`` for machine-readable output —
 scripts and the autoscaling supervisor must not screen-scrape the
 human renderer.
 
 stdlib-only and jax-free: this must run on an operator's laptop against
-a journal scp'd out of a dead fleet.
+a journal scp'd out of a dead fleet.  (``profile --phases`` alone reads
+the dump with ``jax.profiler.ProfileData``, imported inside the call.)
 """
 
 from __future__ import annotations
@@ -208,11 +212,15 @@ def build_parser() -> argparse.ArgumentParser:
 
     prof = sub.add_parser(
         "profile",
-        help="list journaled jax.profiler captures, or --request one "
-             "from the running fleet",
+        help="list journaled jax.profiler captures, --request one from "
+             "the running fleet, or split a dump's train step by phase",
     )
-    prof.add_argument("--journal", required=True,
+    prof.add_argument("--journal",
                       help="journal base path (shifu.tpu.obs-journal)")
+    prof.add_argument("--phases", metavar="DUMP_DIR",
+                      help="reduce the newest capture under DUMP_DIR: the "
+                           "train step's device time by the program's "
+                           "named scopes, and the host spans it holds")
     prof.add_argument("--request", action="store_true",
                       help="write a capture trigger beside the journal; "
                            "the fleet's next obs tick starts the window")
@@ -1981,15 +1989,18 @@ def _mem_data(events: list[dict]) -> dict:
         a = per.setdefault(key, {"snapshots": 0, "hwm_bytes": 0,
                                  "hwm_ts": None, "last": None})
         a["snapshots"] += 1
-        total = int(ev.get("total_bytes", 0) or 0)
-        if total >= a["hwm_bytes"]:
-            a["hwm_bytes"] = total
+        # the allocator's held bytes where the backend reports them
+        # (in use + reserved), the live arrays' total otherwise
+        held = int(ev.get("bytes_held") or ev.get("total_bytes") or 0)
+        if held >= a["hwm_bytes"]:
+            a["hwm_bytes"] = held
             a["hwm_ts"] = ev.get("ts")
         a["last"] = {
             k: ev.get(k) for k in (
                 "ts", "total_bytes", "params_bytes", "opt_bytes",
                 "infeed_bytes", "exec_bytes", "other_bytes", "arrays",
-                "bytes_in_use", "bytes_limit", "devmem_frac", "epoch")
+                "bytes_in_use", "bytes_held", "bytes_limit", "devmem_frac",
+                "epoch")
             if ev.get(k) is not None
         }
         for m, b in (ev.get("models") or {}).items():
@@ -2039,7 +2050,8 @@ def cmd_mem(args) -> int:
         )
         if last.get("bytes_limit"):
             print(f"                  backend: "
-                  f"{_fmt_bytes(last.get('bytes_in_use'))} in use of "
+                  f"{_fmt_bytes(last.get('bytes_in_use'))} in use, "
+                  f"{_fmt_bytes(last.get('bytes_held'))} held of "
                   f"{_fmt_bytes(last['bytes_limit'])} limit "
                   f"({100.0 * (last.get('devmem_frac') or 0):.1f}%)")
     if data["models"]:
@@ -2052,6 +2064,12 @@ def cmd_mem(args) -> int:
 # ---- profile captures ----
 
 def cmd_profile(args) -> int:
+    if args.phases:
+        return _cmd_profile_phases(args)
+    if not args.journal:
+        print("profile needs --journal (or --phases <dump dir>)",
+              file=sys.stderr)
+        return 2
     if args.request:
         from shifu_tensorflow_tpu.obs import profile as obs_profile
 
@@ -2081,6 +2099,36 @@ def cmd_profile(args) -> int:
     print(f"profiler captures ({len(caps)} event(s))")
     for ev in caps:
         print(" " + _fmt_event(ev, t0))
+    return 0
+
+
+def _cmd_profile_phases(args) -> int:
+    from shifu_tensorflow_tpu.obs import profile as obs_profile
+
+    out = obs_profile.phases(args.phases)
+    if args.as_json:
+        print(json.dumps(out, separators=(",", ":")))
+        return 0 if out else 1
+    if not out:
+        print(f"no capture under {args.phases!r} in which "
+              f"{obs_profile.STEP_PROGRAM} ran on a TPU", file=sys.stderr)
+        return 1
+    step_ms = out["step_ms"]
+    print(f"{out['step']}: {step_ms:.3f} ms/step (median of "
+          f"{out['steps']} step(s), {out['devices']} device(s))  "
+          f"{out['xplane']}")
+    print(f"  {'phase':<22} {'ms':>9} {'share':>7}")
+    for name, ms in out["phases_ms"].items():
+        print(f"  {name:<22} {ms:>9.3f} {100 * ms / step_ms:>6.1f}%")
+    busy = sum(out["phases_ms"].values())
+    print(f"  {'(sum)':<22} {busy:>9.3f} {100 * busy / step_ms:>6.1f}%")
+    if out["unscoped_ops_ms"]:
+        print("  unscoped ops (ms): " + ", ".join(
+            f"{n} {ms:.3f}" for n, ms in out["unscoped_ops_ms"].items()))
+    if out["host_spans"]:
+        print(f"  {'host span':<22} {'count':>7} {'total s':>10}")
+        for name, h in out["host_spans"].items():
+            print(f"  {name:<22} {h['count']:>7} {h['total_s']:>10.4f}")
     return 0
 
 

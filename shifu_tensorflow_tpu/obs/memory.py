@@ -4,7 +4,10 @@ owner, over time.
 ``jax.live_arrays()`` enumerates every device buffer the process holds;
 backend ``memory_stats()`` (where the PJRT backend implements it — TPU
 and GPU do, CPU returns None) adds the allocator's own view
-(bytes_in_use / peak / limit).  Neither tells you *whose* bytes those
+(bytes_in_use / reserved / peak / limit; ``bytes_held`` is in use plus
+reserved: what a loaded step's temporaries set aside is memory nothing
+else can have, and ``peak_bytes_in_use`` leaves it out).  Neither tells
+you *whose* bytes those
 are — so the accountant takes attribution pytrees from its callers and
 buckets the total:
 
@@ -206,12 +209,17 @@ class MemoryAccountant:
         if model_b:
             out["models"] = dict(sorted(model_b.items()))
         stats = self._backend_stats(jax)
-        if stats:
-            out.update(stats)
+        out.update(stats)
+        if "bytes_in_use" in stats:
+            out["bytes_held"] = (stats["bytes_in_use"]
+                                 + stats.get("bytes_reserved", 0))
+        # the allocator's figure where there is one: it holds the loaded
+        # programs' reservations, which are no live array
+        held = out.get("bytes_held", total)
         with self._lock:
             self.snapshots += 1
-            if total > self.high_water:
-                self.high_water = total
+            if held > self.high_water:
+                self.high_water = held
                 self.high_water_ts = time.time()
             # MERGE, don't replace: a single-model reload snapshot must
             # not wipe sibling tenants' last-known bytes (eviction
@@ -221,7 +229,7 @@ class MemoryAccountant:
         frac = None
         limit = out.get("bytes_limit")
         if limit:
-            frac = min(1.0, out.get("bytes_in_use", total) / limit)
+            frac = min(1.0, held / limit)
             out["devmem_frac"] = round(frac, 6)
         obs_journal.emit(event, plane=self.plane, worker=self.worker,
                          **out, **ctx)
@@ -243,7 +251,8 @@ class MemoryAccountant:
                       if (ms := d.memory_stats())]
         return {
             key: sum(int(ms[key]) for ms in per_device)
-            for key in ("bytes_in_use", "peak_bytes_in_use", "bytes_limit")
+            for key in ("bytes_in_use", "bytes_reserved",
+                        "peak_bytes_in_use", "bytes_limit")
             if per_device and all(key in ms for ms in per_device)
         }
 
@@ -260,10 +269,9 @@ class MemoryAccountant:
             r.set_gauge("params_dev_bytes", out["params_dev_bytes"])
         else:
             r.remove_gauge("params_dev_bytes")  # absent signal, not zero
-        if "bytes_in_use" in out:
-            r.set_gauge("backend_bytes_in_use", out["bytes_in_use"])
-        if "bytes_limit" in out:
-            r.set_gauge("backend_bytes_limit", out["bytes_limit"])
+        for key in ("bytes_in_use", "bytes_held", "bytes_limit"):
+            if key in out:
+                r.set_gauge(f"backend_{key}", out[key])
         with self._lock:
             models = dict(self._model_bytes)
         for name, b in models.items():

@@ -24,12 +24,22 @@ promotion:
 Off-by-default-cheap: an un-configured process never stats anything;
 a configured one pays one ``os.path.exists`` per slow tick.
 stdlib-only at import; jax loads inside the capture thread.
+
+:func:`phases` (``obs profile --phases <dump dir>``) reduces a dump: the
+train step's device time split by the program's own ``jax.named_scope``
+names (``embed.gather.fwd``, ``embed.gather.bwd``, ``optimizer.update``
+...), which survive a recompile that renumbers XLA's ``fusion.N``, and
+the program's host spans (``obs/trace.py``) that the capture holds.
 """
 
 from __future__ import annotations
 
+import bisect
+import glob
 import json
 import os
+import re
+import statistics
 import threading
 import time
 from typing import Any
@@ -38,7 +48,8 @@ from shifu_tensorflow_tpu.utils import logs
 
 log = logs.get("obs")
 
-__all__ = ["configure", "unconfigure", "trigger_path", "request", "poll"]
+__all__ = ["configure", "unconfigure", "trigger_path", "request", "poll",
+           "PHASE_SCOPES", "phase_of", "phases"]
 
 _lock = threading.Lock()
 _trigger: str | None = None     # trigger file this process polls
@@ -165,3 +176,268 @@ def _capture(out_dir: str, seconds: float) -> None:
     finally:
         with _lock:
             _capturing = False
+
+
+# ---- reading a dump: the step's phases ----
+
+#: the ``jax.named_scope`` names the step is written with
+#: (models/embeddings.py, models/wide_deep.py, train/trainer.py,
+#: train/sagn.py); tests/test_phases.py holds the lowered step to them
+PHASE_SCOPES = ("embed.hash", "embed.gather", "wide.cross", "deep.mlp",
+                "loss", "optimizer.update")
+#: the per-step program's module name (``jax.jit`` of ``train_step``);
+#: for the scan or accumulate path pass ``jit_scan_epoch`` / ``jit_accum_step``
+STEP_PROGRAM = "jit_train_step"
+UNSCOPED = "(unscoped)"
+COLLECTIVE = "collective"
+_COLLECTIVE_OP = re.compile(
+    r"^(all-reduce|all-gather|all-to-all|reduce-scatter|collective-permute"
+    r"|collective-broadcast)")
+_DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
+_TRANSFORM = re.compile(r"^[a-z_]+\((.*)\)$")
+#: the program's span names (``step.infeed.wait``, ``epoch.turn``,
+#: ``rpc.heartbeat``) are lower-case dotted words; so are the XLA:CPU
+#: backend's op events (``dot_general.19``), which unlike an annotation
+#: carry stats (``hlo_op``)
+_SPAN_NAME = re.compile(r"^[a-z][a-z0-9_]*(\.[a-z0-9_?]+)+$")
+
+
+def phase_of(op_path: str) -> str:
+    """The phase an op belongs to, from the name stack XLA keeps as its
+    ``op_name``: the first known scope on the path, ``.bwd`` where the
+    path holds ``transpose(`` and ``.fwd`` otherwise.
+    ``jit(train_step)/transpose(jvp(Model))/hashed_columns/embed.gather/
+    jit(_take)/scatter-add`` is ``embed.gather.bwd``; ``optimizer.update``
+    has no direction; a path with none of the scopes is ``(unscoped)``."""
+    for part in op_path.split("/"):
+        while (m := _TRANSFORM.match(part)):  # jvp(x), transpose(jvp(x))
+            part = m.group(1)
+        if part in PHASE_SCOPES:
+            if part == "optimizer.update":
+                return part
+            return f"{part}.{'bwd' if 'transpose(' in op_path else 'fwd'}"
+    return UNSCOPED
+
+
+def _varint(buf, i: int) -> tuple[int, int]:
+    value = shift = 0
+    while True:
+        byte = buf[i]
+        i += 1
+        value |= (byte & 0x7F) << shift
+        if not byte & 0x80:
+            return value, i
+        shift += 7
+
+
+def _wire_fields(buf):
+    """(field number, wire type, value) of one protobuf message: just
+    enough of the wire format to walk to the HLO module a capture embeds
+    (``jax.profiler.ProfileData`` shows events and their own stats, not
+    the metadata planes)."""
+    i, n = 0, len(buf)
+    while i < n:
+        key, i = _varint(buf, i)
+        field, wire = key >> 3, key & 7
+        if wire == 0:
+            value, i = _varint(buf, i)
+        elif wire in (1, 2, 5):
+            size = 8 if wire == 1 else 4
+            if wire == 2:
+                size, i = _varint(buf, i)
+            value = buf[i:i + size]
+            i += size
+        else:
+            raise ValueError(f"wire type {wire} at byte {i}")
+        yield field, wire, value
+
+
+def _walk(buf, path: tuple[int, ...]):
+    """Every sub-message reached from ``buf`` by following the
+    length-delimited fields numbered ``path``, one level each."""
+    if not path:
+        yield buf
+        return
+    for field, wire, value in _wire_fields(buf):
+        if field == path[0] and wire == 2:
+            yield from _walk(value, path[1:])
+
+
+def _text(buf, field: int) -> str:
+    """The first value of a string field of one message, ``""`` if unset."""
+    return next((bytes(v).decode() for v in _walk(buf, (field,))), "")
+
+
+def hlo_op_names(xspace: bytes, step: str) -> dict[str, str]:
+    """``{instruction: op_name}`` of the compiled ``step`` program(s), from
+    the ``HloProto`` the profiler embeds in the capture's
+    ``/host:metadata`` plane (stat ``Hlo Proto`` of the program's event
+    metadata).  On the TPU's op line an event carries no scope (no
+    ``tf_op`` on a ``conditional`` or on the copies XLA inserts), but the
+    module does: ``cond.40 -> jit(train_step)/optimizer.update/cond``.
+    Field numbers: tsl ``xplane.proto`` (XSpace.planes 1; XPlane.name 2,
+    .event_metadata 4; map value 2; XEventMetadata.name 2, .stats 5;
+    XStat.bytes_value 6) and xla ``hlo.proto`` (HloProto.hlo_module 1;
+    .computations 3; .instructions 2; .name 1, .metadata 7; .op_name 2)."""
+    out: dict[str, str] = {}
+    for plane in _walk(memoryview(xspace), (1,)):
+        if _text(plane, 2) != "/host:metadata":
+            continue
+        for meta in _walk(plane, (4, 2)):
+            if step not in _text(meta, 2):
+                continue
+            for instruction in _walk(meta, (5, 6, 1, 3, 2)):
+                op_name = next((_text(m, 2)
+                                for m in _walk(instruction, (7,))), "")
+                if op_name:
+                    out[_text(instruction, 1)] = op_name
+    return out
+
+
+def find_xplane(dump_dir: str) -> str | None:
+    """The newest ``.xplane.pb`` under a profiler dump directory."""
+    found = glob.glob(os.path.join(glob.escape(dump_dir), "**", "*.xplane.pb"),
+                      recursive=True)
+    return max(found, key=os.path.getmtime) if found else None
+
+
+def load_capture(path: str, step: str) -> dict:
+    """``{"devices": {ordinal: {"steps": [[start_ns, dur_ns], ...], "ops":
+    [[name, op_name, start_ns, dur_ns], ...]}}, "host": [[span, start_ns,
+    dur_ns], ...]}``: per TPU plane the executions of the ``step`` program
+    (``XLA Modules`` line) and every op (``XLA Ops`` line; its event is
+    named by the instruction's HLO text, ``%fusion.6 = f32[...] ...``)
+    with the name stack the embedded module gives it; of the host planes
+    the program's own spans."""
+    from jax.profiler import ProfileData
+
+    with open(path, "rb") as f:
+        raw = f.read()
+    op_names = hlo_op_names(raw, step)
+    devices: dict[int, dict[str, list]] = {}
+    host: list[list] = []
+    for plane in ProfileData.from_serialized_xspace(raw).planes:
+        m = _DEVICE_PLANE.match(plane.name)
+        if m:
+            dev = devices.setdefault(int(m.group(1)),
+                                     {"steps": [], "ops": []})
+            for line in plane.lines:
+                if line.name == "XLA Modules":
+                    dev["steps"].extend(
+                        [int(ev.start_ns), int(ev.duration_ns)]
+                        for ev in line.events if step in ev.name)
+                elif line.name == "XLA Ops":
+                    for ev in line.events:
+                        name = ev.name.split(" ", 1)[0].lstrip("%")
+                        dev["ops"].append(
+                            [name, op_names.get(name, ""),
+                             int(ev.start_ns), int(ev.duration_ns)])
+        elif plane.name.startswith("/host:"):
+            for line in plane.lines:
+                host.extend([ev.name, int(ev.start_ns), int(ev.duration_ns)]
+                            for ev in line.events
+                            if _SPAN_NAME.match(ev.name)
+                            and not any(True for _ in ev.stats))
+    return {"devices": devices, "host": host}
+
+
+def phases(dump_dir: str, step: str = STEP_PROGRAM) -> dict:
+    """The newest capture under ``dump_dir``, reduced
+    (:func:`reduce_phases`); ``{}`` when there is no capture or the step
+    program did not run on a TPU in it."""
+    path = find_xplane(dump_dir)
+    if path is None:
+        return {}
+    out = reduce_phases(load_capture(path, step))
+    if out:
+        out["step"], out["xplane"] = step, path
+    return out
+
+
+def _medians_ms(per_device: list[list[dict[str, int]]]) -> dict[str, float]:
+    """``{key: ms}``, largest first: the median over devices of the
+    median over one device's steps (``{key: ns}`` a step; a key a step
+    lacks counts 0 there)."""
+    keys = {k for steps in per_device for split in steps for k in split}
+    out = {k: statistics.median(
+        statistics.median(split.get(k, 0) for split in steps) / 1e6
+        for steps in per_device) for k in keys}
+    return dict(sorted(out.items(), key=lambda kv: -kv[1]))
+
+
+def _split_step(ops: list) -> tuple[dict[str, int], dict[str, int]]:
+    """One step's device time shared out among phases, each instant once:
+    ``({phase: ns}, {unscoped op: ns})``.  A ``conditional`` and the ops
+    of its branch are both on the op line and overlap, so an instant goes
+    to the innermost op open at it that has a phase; an op without one
+    (a layout copy XLA put inside the conditional) inherits the phase of
+    the op around it, and is ``(unscoped)`` only where nothing around it
+    has one.  ``ops``: ``[name, phase, start_ns, dur_ns]``."""
+    edges = []
+    for i, (_, _, start, dur) in enumerate(ops):
+        if dur > 0:
+            edges.append((start, 1, i))
+            edges.append((start + dur, 0, i))
+    edges.sort()
+    by_phase: dict[str, int] = {}
+    loose: dict[str, int] = {}
+    open_: set[int] = set()
+    prev = 0
+    for t, opening, i in edges:
+        if open_ and t > prev:
+            inner_first = sorted(open_, key=lambda k: ops[k][3])
+            owner = next((k for k in inner_first if ops[k][1] != UNSCOPED),
+                         None)
+            if owner is None:
+                name = ops[inner_first[0]][0]
+                loose[name] = loose.get(name, 0) + t - prev
+                phase = UNSCOPED
+            else:
+                phase = ops[owner][1]
+            by_phase[phase] = by_phase.get(phase, 0) + t - prev
+        (open_.add if opening else open_.discard)(i)
+        prev = t
+    return by_phase, loose
+
+
+def reduce_phases(capture: dict) -> dict:
+    """The arithmetic of :func:`phases` on what :func:`load_capture` read
+    (plain lists, so a recorded capture kept as JSON reduces the same).
+    Per device and per execution of the step program: :func:`_split_step`
+    over the ops inside it.  Reported in ms: the median over steps, then
+    over devices, of each phase and of the step; ``{}`` when no device
+    ran the step program."""
+    durations, by_phase, loose = [], [], []
+    for dev in sorted(capture["devices"], key=int):
+        d = capture["devices"][dev]
+        if not d["steps"]:
+            continue
+        ops = sorted(d["ops"], key=lambda o: o[2])
+        starts = [o[2] for o in ops]
+        splits = []
+        for start, dur in d["steps"]:
+            lo = bisect.bisect_left(starts, start)
+            hi = bisect.bisect_right(starts, start + dur)
+            splits.append(_split_step(
+                [[name, COLLECTIVE if _COLLECTIVE_OP.match(name)
+                  else phase_of(scope), s, n]
+                 for name, scope, s, n in ops[lo:hi] if s + n <= start + dur]))
+        durations.append([{"step": dur} for _, dur in d["steps"]])
+        by_phase.append([split[0] for split in splits])
+        loose.append([split[1] for split in splits])
+    if not durations:
+        return {}
+    host: dict[str, dict[str, float]] = {}
+    for name, _, dur in capture["host"]:
+        h = host.setdefault(name, {"count": 0, "total_s": 0.0})
+        h["count"] += 1
+        h["total_s"] += dur / 1e9
+    return {
+        "step_ms": _medians_ms(durations)["step"],
+        "steps": min(len(steps) for steps in durations),
+        "devices": len(durations),
+        "phases_ms": _medians_ms(by_phase),
+        "unscoped_ops_ms": dict(
+            [(n, ms) for n, ms in _medians_ms(loose).items() if ms > 0][:12]),
+        "host_spans": dict(sorted(host.items())),
+    }

@@ -25,11 +25,21 @@ thread, so contention is nil; the lock exists for the cross-thread
 spans (retry sleeps on a checkpoint writer thread, RPC heartbeats).
 ``sample_every=N`` measures every Nth event per span name — steady-state
 ratios stay unbiased while the (already tiny) cost divides by N.
+
+Every measured event is also a ``jax.profiler.TraceAnnotation``, so a
+profiler capture (``obs profile --request``, ``--profile-dir``) holds the
+program's spans on the same clock as the device's ops.  This module never
+imports JAX: the annotation class is taken from ``sys.modules`` once the
+process has imported JAX itself, and a process that never does (the
+coordinator, the ``obs`` CLI, load clients) has no profiler to annotate
+for and records sums only.  With no capture open an annotation costs a
+fraction of the span's own microsecond.
 """
 
 from __future__ import annotations
 
 import contextlib
+import sys
 import threading
 import time
 from typing import Any, Callable, Iterable, Iterator
@@ -44,6 +54,7 @@ __all__ = [
 ]
 
 _perf = time.perf_counter
+_NULL_CM = contextlib.nullcontext()
 
 
 class Tracer:
@@ -58,6 +69,8 @@ class Tracer:
         self._spans: dict[str, list] = {}
         # per-name call counter driving the sampling decision
         self._calls: dict[str, int] = {}
+        # jax.profiler.TraceAnnotation, once this process has imported jax
+        self._trace_annotation = None
 
     # ---- recording ----
     def add(self, name: str, seconds: float) -> None:
@@ -84,6 +97,18 @@ class Tracer:
             self._calls[name] = n + 1
         return n % self.sample_every == 0
 
+    def _annotated(self, name: str):
+        """The profiler annotation for one measured event; a shared
+        nullcontext while this process has not imported JAX."""
+        ann = self._trace_annotation
+        if ann is None:
+            profiler = getattr(sys.modules.get("jax"), "profiler", None)
+            ann = getattr(profiler, "TraceAnnotation", None)
+            if ann is None:
+                return _NULL_CM
+            self._trace_annotation = ann
+        return ann(name)
+
     @contextlib.contextmanager
     def span(self, name: str) -> Iterator[None]:
         if not self._sampled(name):
@@ -91,7 +116,8 @@ class Tracer:
             return
         t0 = _perf()
         try:
-            yield
+            with self._annotated(name):
+                yield
         finally:
             self.add(name, _perf() - t0)
 
@@ -103,7 +129,8 @@ class Tracer:
                 return fn(*a, **kw)
             t0 = _perf()
             try:
-                return fn(*a, **kw)
+                with self._annotated(name):
+                    return fn(*a, **kw)
             finally:
                 self.add(name, _perf() - t0)
 
@@ -117,7 +144,8 @@ class Tracer:
             if self._sampled(name):
                 t0 = _perf()
                 try:
-                    item = next(it)
+                    with self._annotated(name):
+                        item = next(it)
                 except StopIteration:
                     return
                 self.add(name, _perf() - t0)
@@ -173,7 +201,6 @@ class Tracer:
 # ---- process-global hook (the instrumented seams call these) ----
 
 _active: Tracer | None = None
-_NULL_CM = contextlib.nullcontext()
 
 
 def install(tracer: Tracer) -> Tracer:

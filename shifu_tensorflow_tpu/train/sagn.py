@@ -84,9 +84,10 @@ def make_sagn_step(
 
         pred = apply_fn({"params": params},
                         _widen_features(params, micro["x"]))
-        loss = loss_fn(pred, micro["y"], micro["w"])
-        if l2:
-            loss = loss + l2_penalty(params, l2)
+        with jax.named_scope("loss"):
+            loss = loss_fn(pred, micro["y"], micro["w"])
+            if l2:
+                loss = loss + l2_penalty(params, l2)
         return loss
 
     def local_window(params, wb):
@@ -147,12 +148,13 @@ def make_sagn_step(
         # still move Adam-style momentum / increment step) and report NaN
         # so epoch means exclude it — same contract as make_train_step
         has_rows = jnp.sum(window_batch["w"] != 0.0) > 0
-        state = jax.lax.cond(
-            has_rows,
-            lambda s: s.apply_gradients(grads=avg_grads),
-            lambda s: s,
-            state,
-        )
+        with jax.named_scope("optimizer.update"):
+            state = jax.lax.cond(
+                has_rows,
+                lambda s: s.apply_gradients(grads=avg_grads),
+                lambda s: s,
+                state,
+            )
         return state, jnp.where(has_rows, loss, jnp.nan)
 
     return obs_compile.observe(sagn_step, "train.sagn_step")

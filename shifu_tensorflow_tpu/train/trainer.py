@@ -530,9 +530,10 @@ def make_train_step_body(apply_fn, loss_name: str = "mse", l2: float = 0.0,
 
     def compute_loss(params, batch):
         pred = apply_fn({"params": params}, _widen_features(params, batch["x"]))
-        loss = loss_fn(pred, batch["y"], batch["w"])
-        if l2:
-            loss = loss + l2_penalty(params, l2)
+        with jax.named_scope("loss"):
+            loss = loss_fn(pred, batch["y"], batch["w"])
+            if l2:
+                loss = loss + l2_penalty(params, l2)
         return loss
 
     def train_step(state: TrainState, batch: Batch):
@@ -546,12 +547,13 @@ def make_train_step_body(apply_fn, loss_name: str = "mse", l2: float = 0.0,
         # loss reports NaN for such batches so epoch means (nanmean) skip
         # them instead of being biased toward zero.
         has_rows = jnp.sum(batch["w"] != 0.0) > 0
-        state = jax.lax.cond(
-            has_rows,
-            lambda s: s.apply_gradients(grads=grads),
-            lambda s: s,
-            state,
-        )
+        with jax.named_scope("optimizer.update"):
+            state = jax.lax.cond(
+                has_rows,
+                lambda s: s.apply_gradients(grads=grads),
+                lambda s: s,
+                state,
+            )
         loss = jnp.where(has_rows, loss, jnp.nan)
         if with_grad_norm:
             import optax
@@ -592,9 +594,10 @@ def make_host_emb_train_step(apply_fn, raw_width: int,
 
     def compute(params, x, batch):
         pred = apply_fn({"params": params}, _widen_features(params, x))
-        loss = loss_fn(pred, batch["y"], batch["w"])
-        if l2:
-            loss = loss + l2_penalty(params, l2)
+        with jax.named_scope("loss"):
+            loss = loss_fn(pred, batch["y"], batch["w"])
+            if l2:
+                loss = loss + l2_penalty(params, l2)
         return loss
 
     @partial(jax.jit, donate_argnums=(0,))
@@ -604,12 +607,13 @@ def make_host_emb_train_step(apply_fn, raw_width: int,
             state.params, x, batch
         )
         has_rows = jnp.sum(batch["w"] != 0.0) > 0
-        state = jax.lax.cond(
-            has_rows,
-            lambda s: s.apply_gradients(grads=gp),
-            lambda s: s,
-            state,
-        )
+        with jax.named_scope("optimizer.update"):
+            state = jax.lax.cond(
+                has_rows,
+                lambda s: s.apply_gradients(grads=gp),
+                lambda s: s,
+                state,
+            )
         g_emb = jnp.where(has_rows, gx[:, raw_width:], 0.0)
         return state, jnp.where(has_rows, loss, jnp.nan), g_emb
 
@@ -656,7 +660,8 @@ def make_accum_step(apply_fn, loss_name: str = "mse", l2: float = 0.0):
     def sum_form(params, mb):
         pred = apply_fn({"params": params}, _widen_features(params, mb["x"]))
         n = jnp.sum((mb["w"] != 0.0).astype(jnp.float32))
-        loss = loss_fn(pred, mb["y"], mb["w"])
+        with jax.named_scope("loss"):
+            loss = loss_fn(pred, mb["y"], mb["w"])
         # loss is sum/count; recover the sum (0 for all-padding micros,
         # where loss is 0/max(count,1) = 0 already, but guard anyway)
         return jnp.where(n > 0, loss * n, 0.0), n
@@ -689,12 +694,13 @@ def make_accum_step(apply_fn, loss_name: str = "mse", l2: float = 0.0):
             )(state.params)
             grads = jax.tree_util.tree_map(jnp.add, grads, l2_g)
             loss = loss + l2_loss
-        state = jax.lax.cond(
-            has_rows,
-            lambda s: s.apply_gradients(grads=grads),
-            lambda s: s,
-            state,
-        )
+        with jax.named_scope("optimizer.update"):
+            state = jax.lax.cond(
+                has_rows,
+                lambda s: s.apply_gradients(grads=grads),
+                lambda s: s,
+                state,
+            )
         return state, jnp.where(has_rows, loss, jnp.nan)
 
     return obs_compile.observe(accum_step, "train.accum_step")
@@ -1967,6 +1973,39 @@ class Trainer:
             "auc": M.auc(s, y, w),
         }
 
+    def _epoch_stats(self, epoch: int, train_loss: float, ev: dict,
+                     train_time: float, valid_time: float) -> EpochStats:
+        return EpochStats(
+            worker_index=self.worker_index,
+            current_epoch=epoch,
+            training_loss=train_loss,
+            valid_loss=ev["loss"],
+            training_time_s=train_time,
+            valid_time_s=valid_time,
+            global_step=int(jax.device_get(self.state.step)),
+            ks=ev["ks"],
+            auc=ev["auc"],
+        )
+
+    def _close_epoch(self, stats: EpochStats, history: list, on_epoch,
+                     checkpointer, early_stop) -> bool:
+        """What every fit loop does with a finished epoch's stats: health
+        check, journal, best snapshot, callback, checkpoint, early stop.
+        True when early stopping says stop (``stop_reason`` holds why)."""
+        self._health_check_epoch(stats)
+        self._obs_epoch(stats)
+        self._warn_if_validation_empty(stats, early_stop)
+        self._maybe_snapshot_best(stats, checkpointer)
+        history.append(stats)
+        if on_epoch:
+            on_epoch(stats)
+        if checkpointer is not None:
+            self._maybe_save_with_sidecar(checkpointer, stats.current_epoch)
+        if early_stop is not None:
+            self.stop_reason = early_stop.should_stop(stats)
+            return bool(self.stop_reason)
+        return False
+
     @_sketch_fit_scope
     def fit(
         self,
@@ -2000,30 +2039,13 @@ class Trainer:
             ev = self.evaluate(dataset.valid_batches(batch_size))
             valid_time = time.time() - t1
 
-            stats = EpochStats(
-                worker_index=self.worker_index,
-                current_epoch=epoch,
-                training_loss=train_loss,
-                valid_loss=ev["loss"],
-                training_time_s=train_time,
-                valid_time_s=valid_time,
-                global_step=int(jax.device_get(self.state.step)),
-                ks=ev["ks"],
-                auc=ev["auc"],
-            )
-            self._health_check_epoch(stats)
-            self._obs_epoch(stats)
-            self._warn_if_validation_empty(stats, early_stop)
-            self._maybe_snapshot_best(stats, checkpointer)
-            history.append(stats)
-            if on_epoch:
-                on_epoch(stats)
-            if checkpointer is not None:
-                self._maybe_save_with_sidecar(checkpointer, epoch)
-            if early_stop is not None:
-                self.stop_reason = early_stop.should_stop(stats)
-                if self.stop_reason:
-                    break
+            with obs_trace.maybe_span(self.tracer, "epoch.turn"):
+                stats = self._epoch_stats(epoch, train_loss, ev,
+                                          train_time, valid_time)
+                stop = self._close_epoch(stats, history, on_epoch,
+                                         checkpointer, early_stop)
+            if stop:
+                break
         return history
 
     @_sketch_fit_scope
@@ -2152,17 +2174,8 @@ class Trainer:
                 }
                 valid_time = time.time() - t1
 
-            stats = EpochStats(
-                worker_index=self.worker_index,
-                current_epoch=epoch,
-                training_loss=train_loss,
-                valid_loss=ev["loss"],
-                training_time_s=train_time,
-                valid_time_s=valid_time,
-                global_step=int(jax.device_get(self.state.step)),
-                ks=ev["ks"],
-                auc=ev["auc"],
-            )
+            stats = self._epoch_stats(epoch, train_loss, ev,
+                                      train_time, valid_time)
             # one-dispatch epochs have no per-step stream for the guard to
             # instrument; the epoch-level checks (mean-NaN, spike) and the
             # hang watchdog still apply
@@ -2172,19 +2185,9 @@ class Trainer:
                     self.health_guard._count_bad = (
                         "epoch mean loss non-finite"
                     )
-            self._health_check_epoch(stats)
-            self._obs_epoch(stats)
-            self._warn_if_validation_empty(stats, early_stop)
-            self._maybe_snapshot_best(stats, checkpointer)
-            history.append(stats)
-            if on_epoch:
-                on_epoch(stats)
-            if checkpointer is not None:
-                self._maybe_save_with_sidecar(checkpointer, epoch)
-            if early_stop is not None:
-                self.stop_reason = early_stop.should_stop(stats)
-                if self.stop_reason:
-                    break
+            if self._close_epoch(stats, history, on_epoch, checkpointer,
+                                 early_stop):
+                break
         return history
 
     def _make_device_epoch(self, steps: int, batch_size: int):
@@ -2265,15 +2268,21 @@ class Trainer:
         self.stop_reason = None
         autotuner = self.ingest_autotuner
         for epoch in range(start_epoch, epochs):
-            if autotuner is not None:
-                # apply the tuner's device-put depth for this epoch; the
-                # reader/decode widths land via the stream factory, which
-                # reads autotuner.settings() at build time
-                self.prefetch_depth = max(
-                    1, autotuner.settings().prefetch)
-            self._health_begin_epoch(epoch)
-            t0 = time.time()
-            train_loss, n = self.train_epoch(make_train_stream(epoch))
+            # "epoch.turn": what the loop does between one epoch's last
+            # step and the next one's first (not a "step." name, so never
+            # sampled; journaled under "spans")
+            with obs_trace.maybe_span(self.tracer, "epoch.turn"):
+                if autotuner is not None:
+                    # apply the tuner's device-put depth for this epoch;
+                    # the reader/decode widths land via the stream
+                    # factory, which reads autotuner.settings() at build
+                    # time
+                    self.prefetch_depth = max(
+                        1, autotuner.settings().prefetch)
+                self._health_begin_epoch(epoch)
+                t0 = time.time()  # train_time counts the stream's build
+                stream = make_train_stream(epoch)
+            train_loss, n = self.train_epoch(stream)
             train_time = time.time() - t0
             if autotuner is not None:
                 # digest the epoch's stage stats (delivered through the
@@ -2293,37 +2302,21 @@ class Trainer:
                                       or self.slo is not None)
                     summ = (self.tracer.summary() if drained_by_obs
                             else self.tracer.take_summary())
-                autotuner.observe_epoch(summ)
+                with obs_trace.maybe_span(self.tracer, "epoch.turn"):
+                    autotuner.observe_epoch(summ)
             ev = {"loss": float("nan"), "ks": 0.0, "auc": 0.5}
             valid_time = 0.0
             if make_valid_stream is not None:
                 t1 = time.time()
                 ev = self.evaluate(make_valid_stream())
                 valid_time = time.time() - t1
-            stats = EpochStats(
-                worker_index=self.worker_index,
-                current_epoch=epoch,
-                training_loss=train_loss,
-                valid_loss=ev["loss"],
-                training_time_s=train_time,
-                valid_time_s=valid_time,
-                global_step=int(jax.device_get(self.state.step)),
-                ks=ev["ks"],
-                auc=ev["auc"],
-            )
-            self._health_check_epoch(stats)
-            self._obs_epoch(stats)
-            self._warn_if_validation_empty(stats, early_stop)
-            self._maybe_snapshot_best(stats, checkpointer)
-            history.append(stats)
-            if on_epoch:
-                on_epoch(stats)
-            if checkpointer is not None:
-                self._maybe_save_with_sidecar(checkpointer, epoch)
-            if early_stop is not None:
-                self.stop_reason = early_stop.should_stop(stats)
-                if self.stop_reason:
-                    break
+            with obs_trace.maybe_span(self.tracer, "epoch.turn"):
+                stats = self._epoch_stats(epoch, train_loss, ev,
+                                          train_time, valid_time)
+                stop = self._close_epoch(stats, history, on_epoch,
+                                         checkpointer, early_stop)
+            if stop:
+                break
         return history
 
     def predict(self, features: np.ndarray, batch_size: int = 4096) -> np.ndarray:

@@ -8,8 +8,8 @@ idiomatically).  This module fills it the TPU way:
 - ``trace_if(dir)`` wraps a region in ``jax.profiler.trace`` so the run
   produces a TensorBoard/XPlane trace (op-level timeline, HBM usage) when a
   directory is given, and costs nothing when not;
-- ``annotate(name)`` marks host-side regions so they show up on the trace
-  timeline next to the device ops;
+- host-side regions reach the trace timeline as the obs tracer's spans
+  (``obs/trace.py``: every measured span is a ``TraceAnnotation``);
 - ``StepTimer`` measures steady-state step time without serializing the
   pipeline: host dispatch time is accumulated every step, and the device is
   synced only every ``sync_every`` steps, so the measured rate amortizes the
@@ -53,13 +53,6 @@ def trace_if(trace_dir: str | None) -> Iterator[None]:
         obs_journal.emit("profile_capture",
                          status="done" if ok else "failed", dir=trace_dir,
                          wall_s=round(_time.time() - t0, 3))
-
-
-def annotate(name: str):
-    """Host-side region marker (shows on the profiler timeline)."""
-    import jax
-
-    return jax.profiler.TraceAnnotation(name)
 
 
 def true_sync(x: Any) -> None:

@@ -343,6 +343,36 @@ def test_memory_snapshot_buckets_and_high_water(tmp_path):
     assert "stpu_devmem_hwm_bytes" in text
 
 
+def test_memory_snapshot_counts_reserved_bytes_as_held(tmp_path, monkeypatch):
+    """A loaded step's temporaries are `bytes_reserved`, not in use: the
+    held figure, the high water and the fraction of the limit hold them
+    (the flagship step: 3.31 GB in use, 9.13 GB reserved, PERF.md §4)."""
+    import jax
+
+    class Dev:
+        def memory_stats(self):
+            return {"bytes_in_use": 3_000, "bytes_reserved": 9_000,
+                    "peak_bytes_in_use": 3_300, "bytes_limit": 16_000}
+
+    monkeypatch.setattr(jax, "local_devices", lambda: [Dev(), Dev()])
+    path = _journal(tmp_path)
+    mem = memory_mod.install(memory_mod.MemoryAccountant(plane="train"))
+    snap = mem.snapshot(epoch=0)
+    assert snap["bytes_in_use"] == 6_000 and snap["bytes_reserved"] == 18_000
+    assert snap["bytes_held"] == 24_000
+    assert snap["hwm_bytes"] == 24_000
+    assert snap["devmem_frac"] == 0.75
+    journal_mod.uninstall()
+    text = mem.render_prometheus()
+    assert "stpu_devmem_backend_bytes_held 24000" in text
+    assert "stpu_devmem_backend_bytes_in_use 6000" in text
+    from shifu_tensorflow_tpu.obs.__main__ import _mem_data
+
+    worker = _mem_data(read_events(path))["workers"]["train"]
+    assert worker["hwm_bytes"] == 24_000
+    assert worker["last"]["bytes_held"] == 24_000
+
+
 def test_memory_snapshot_per_model_merge_and_drop(tmp_path):
     _journal(tmp_path)
     mem = memory_mod.install(memory_mod.MemoryAccountant(plane="serve"))

@@ -620,6 +620,137 @@ def test_trainer_untraced_emits_nothing_and_has_no_tracer(tmp_path):
     trainer.fit(dataset, epochs=1, batch_size=32)  # must not journal/crash
 
 
+# ---- the spans on the profiler's clock ----
+
+def _captured_spans(dump_dir) -> list[str]:
+    """Names of the program's spans on a capture's host planes."""
+    from shifu_tensorflow_tpu.obs import profile as profile_mod
+
+    path = profile_mod.find_xplane(str(dump_dir))
+    assert path, f"no capture under {dump_dir}"
+    return [name for name, _, _ in
+            profile_mod.load_capture(path, profile_mod.STEP_PROGRAM)["host"]]
+
+
+def _drive_span(t):
+    for _ in range(8):
+        with t.span("step.host"):
+            pass
+
+
+def _drive_timed(t):
+    f = t.timed("step.host", lambda: None)
+    for _ in range(8):
+        f()
+
+
+def _drive_wrap_iter(t):
+    # next() is called 8 times: 7 items and the StopIteration
+    assert list(t.wrap_iter("step.host", range(7))) == list(range(7))
+
+
+@pytest.mark.parametrize("drive", [_drive_span, _drive_timed,
+                                   _drive_wrap_iter])
+def test_measured_events_are_profiler_annotations(tmp_path, drive):
+    """A plain Tracer (no harness subclass): what it measures is in the
+    capture, what sampling skipped is not."""
+    import jax
+
+    t = Tracer(sample_every=4)
+    with jax.profiler.trace(str(tmp_path)):
+        drive(t)
+    assert t.summary()["step.host"]["count"] == 2
+    assert _captured_spans(tmp_path).count("step.host") == 2
+
+
+def test_nested_spans_are_both_annotated(tmp_path):
+    import jax
+
+    t = Tracer()
+    with jax.profiler.trace(str(tmp_path)):
+        with t.span("checkpoint.save"):
+            with t.span("retry.sleep"):
+                pass
+    names = _captured_spans(tmp_path)
+    assert names.count("checkpoint.save") == 1
+    assert names.count("retry.sleep") == 1
+
+
+def test_tracer_sums_without_jax_in_the_process(monkeypatch):
+    """The tracer looks JAX up in sys.modules and never imports it: a
+    process without JAX (coordinator, obs CLI, load client) sums spans
+    and annotates nothing; once JAX is there the next event finds it."""
+    jax_mod = sys.modules.get("jax")
+    monkeypatch.delitem(sys.modules, "jax", raising=False)
+    t = Tracer()
+    with t.span("rpc.heartbeat"):
+        pass
+    assert list(t.wrap_iter("step.infeed.wait", [1, 2])) == [1, 2]
+    assert t.timed("step.dispatch", lambda: 7)() == 7
+    assert t._trace_annotation is None
+    assert "jax" not in sys.modules
+    assert t.summary()["rpc.heartbeat"]["count"] == 1
+    if jax_mod is not None:
+        monkeypatch.setitem(sys.modules, "jax", jax_mod)
+        with t.span("rpc.heartbeat"):
+            pass
+        assert t._trace_annotation is jax_mod.profiler.TraceAnnotation
+        assert t.summary()["rpc.heartbeat"]["count"] == 2
+
+
+def test_obs_trace_and_cli_import_without_jax():
+    code = ("import sys; import shifu_tensorflow_tpu.obs.trace, "
+            "shifu_tensorflow_tpu.obs.profile, "
+            "shifu_tensorflow_tpu.obs.__main__; "
+            "assert 'jax' not in sys.modules, 'jax imported'")
+    subprocess.run([sys.executable, "-c", code], check=True,
+                   cwd=os.path.dirname(os.path.dirname(__file__)))
+
+
+@pytest.mark.parametrize("stream", [False, True])
+def test_epoch_turn_is_spanned_journaled_and_annotated(tmp_path, stream):
+    """What the epoch loop does outside train_epoch is `epoch.turn`: not
+    a `step.` name, so it lands under the breakdown's `spans`; with the
+    step's own spans it is in a capture taken around the fit."""
+    import jax
+
+    from shifu_tensorflow_tpu.config.model_config import ModelConfig
+    from shifu_tensorflow_tpu.train import make_trainer
+
+    t = trace_mod.install(Tracer())
+    journal_mod.install(Journal(str(tmp_path / "j.jsonl"), plane="train"))
+    dataset, _ = _tiny_dataset(tmp_path)
+    mc = ModelConfig.from_json(
+        {"train": {"params": {"NumHiddenLayers": 1, "NumHiddenNodes": [4],
+                              "ActivationFunc": ["relu"],
+                              "LearningRate": 0.1}}}
+    )
+    trainer = make_trainer(mc, 2, feature_columns=(1, 2))
+    with jax.profiler.trace(str(tmp_path / "dump")):
+        if stream:
+            trainer.fit_stream(
+                lambda epoch: dataset.train_batches(32, epoch=epoch),
+                epochs=2)
+        else:
+            trainer.fit(dataset, epochs=2, batch_size=32)
+    names = _captured_spans(tmp_path / "dump")
+    # fit: once after each epoch; fit_stream: before and after each
+    assert names.count("epoch.turn") == (4 if stream else 2)
+    assert {"step.dispatch", "step.infeed.wait", "step.infeed.put",
+            "step.block"} <= set(names)
+    # and nothing of the runtime's own (the CPU backend's op events are
+    # dotted lower-case names too: dot_general.19)
+    assert all(n.startswith(("step.", "epoch.", "checkpoint."))
+               for n in names), sorted(set(names))
+    breakdowns = [e for e in read_events(str(tmp_path / "j.jsonl"))
+                  if e["event"] == "step_breakdown"]
+    assert len(breakdowns) == 2
+    # the turn after an epoch closes after that epoch's drain: it is in
+    # the next breakdown (the lag every auxiliary span has)
+    assert breakdowns[1]["spans"]["epoch.turn"]["count"] >= 1
+    assert "epoch.turn" in t.summary()
+
+
 # ---- CLI ----
 
 def _seed_cli_journal(tmp_path) -> str:

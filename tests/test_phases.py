@@ -1,0 +1,393 @@
+"""The train step's phases: the ``jax.named_scope`` names the step is
+written with reach the compiled program as each instruction's ``op_name``,
+and ``obs.profile.phases`` splits a capture's device time by them.
+
+The recorded capture (``tests/fixtures/wdl_criteo_stream_4steps.phases.json.gz``)
+is four steps of the flagship cell on one TPU v5e, as
+``obs.profile.load_capture`` read them from the chip's ``.xplane.pb``
+(its ``origin`` key says which run).  The synthetic capture below is a
+hand-encoded ``XSpace`` in the layout the chip's has: ops with no scope
+of their own on the op line, the scopes in the HLO module under
+``/host:metadata``.
+"""
+
+import gzip
+import io
+import json
+import os
+import re
+import struct
+from contextlib import redirect_stderr, redirect_stdout
+
+import numpy as np
+import pytest
+
+from shifu_tensorflow_tpu.obs import profile as profile_mod
+from shifu_tensorflow_tpu.obs.profile import (
+    COLLECTIVE,
+    PHASE_SCOPES,
+    UNSCOPED,
+    phase_of,
+    reduce_phases,
+)
+
+FIXTURE = os.path.join(os.path.dirname(__file__), "fixtures",
+                       "wdl_criteo_stream_4steps.phases.json.gz")
+
+
+# ---- the scopes in the program ----
+
+def _wdl_trainer(**kw):
+    from shifu_tensorflow_tpu.config.model_config import ModelConfig
+    from shifu_tensorflow_tpu.train.trainer import Trainer
+
+    mc = ModelConfig.from_json({"train": {"params": {
+        "NumHiddenLayers": 2, "NumHiddenNodes": [8, 4],
+        "ActivationFunc": ["relu", "relu"], "LearningRate": 0.01,
+        "Optimizer": "adam", "ModelType": "wide_deep",
+        "WideColumnNums": [3, 4], "CrossHashSize": 64,
+        "EmbeddingColumnNums": [3, 4], "EmbeddingHashSize": 32,
+        "EmbeddingDim": 4}}})
+    return Trainer(mc, 4, feature_columns=(1, 2, 3, 4), **kw)
+
+
+def _batch(*lead):
+    return {"x": np.ones((*lead, 4), np.float32),
+            "y": np.ones((*lead, 1), np.float32),
+            "w": np.ones((*lead, 1), np.float32)}
+
+
+_STEP_TEXTS: dict = {}
+
+
+def _step_texts(path: str) -> dict:
+    """``{"lowered": ..., "compiled": ..., "module": ...}`` of the tiny
+    wide-deep step on one path (built once per path)."""
+    if path not in _STEP_TEXTS:
+        if path == "per_step":
+            trainer = _wdl_trainer()
+            fn, batch = trainer._train_step, _batch(8)
+        elif path == "scan":
+            trainer = _wdl_trainer(scan_steps=2)
+            fn, batch = trainer._scan_epoch, _batch(2, 8)
+        else:
+            trainer = _wdl_trainer(accum_steps=2)
+            fn, batch = trainer._accum_step, _batch(2, 8)
+        lowered = fn.lower(trainer.state, batch)
+        compiled = lowered.compile().as_text()
+        _STEP_TEXTS[path] = {
+            "lowered": lowered.as_text(debug_info=True),
+            "compiled": compiled,
+            "module": re.search(r"^HloModule (\w+)", compiled, re.M).group(1),
+        }
+    return _STEP_TEXTS[path]
+
+
+def _op_names(compiled: str) -> set[str]:
+    return set(re.findall(r'op_name="([^"]+)"', compiled))
+
+
+@pytest.mark.parametrize("path", ["per_step", "scan", "accum"])
+@pytest.mark.parametrize("scope", PHASE_SCOPES)
+def test_compiled_step_carries_every_scope(path, scope):
+    texts = _step_texts(path)
+    assert scope in texts["lowered"]
+    phases = {phase_of(n) for n in _op_names(texts["compiled"])}
+    assert phases & {scope, scope + ".fwd", scope + ".bwd"}, phases
+
+
+@pytest.mark.parametrize("path", ["per_step", "scan", "accum"])
+def test_gather_scope_has_both_directions_and_update_holds_the_cond(path):
+    names = _op_names(_step_texts(path)["compiled"])
+    fwd = [n for n in names if "embed.gather" in n and "transpose(" not in n]
+    bwd = [n for n in names if "embed.gather" in n and "transpose(jvp(" in n]
+    assert any(n.endswith("/gather") for n in fwd), sorted(names)
+    assert any(n.endswith("/scatter-add") for n in bwd), sorted(names)
+    assert {phase_of(n) for n in fwd} == {"embed.gather.fwd"}
+    assert {phase_of(n) for n in bwd} == {"embed.gather.bwd"}
+    # the guard and Adam inside it: optimizer.update/cond/branch_1_fun/...
+    assert any(re.search(r"/optimizer\.update/cond$", n) for n in names)
+    assert any("/optimizer.update/cond/branch_1_fun/" in n for n in names)
+
+
+@pytest.mark.parametrize("path,module", [
+    ("per_step", profile_mod.STEP_PROGRAM),
+    ("scan", "jit_scan_epoch"),
+    ("accum", "jit_accum_step"),
+])
+def test_step_programs_keep_their_module_names(path, module):
+    """The benchmark's ``step_pattern``, PERF.md and ``phases()``'s default
+    read ``jit_train_step``: the jitted functions are not renamed."""
+    assert module == _step_texts(path)["module"]
+    assert profile_mod.STEP_PROGRAM == "jit_train_step"
+
+
+def test_pallas_gather_carries_the_same_scope(pallas_interpret):
+    """Either implementation of the lookup is ``embed.gather``."""
+    import jax
+    import jax.numpy as jnp
+
+    from shifu_tensorflow_tpu.models.embeddings import HashedEmbedding
+
+    model = HashedEmbedding(hash_size=128, features=8, impl="pallas",
+                            shard_table=False)
+    x = jnp.ones((8, 2), jnp.float32)
+    variables = model.init(jax.random.key(0), x)
+
+    def loss(v):
+        return model.apply(v, x).sum()
+
+    text = jax.jit(jax.grad(loss)).lower(variables).as_text(debug_info=True)
+    assert "embed.hash" in text
+    assert re.search(r"jvp\([^\"]*embed\.gather", text), text[:2000]
+    assert re.search(r"transpose\(jvp\([^\"]*embed\.gather", text)
+
+
+@pytest.mark.parametrize("op_name,phase", [
+    ("jit(train_step)/jvp(EmbeddingAugmented)/hashed_columns/embed.gather/"
+     "jit(_take)/gather", "embed.gather.fwd"),
+    ("jit(train_step)/transpose(jvp(EmbeddingAugmented))/hashed_columns/"
+     "embed.gather/jit(_take)/scatter-add", "embed.gather.bwd"),
+    ("jit(train_step)/jvp(loss)/reduce_sum", "loss.fwd"),
+    ("jit(train_step)/transpose(jvp(loss))/mul", "loss.bwd"),
+    ("jit(train_step)/optimizer.update/cond/branch_1_fun/add",
+     "optimizer.update"),
+    ("jit(train_step)/transpose(jvp(EmbeddingAugmented))/base/deep.mlp/deep/"
+     "hidden_layer0/dot_general", "deep.mlp.bwd"),
+    ("jit(scan_epoch)/while/body/jvp(EmbeddingAugmented)/base/wide_cross/"
+     "wide.cross/xor", "wide.cross.fwd"),
+    ("jit(train_step)/jvp(EmbeddingAugmented)/concatenate", UNSCOPED),
+    ("jit(train_step)/reduce_sum", UNSCOPED),
+    ("", UNSCOPED),
+])
+def test_phase_of(op_name, phase):
+    assert phase_of(op_name) == phase
+
+
+# ---- the reduction, on what the chip recorded ----
+
+@pytest.fixture(scope="module")
+def recorded():
+    with gzip.open(FIXTURE, "rt") as f:
+        return json.load(f)
+
+
+def test_recorded_capture_phases_sum_to_the_step(recorded):
+    out = reduce_phases(recorded)
+    assert out["steps"] == 4 and out["devices"] == 1
+    assert 120.0 < out["step_ms"] < 125.0
+    total = sum(out["phases_ms"].values())
+    assert abs(total - out["step_ms"]) < 0.02 * out["step_ms"]
+    # the three phases the next perf_opt PRs rewrite are most of the step
+    top = list(out["phases_ms"])[:3]
+    assert set(top) == {"embed.gather.bwd", "optimizer.update",
+                        "embed.gather.fwd"}
+    assert out["phases_ms"][UNSCOPED] < 0.05 * out["step_ms"]
+    assert out["host_spans"]["step.dispatch"]["count"] >= 4
+
+
+def test_recorded_conditional_is_not_counted_twice(recorded):
+    """``cond.40`` spans ``fusion.7`` (Adam) and seven layout copies that
+    are on the op line too: the raw durations count that time twice, the
+    phases once, and the copies (no ``op_name`` of their own) go to
+    ``optimizer.update`` with the conditional around them."""
+    dev = recorded["devices"]["0"]
+    start, dur = dev["steps"][0]
+    inside = [o for o in dev["ops"]
+              if start <= o[2] and o[2] + o[3] <= start + dur]
+    raw_ms = sum(o[3] for o in inside) / 1e6
+    cond = next(o for o in inside if o[0].startswith("cond."))
+    nested = [o for o in inside if o is not cond
+              and cond[2] <= o[2] and o[2] + o[3] <= cond[2] + cond[3]]
+    assert any(o[0].startswith("copy.") and not o[1] for o in nested)
+    out = reduce_phases({"devices": {"0": {"steps": [[start, dur]],
+                                           "ops": inside}}, "host": []})
+    assert raw_ms > out["step_ms"] * 1.3            # the double count
+    assert sum(out["phases_ms"].values()) <= out["step_ms"]
+    assert abs(out["phases_ms"]["optimizer.update"] - cond[3] / 1e6) < 0.01
+    assert not {o[0] for o in nested} & set(out["unscoped_ops_ms"])
+
+
+def test_reduce_phases_medians_over_steps_then_devices():
+    def dev(scale):
+        ops, steps = [], []
+        for k in range(3):
+            t = k * 1000
+            steps.append([t, 100 * scale])
+            ops += [
+                ["fusion.1", "jit(train_step)/jvp(M)/embed.gather/gather",
+                 t, 10 * scale + k],
+                ["all-reduce.2", "jit(train_step)/transpose(jvp(M))/x",
+                 t + 20 * scale, 5 * scale],
+                ["copy.9", "", t + 40 * scale, 2 * scale],
+            ]
+        return {"steps": steps, "ops": ops}
+
+    out = reduce_phases({"devices": {"0": dev(1), "1": dev(3)}, "host": [
+        ["epoch.turn", 0, 2_000_000], ["epoch.turn", 5, 1_000_000]]})
+    assert out["devices"] == 2 and out["steps"] == 3
+    # median over steps (k = 1), then over the two devices
+    assert out["phases_ms"]["embed.gather.fwd"] == pytest.approx(
+        ((10 + 1) + (30 + 1)) / 2 / 1e6)
+    assert out["phases_ms"][COLLECTIVE] == pytest.approx(10 / 1e6)
+    assert out["phases_ms"][UNSCOPED] == pytest.approx(4 / 1e6)
+    assert out["unscoped_ops_ms"] == {"copy.9": pytest.approx(4 / 1e6)}
+    assert out["step_ms"] == pytest.approx(200 / 1e6)
+    assert out["host_spans"] == {"epoch.turn": {"count": 2,
+                                                "total_s": 0.003}}
+
+
+def test_reduce_phases_without_a_step_program_is_empty():
+    assert reduce_phases({"devices": {}, "host": []}) == {}
+    assert reduce_phases({"devices": {"0": {"steps": [], "ops": [
+        ["fusion.1", "", 0, 5]]}}, "host": [["step.block", 0, 9]]}) == {}
+
+
+# ---- the reading, on a hand-encoded capture ----
+
+def _varint(n: int) -> bytes:
+    out = bytearray()
+    while True:
+        byte, n = n & 0x7F, n >> 7
+        out.append(byte | (0x80 if n else 0))
+        if not n:
+            return bytes(out)
+
+
+def _field(number: int, value) -> bytes:
+    """One protobuf field: an int as a varint, bytes/str length-delimited."""
+    if isinstance(value, int):
+        return _varint(number << 3) + _varint(value)
+    if isinstance(value, str):
+        value = value.encode()
+    return _varint(number << 3 | 2) + _varint(len(value)) + value
+
+
+def _plane(name: str, lines=(), event_names=(), extra=b"") -> bytes:
+    """XPlane: name 2, lines 3, event_metadata 4 (map: key 1, value 2;
+    XEventMetadata id 1, name 2)."""
+    body = _field(2, name) + extra
+    for k, ev_name in enumerate(event_names, start=1):
+        body += _field(4, _field(1, k) + _field(
+            2, _field(1, k) + _field(2, ev_name)))
+    for line_name, events in lines:
+        line = _field(2, line_name) + _field(3, 0)   # timestamp_ns 0
+        for meta_id, start_ns, dur_ns in events:
+            # XEvent: metadata_id 1, offset_ps 2, duration_ps 3
+            line += _field(4, _field(1, meta_id) + _field(2, start_ns * 1000)
+                           + _field(3, dur_ns * 1000))
+        body += _field(3, line)
+    return _field(1, body)
+
+
+def _hlo_metadata_plane(program: str, op_names: dict) -> bytes:
+    """``/host:metadata``: the program's event metadata carries a stat
+    whose bytes value is the HloProto (module 1, computations 3,
+    instructions 2; instruction name 1, metadata 7, op_name 2)."""
+    comp = b"".join(
+        _field(2, _field(1, ins) + _field(7, _field(2, op_name)))
+        for ins, op_name in op_names.items())
+    # a fixed64 field the reader has to step over (wire type 1)
+    hlo = _field(1, _field(3, comp)) + _varint(9 << 3 | 1) + struct.pack(
+        "<d", 1.0)
+    meta = _field(1, 1) + _field(2, program) + _field(
+        5, _field(1, 1) + _field(6, hlo))
+    return _field(1, _field(2, "/host:metadata")
+                  + _field(4, _field(1, 1) + _field(2, meta)))
+
+
+def _write_dump(tmp_path, *, program="jit_train_step(123)") -> str:
+    ops = ["%fusion.2 = f32[8,4]{1,0} fusion(%p0), kind=kLoop",
+           "%cond.4 = (f32[8]) conditional(%p1, %t, %f)",
+           "%fusion.7 = f32[8] fusion(%p2), kind=kLoop",
+           "%copy.3 = f32[8] copy(%p3)",
+           "%all-reduce.1 = f32[8] all-reduce(%p4)",
+           "%copy.9 = f32[8] copy(%p5)"]
+    step_ops = [(1, 0, 30), (2, 30, 50), (3, 32, 20), (4, 55, 10),
+                (5, 80, 10), (6, 95, 5)]
+    events = [(m, 1000 * k + s, d) for k in range(2) for m, s, d in step_ops]
+    device = _plane(
+        "/device:TPU:0",
+        lines=[("XLA Modules", [(7, 0, 100), (7, 1000, 100)]),
+               ("XLA Ops", events)],
+        event_names=ops + [program])
+    host = _plane("/host:CPU",
+                  lines=[("main/1", [(1, 0, 40), (2, 5, 10), (3, 50, 7)])],
+                  event_names=["step.dispatch", "epoch.turn",
+                               "PjitFunction(train_step)"])
+    names = {"fusion.2": "jit(train_step)/jvp(M)/embed.gather/gather",
+             "cond.4": "jit(train_step)/optimizer.update/cond",
+             "fusion.7": "jit(train_step)/optimizer.update/cond/"
+                         "branch_1_fun/add",
+             "all-reduce.1": "jit(train_step)/transpose(jvp(M))/deep.mlp/x"}
+    dump = tmp_path / "dump" / "plugins" / "profile" / "t0"
+    dump.mkdir(parents=True)
+    (dump / "host.xplane.pb").write_bytes(
+        device + host + _hlo_metadata_plane(program, names))
+    return str(tmp_path / "dump")
+
+
+def test_phases_reads_scopes_from_the_capture_itself(tmp_path):
+    out = profile_mod.phases(_write_dump(tmp_path))
+    assert out["step"] == "jit_train_step"
+    assert out["xplane"].endswith("host.xplane.pb")
+    assert out["steps"] == 2 and out["devices"] == 1
+    assert out["step_ms"] == pytest.approx(100 / 1e6)
+    assert out["phases_ms"] == {
+        # the conditional, Adam and the scopeless copy inside it: once
+        "optimizer.update": pytest.approx(50 / 1e6),
+        "embed.gather.fwd": pytest.approx(30 / 1e6),
+        COLLECTIVE: pytest.approx(10 / 1e6),
+        UNSCOPED: pytest.approx(5 / 1e6),
+    }
+    assert out["unscoped_ops_ms"] == {"copy.9": pytest.approx(5 / 1e6)}
+    # the program's spans, not the runtime's own events
+    assert set(out["host_spans"]) == {"step.dispatch", "epoch.turn"}
+
+
+def test_phases_takes_the_newest_capture(tmp_path):
+    dump = _write_dump(tmp_path)
+    older = os.path.join(dump, "plugins", "profile", "t0", "host.xplane.pb")
+    newer = os.path.join(dump, "plugins", "profile", "t1")
+    os.makedirs(newer)
+    with open(os.path.join(newer, "host.xplane.pb"), "wb") as f:
+        f.write(_plane("/host:CPU"))
+    os.utime(older, (1, 1))
+    assert profile_mod.find_xplane(dump).startswith(newer)
+    assert profile_mod.phases(dump) == {}
+
+
+def _cli(*argv) -> tuple[int, str, str]:
+    from shifu_tensorflow_tpu.obs.__main__ import main
+
+    out, err = io.StringIO(), io.StringIO()
+    with redirect_stdout(out), redirect_stderr(err):
+        rc = main(list(argv))
+    return rc, out.getvalue(), err.getvalue()
+
+
+def test_cli_profile_phases_prints_the_split(tmp_path):
+    dump = _write_dump(tmp_path)
+    rc, out, _ = _cli("profile", "--phases", dump)
+    assert rc == 0
+    assert "jit_train_step" in out and "optimizer.update" in out
+    assert "embed.gather.fwd" in out and "epoch.turn" in out
+    rc, out, _ = _cli("profile", "--phases", dump, "--json")
+    assert rc == 0
+    assert json.loads(out)["phases_ms"]["collective"] == pytest.approx(1e-5)
+
+
+@pytest.mark.parametrize("as_json", [False, True])
+def test_cli_profile_phases_exits_1_without_a_step_program(tmp_path, as_json):
+    dump = _write_dump(tmp_path, program="jit_eval_step(5)")
+    rc, out, err = _cli("profile", "--phases", dump,
+                        *(["--json"] if as_json else []))
+    assert rc == 1
+    assert (out.strip() == "{}") if as_json else ("jit_train_step" in err)
+    rc, _, _ = _cli("profile", "--phases", str(tmp_path / "nothing"))
+    assert rc == 1
+
+
+def test_cli_profile_without_journal_or_phases_is_a_usage_error():
+    rc, _, err = _cli("profile")
+    assert rc == 2 and "--journal" in err

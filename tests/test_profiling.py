@@ -7,9 +7,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from shifu_tensorflow_tpu.obs.trace import Tracer
 from shifu_tensorflow_tpu.utils.profiling import (
     StepTimer,
-    annotate,
     trace_if,
     true_sync,
 )
@@ -55,11 +55,27 @@ def test_trace_if_none_is_noop():
 def test_trace_if_writes_profile(tmp_path):
     d = str(tmp_path / "trace")
     with trace_if(d):
-        with annotate("unit-test-region"):
+        with Tracer().span("unit_test.region"):
             jnp.dot(jnp.ones((8, 8)), jnp.ones((8, 8))).block_until_ready()
     # jax writes <dir>/plugins/profile/<ts>/*.xplane.pb
     found = glob.glob(os.path.join(d, "**", "*.xplane.pb"), recursive=True)
     assert found, f"no trace written under {d}"
+
+
+def test_trace_if_capture_holds_the_tracers_spans(tmp_path):
+    """Host-side regions reach the timeline as the obs tracer's spans
+    (what `annotate` was for)."""
+    from shifu_tensorflow_tpu.obs import profile as obs_profile
+
+    d = str(tmp_path / "trace")
+    tracer = Tracer()
+    with trace_if(d):
+        with tracer.span("unit_test.region"):
+            jnp.dot(jnp.ones((8, 8)), jnp.ones((8, 8))).block_until_ready()
+    host = obs_profile.load_capture(obs_profile.find_xplane(d),
+                                    obs_profile.STEP_PROGRAM)["host"]
+    (name, _, dur_ns), = [h for h in host if h[0] == "unit_test.region"]
+    assert 0 < dur_ns / 1e9 <= tracer.summary()[name]["total_s"] * 1.5 + 1e-3
 
 
 def test_trainer_step_timer_integration(model_config_json):
