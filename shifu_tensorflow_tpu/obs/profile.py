@@ -272,9 +272,10 @@ def hlo_op_names(xspace: bytes, step: str) -> dict[str, str]:
     """``{instruction: op_name}`` of the compiled ``step`` program(s), from
     the ``HloProto`` the profiler embeds in the capture's
     ``/host:metadata`` plane (stat ``Hlo Proto`` of the program's event
-    metadata).  On the TPU's op line an event carries no scope (no
-    ``tf_op`` on a ``conditional`` or on the copies XLA inserts), but the
-    module does: ``cond.40 -> jit(train_step)/optimizer.update/cond``.
+    metadata).  On the TPU's op line an event carries no scope, but the
+    module's instructions do: the train step's Adam fusion reads
+    ``jit(train_step)/optimizer.update/jit(_where)/select_n`` (the guard
+    is a select fused into the update, train/trainer.py apply_if_rows).
     Field numbers: tsl ``xplane.proto`` (XSpace.planes 1; XPlane.name 2,
     .event_metadata 4; map value 2; XEventMetadata.name 2, .stats 5;
     XStat.bytes_value 6) and xla ``hlo.proto`` (HloProto.hlo_module 1;
@@ -367,12 +368,14 @@ def _medians_ms(per_device: list[list[dict[str, int]]]) -> dict[str, float]:
 
 def _split_step(ops: list) -> tuple[dict[str, int], dict[str, int]]:
     """One step's device time shared out among phases, each instant once:
-    ``({phase: ns}, {unscoped op: ns})``.  A ``conditional`` and the ops
-    of its branch are both on the op line and overlap, so an instant goes
-    to the innermost op open at it that has a phase; an op without one
-    (a layout copy XLA put inside the conditional) inherits the phase of
-    the op around it, and is ``(unscoped)`` only where nothing around it
-    has one.  ``ops``: ``[name, phase, start_ns, dur_ns]``."""
+    ``({phase: ns}, {unscoped op: ns})``.  The train step's ops each
+    carry their own ``op_name`` and do not nest.  Where a program does
+    hold a ``conditional`` or a ``while``, it and the ops of its body are
+    both on the op line and overlap, so an instant goes to the innermost
+    op open at it that has a phase; an op without one (a copy XLA put
+    inside the body) inherits the phase of the op around it, and is
+    ``(unscoped)`` only where nothing around it has one.  ``ops``:
+    ``[name, phase, start_ns, dur_ns]``."""
     edges = []
     for i, (_, _, start, dur) in enumerate(ops):
         if dur > 0:

@@ -56,7 +56,7 @@ from shifu_tensorflow_tpu.obs import trace as obs_trace
 from shifu_tensorflow_tpu.ops.losses import get_loss, l2_penalty
 from shifu_tensorflow_tpu.parallel.mesh import DATA_AXIS
 from shifu_tensorflow_tpu.train.optimizers import make_base_optimizer
-from shifu_tensorflow_tpu.train.trainer import Trainer
+from shifu_tensorflow_tpu.train.trainer import Trainer, apply_if_rows
 
 from shifu_tensorflow_tpu.parallel.shmap import shard_map
 
@@ -144,17 +144,12 @@ def make_sagn_step(
     @partial(jax.jit, donate_argnums=(0,))
     def sagn_step(state, window_batch):
         avg_grads, loss = window_fn(state.params, window_batch)
-        # all-padding window: skip the update entirely (zero grads would
+        # all-padding window: keep the state as it was (zero grads would
         # still move Adam-style momentum / increment step) and report NaN
         # so epoch means exclude it — same contract as make_train_step
         has_rows = jnp.sum(window_batch["w"] != 0.0) > 0
         with jax.named_scope("optimizer.update"):
-            state = jax.lax.cond(
-                has_rows,
-                lambda s: s.apply_gradients(grads=avg_grads),
-                lambda s: s,
-                state,
-            )
+            state = apply_if_rows(state, avg_grads, has_rows)
         return state, jnp.where(has_rows, loss, jnp.nan)
 
     return obs_compile.observe(sagn_step, "train.sagn_step")

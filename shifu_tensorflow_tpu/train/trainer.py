@@ -514,6 +514,20 @@ def _widen_features(params, x):
     return x
 
 
+def apply_if_rows(state, grads, has_rows):
+    """``state.apply_gradients(grads=grads)`` where the batch has rows, the
+    state as it was (every leaf, bit for bit) where it is all padding.
+
+    Computes the update unconditionally and selects per leaf: the select
+    fuses into the optimizer's element-wise fusion and the leaves keep
+    the layout they rest in.  The one guard of every step builder here
+    and of train/sagn.py; the why is in make_train_step_body."""
+    new = state.apply_gradients(grads=grads)
+    return jax.tree_util.tree_map(
+        lambda n, o: jnp.where(has_rows, n, o), new, state
+    )
+
+
 def make_train_step_body(apply_fn, loss_name: str = "mse", l2: float = 0.0,
                          with_grad_norm: bool = False):
     """The un-jitted (state, batch) -> (state, loss) transition — jitted
@@ -543,17 +557,15 @@ def make_train_step_body(apply_fn, loss_name: str = "mse", l2: float = 0.0,
         # momentum produces nonzero updates even from zero grads — either
         # would let the fixed-step SPMD padding batches (data/dataset.py
         # fixed_step_batches) drift parameters.  The count is over the
-        # GLOBAL batch, so every SPMD process takes the same branch.  The
+        # GLOBAL batch, so every SPMD process selects the same.  The
         # loss reports NaN for such batches so epoch means (nanmean) skip
-        # them instead of being biased toward zero.
+        # them instead of being biased toward zero.  The guard is a select
+        # (apply_if_rows), not a lax.cond: a branch computation's
+        # parameters get XLA's default layout, so a conditional makes every
+        # step copy a big table and its moments into that layout and back.
         has_rows = jnp.sum(batch["w"] != 0.0) > 0
         with jax.named_scope("optimizer.update"):
-            state = jax.lax.cond(
-                has_rows,
-                lambda s: s.apply_gradients(grads=grads),
-                lambda s: s,
-                state,
-            )
+            state = apply_if_rows(state, grads, has_rows)
         loss = jnp.where(has_rows, loss, jnp.nan)
         if with_grad_norm:
             import optax
@@ -608,12 +620,7 @@ def make_host_emb_train_step(apply_fn, raw_width: int,
         )
         has_rows = jnp.sum(batch["w"] != 0.0) > 0
         with jax.named_scope("optimizer.update"):
-            state = jax.lax.cond(
-                has_rows,
-                lambda s: s.apply_gradients(grads=gp),
-                lambda s: s,
-                state,
-            )
+            state = apply_if_rows(state, gp, has_rows)
         g_emb = jnp.where(has_rows, gx[:, raw_width:], 0.0)
         return state, jnp.where(has_rows, loss, jnp.nan), g_emb
 
@@ -695,12 +702,7 @@ def make_accum_step(apply_fn, loss_name: str = "mse", l2: float = 0.0):
             grads = jax.tree_util.tree_map(jnp.add, grads, l2_g)
             loss = loss + l2_loss
         with jax.named_scope("optimizer.update"):
-            state = jax.lax.cond(
-                has_rows,
-                lambda s: s.apply_gradients(grads=grads),
-                lambda s: s,
-                state,
-            )
+            state = apply_if_rows(state, grads, has_rows)
         return state, jnp.where(has_rows, loss, jnp.nan)
 
     return obs_compile.observe(accum_step, "train.accum_step")

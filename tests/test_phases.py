@@ -97,7 +97,7 @@ def test_compiled_step_carries_every_scope(path, scope):
 
 
 @pytest.mark.parametrize("path", ["per_step", "scan", "accum"])
-def test_gather_scope_has_both_directions_and_update_holds_the_cond(path):
+def test_gather_scope_has_both_directions_and_update_holds_the_select(path):
     names = _op_names(_step_texts(path)["compiled"])
     fwd = [n for n in names if "embed.gather" in n and "transpose(" not in n]
     bwd = [n for n in names if "embed.gather" in n and "transpose(jvp(" in n]
@@ -105,9 +105,12 @@ def test_gather_scope_has_both_directions_and_update_holds_the_cond(path):
     assert any(n.endswith("/scatter-add") for n in bwd), sorted(names)
     assert {phase_of(n) for n in fwd} == {"embed.gather.fwd"}
     assert {phase_of(n) for n in bwd} == {"embed.gather.bwd"}
-    # the guard and Adam inside it: optimizer.update/cond/branch_1_fun/...
-    assert any(re.search(r"/optimizer\.update/cond$", n) for n in names)
-    assert any("/optimizer.update/cond/branch_1_fun/" in n for n in names)
+    # Adam and the guard's select beside it, under no conditional:
+    # optimizer.update/add ..., optimizer.update/jit(_where)/select_n
+    update = [n for n in names if "/optimizer.update/" in n]
+    assert any(n.endswith("/optimizer.update/jit(_where)/select_n")
+               for n in update), sorted(update)
+    assert not [n for n in update if "/cond" in n]
 
 
 @pytest.mark.parametrize("path,module", [

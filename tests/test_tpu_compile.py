@@ -109,11 +109,10 @@ def test_embedding_gather_lowers_for_v5e(topo):
 # ----------------------------------------------------- the flagship step
 
 
-def _flagship_step_and_shapes(mesh):
+def _step_and_shapes(mc, columns, mesh=None, with_grad_norm=False):
     """The trainer's own step body and the abstract TrainState / batch it
-    takes at batch 16,384 — built the way ``Trainer.__init__`` builds
-    them, minus everything that needs a device."""
-    import __graft_entry__ as g  # conftest puts the repo root on sys.path
+    takes at ``mc.batch_size`` rows — built the way ``Trainer.__init__``
+    builds them, minus everything that needs a device."""
     from shifu_tensorflow_tpu.models.factory import build_model
     from shifu_tensorflow_tpu.train.optimizers import make_optimizer
     from shifu_tensorflow_tpu.train.trainer import (
@@ -121,26 +120,38 @@ def _flagship_step_and_shapes(mesh):
         make_train_step_body,
     )
 
-    mc = g._flagship_model_config(embedding_hash=1_048_576)
     sharded = mesh is not None and mesh.shape.get("model", 1) > 1
-    model = build_model(mc, tuple(range(g.NUM_FEATURES)),
-                        shard_embeddings=sharded, embedding_impl="xla",
-                        mesh=mesh)
+    model = build_model(mc, columns, shard_embeddings=sharded,
+                        embedding_impl="xla", mesh=mesh)
     tx = make_optimizer(mc.params)
 
     def init():
         params = model.init(jax.random.key(0),
-                            jnp.zeros((1, g.NUM_FEATURES)))["params"]
+                            jnp.zeros((1, len(columns))))["params"]
         state = TrainState.create(apply_fn=model.apply, params=params,
                                   tx=tx)
         return state.replace(step=jnp.asarray(state.step, jnp.int32))
 
-    rows = 16_384
-    batch = {"x": jax.ShapeDtypeStruct((rows, g.NUM_FEATURES), jnp.float32),
+    rows = mc.batch_size
+    batch = {"x": jax.ShapeDtypeStruct((rows, len(columns)), jnp.float32),
              "y": jax.ShapeDtypeStruct((rows, 1), jnp.float32),
              "w": jax.ShapeDtypeStruct((rows, 1), jnp.float32)}
-    body = make_train_step_body(model.apply, "mse", mc.params.l2_reg)
+    body = make_train_step_body(model.apply, "mse", mc.params.l2_reg,
+                                with_grad_norm=with_grad_norm)
     return body, jax.eval_shape(init), batch
+
+
+def _flagship_step_and_shapes(mesh):
+    """The flagship of ``__graft_entry__`` over a 1,048,576-row table, at
+    batch 16,384."""
+    import dataclasses
+
+    import __graft_entry__ as g  # conftest puts the repo root on sys.path
+
+    mc = dataclasses.replace(
+        g._flagship_model_config(embedding_hash=1_048_576),
+        batch_size=16_384)
+    return _step_and_shapes(mc, tuple(range(g.NUM_FEATURES)), mesh)
 
 
 def test_flagship_train_step_lowers_for_one_v5e_chip(topo):
@@ -152,6 +163,39 @@ def test_flagship_train_step_lowers_for_one_v5e_chip(topo):
     # table + Adam mirrors is ~100 MB; a step that asked for gigabytes
     # would mean the gather or its gradient densified somewhere
     assert mem.temp_size_in_bytes < 2 << 30
+
+
+def test_table_step_keeps_its_resting_layout_on_one_v5e_chip(topo):
+    """The benchmark cell's step (``benchmark/configs/wdl_criteo.json``:
+    wide_deep, 4,194,304 x 32 hashed table, batch 16,384, the health
+    guard's gradient norm) must update the table in the layout it rests
+    in.  A ``lax.cond`` around the update makes XLA copy table, moments
+    and gradient into the branch's row-major layout (the 32-wide minor
+    dimension padded to 128 lanes) and back: seven copies and 9.67 GB of
+    temporaries a step.  The guard's select leaves the dense gradient's
+    0.54 GB and no such copy."""
+    import json
+    import re
+
+    from shifu_tensorflow_tpu.config.model_config import ModelConfig
+
+    cell = os.path.join(os.path.dirname(__file__), os.pardir, "benchmark",
+                        "configs", "wdl_criteo.json")
+    with open(cell) as f:
+        config = json.load(f)
+    mc = ModelConfig.from_json(config["model_config"])
+    table = (mc.params.embedding_hash_size, mc.params.embedding_dim)
+    assert (mc.batch_size, table) == (16_384, (4_194_304, 32))
+    features = config["data"]["numeric"] + config["data"]["categorical"]
+    body, state, batch = _step_and_shapes(
+        mc, tuple(range(1, features + 1)), with_grad_norm=True)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    compiled = jax.jit(body, donate_argnums=(0,)).lower(
+        _on(one_chip, state), _on(one_chip, batch)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+    text = compiled.as_text()
+    assert "conditional" not in text
+    assert not re.findall(r"= f32\[%d,%d\]\{1,0[^}]*\} copy\(" % table, text)
 
 
 def test_flagship_train_step_lowers_for_the_2x2_mesh(topo):

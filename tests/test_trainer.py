@@ -5,14 +5,20 @@ synthetic PSV dataset, checkpoint/resume epoch accounting, mesh-sharded DP
 import jax
 import jax.numpy as jnp
 import numpy as np
+import optax
 import pytest
 
 from shifu_tensorflow_tpu.config.model_config import ModelConfig
 from shifu_tensorflow_tpu.data.dataset import InMemoryDataset
 from shifu_tensorflow_tpu.data.reader import RecordSchema
-from shifu_tensorflow_tpu.ops.losses import weighted_bce, weighted_mse
+from shifu_tensorflow_tpu.ops.losses import (
+    l2_penalty,
+    weighted_bce,
+    weighted_mse,
+)
 from shifu_tensorflow_tpu.parallel.mesh import make_mesh
 from shifu_tensorflow_tpu.train.checkpoint import Checkpointer
+from shifu_tensorflow_tpu.train import sagn, trainer as trainer_mod
 from shifu_tensorflow_tpu.train.trainer import Trainer
 
 
@@ -270,6 +276,86 @@ def test_scan_epoch_tail_padding_counts():
     a = jax.device_get(trainer.state.params["shifu_output_0"]["kernel"])
     b = jax.device_get(t_ref.state.params["shifu_output_0"]["kernel"])
     np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-6)
+
+
+# ---- the all-padding guard, held by every step builder ----
+
+_GUARD_L2 = 0.01  # l2 gradients are nonzero on an all-padding batch
+
+# every step that holds the guard: (build(apply_fn), stacked), where
+# stacked = the batch takes a leading axis of one microbatch / window / step
+_GUARDED_STEPS = [
+    pytest.param(lambda f: trainer_mod.make_train_step(f, "mse", _GUARD_L2),
+                 False, id="per_batch"),
+    pytest.param(lambda f: trainer_mod.make_train_step(
+        f, "mse", _GUARD_L2, with_grad_norm=True),
+                 False, id="per_batch_grad_norm"),
+    pytest.param(lambda f: trainer_mod.make_scan_epoch(f, "mse", _GUARD_L2),
+                 True, id="scan"),
+    pytest.param(lambda f: trainer_mod.make_accum_step(f, "mse", _GUARD_L2),
+                 True, id="accumulation"),
+    pytest.param(lambda f: sagn.make_sagn_step(
+        f, optax.sgd(0.1), loss_name="mse", l2=_GUARD_L2),
+                 True, id="sagn"),
+    pytest.param(lambda f: trainer_mod.make_host_emb_train_step(
+        f, 4, "mse", _GUARD_L2),
+                 False, id="host_embedding"),
+]
+
+
+@pytest.mark.parametrize("build,stacked", _GUARDED_STEPS)
+def test_all_padding_batch_keeps_every_leaf_and_rows_apply_gradients(
+        build, stacked):
+    """The guard is one select over the whole state (``apply_if_rows``):
+    an all-padding batch leaves EVERY leaf bit-identical (step, optax
+    count, mu, nu, parameters) though Adam and the l2 term would move
+    them, and reports NaN; a batch with rows gives what
+    ``state.apply_gradients`` gives when called directly."""
+    trainer = Trainer(_mc(epochs=1), 6, seed=3)
+    apply_fn = trainer.model.apply
+    step = build(apply_fn)
+    rng_ = np.random.default_rng(11)
+    x = rng_.normal(size=(32, 6)).astype(np.float32)
+    y = (rng_.random((32, 1)) < 0.4).astype(np.float32)
+
+    def run(state, w):
+        batch = {"x": x, "y": y, "w": w}
+        if stacked:
+            batch = {k: v[None] for k, v in batch.items()}
+        # the steps donate their state: hand each a copy of its own
+        out = step(jax.tree_util.tree_map(jnp.copy, state), batch)
+        return out[0], jax.tree_util.tree_leaves(out[1:])
+
+    # one real update first, so mu, nu, count and step are all nonzero
+    state, _ = run(trainer.state, np.ones((32, 1), np.float32))
+    before = jax.device_get(state)
+    assert int(before.step) == 1
+
+    kept, aux = run(state, np.zeros((32, 1), np.float32))
+    assert np.isnan(np.asarray(aux[0])).all()  # the loss(es)
+    for extra in aux[1:]:  # gradient norm / embedding gradients
+        assert not np.asarray(extra).any()
+    old, new = (jax.tree_util.tree_leaves_with_path(t)
+                for t in (before, jax.device_get(kept)))
+    assert len(old) == len(new) == 20  # step, count, 6 each of p, mu, nu
+    for (path, a), (_, b) in zip(old, new):
+        assert a.dtype == b.dtype and a.tobytes() == b.tobytes(), path
+
+    w = (rng_.random((32, 1)) < 0.8).astype(np.float32) * 2.0
+
+    def loss(params):
+        pred = apply_fn({"params": params}, x)
+        return weighted_mse(pred, y, w) + l2_penalty(params, _GUARD_L2)
+
+    want = jax.device_get(state.apply_gradients(
+        grads=jax.grad(loss)(state.params)))
+    got, aux = run(state, w)
+    assert np.isfinite(np.asarray(aux[0])).all()
+    assert int(got.step) == int(want.step) == 2
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(want),
+                            jax.tree_util.tree_leaves(jax.device_get(got))):
+        np.testing.assert_allclose(b, a, rtol=2e-5, atol=1e-7,
+                                   err_msg=str(path))
 
 
 def test_scan_epoch_fixed_shape_and_timer_rows():
