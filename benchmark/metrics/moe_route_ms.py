@@ -1,0 +1,20 @@
+"""``moe_route_ms`` — layer: models models/ ops/.  Unit ``ms``, source
+``device_trace``; should move ``train_rows_per_s``.
+
+Device ms a step inside ``moe.route``, forward + backward summed (the
+backward's recomputed forward included): gate, top-k, the sort of the
+(token, choice) pairs by expert and the tile plan, of every expert layer.
+From ``obs.profile.phases`` on the run's own capture, handed on by the
+plane; ``None`` on a reading without the phase.
+"""
+
+LAYER = "models models/ ops/"
+UNIT = "ms"
+SOURCE = "device_trace"
+MOVES = "train_rows_per_s"
+
+from benchmark.lm_readings import phase_ms
+
+
+def read(r):
+    return phase_ms(r, "moe.route")

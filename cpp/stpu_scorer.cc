@@ -559,6 +559,12 @@ Scorer* build_scorer(const std::string& dir, std::string* err) {
     return nullptr;
   }
 
+  if (model_type == "hybrid_lm") {
+    *err = "ModelType='hybrid_lm' is a training-only family: the native "
+           "scorer has no path for it";
+    return nullptr;
+  }
+
   auto scorer = std::make_unique<Scorer>();
   scorer->num_features =
       static_cast<long>(num_of(arch.get("num_features"), 0));

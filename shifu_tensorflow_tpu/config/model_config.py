@@ -31,6 +31,132 @@ def _parse_bool(v: Any) -> bool:
     return str(v).strip().lower() in ("true", "1", "yes", "on")
 
 
+class UnsupportedModelType(ValueError):
+    """A plane that cannot run a model family refuses it by name: export,
+    ``EvalModel``, ``serve/``, ``score/`` and the C++ scorer score one
+    fixed-width row to one ``(B, 1)`` sigmoid, which a causal language
+    model is not."""
+
+    def __init__(self, model_type: str, plane: str):
+        self.model_type, self.plane = model_type, plane
+        super().__init__(
+            f"ModelType={model_type!r} is a training-only family: "
+            f"{plane} has no path for it (no scorer, artifact or serving "
+            "contract exists for a per-token model)")
+
+
+#: families that train through ``Trainer.fit_stream`` and nothing else
+TRAINING_ONLY_MODEL_TYPES = ("hybrid_lm",)
+
+
+def require_servable(model_type: str, plane: str) -> None:
+    if str(model_type).lower() in TRAINING_ONLY_MODEL_TYPES:
+        raise UnsupportedModelType(str(model_type).lower(), plane)
+
+
+@dataclass(frozen=True)
+class HybridLMConfig:
+    """``ModelType: hybrid_lm`` — the keys of a public ``nemotron_h``
+    ``config.json`` under their own names (``train.params`` carries them
+    beside ``ModelType``), plus the share this chip holds.
+
+    ``n_routed_experts`` is the router's width (all the experts there
+    are); ``experts_held`` = (first id, count) the experts whose weights
+    live here; ``vocab_size`` is the slice of the vocabulary the embedding
+    and the head hold (a sliced vocabulary is a smaller vocabulary)."""
+
+    hidden_size: int
+    hybrid_override_pattern: str
+    vocab_size: int
+    layer_norm_epsilon: float = 1e-5
+    initializer_range: float = 0.02
+    # Mamba-2
+    mamba_num_heads: int = 64
+    mamba_head_dim: int = 64
+    n_groups: int = 8
+    ssm_state_size: int = 128
+    conv_kernel: int = 4
+    chunk_size: int = 128
+    time_step_min: float = 0.001
+    time_step_max: float = 0.1
+    time_step_floor: float = 1e-4
+    # experts
+    n_routed_experts: int = 8
+    num_experts_per_tok: int = 2
+    moe_intermediate_size: int = 64
+    moe_shared_expert_intermediate_size: int = 64
+    n_shared_experts: int = 1
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    experts_held: tuple[int, int] = (0, 0)  # (first id, count); 0 = all
+    # attention
+    num_attention_heads: int = 4
+    num_key_value_heads: int = 1
+    head_dim: int = 16
+
+    @classmethod
+    def from_json(cls, params: Mapping[str, Any]) -> "HybridLMConfig":
+        import dataclasses
+
+        known = {f.name: f for f in dataclasses.fields(cls)}
+        missing = [k for k in ("hidden_size", "hybrid_override_pattern",
+                               "vocab_size") if k not in params]
+        if missing:
+            raise ValueError(
+                f"ModelType=hybrid_lm needs train.params {missing}")
+        kw: dict[str, Any] = {}
+        for name, f in known.items():
+            if name not in params:
+                continue
+            v = params[name]
+            if name == "experts_held":
+                kw[name] = (int(v[0]), int(v[1]))
+            elif f.type in ("int",):
+                kw[name] = int(v)
+            elif f.type in ("float",):
+                kw[name] = float(v)
+            elif f.type in ("bool",):
+                kw[name] = _parse_bool(v)
+            else:
+                kw[name] = str(v)
+        cfg = cls(**kw)
+        if cfg.experts_held[1] <= 0:
+            cfg = dataclasses.replace(
+                cfg, experts_held=(0, cfg.n_routed_experts))
+        cfg.validate(params)
+        return cfg
+
+    def validate(self, params: Mapping[str, Any] = ()) -> None:
+        bad = set(self.hybrid_override_pattern) - set("ME*")
+        if bad or not self.hybrid_override_pattern:
+            raise ValueError(
+                "hybrid_override_pattern is a string of M (Mamba-2), E "
+                f"(experts) and * (attention); got {sorted(bad)}")
+        layers = params.get("num_hidden_layers") if params else None
+        if layers is not None and int(layers) != len(
+                self.hybrid_override_pattern):
+            raise ValueError(
+                f"num_hidden_layers={layers} but hybrid_override_pattern "
+                f"has {len(self.hybrid_override_pattern)} characters")
+        for key in ("n_group", "topk_group"):
+            if params and int(params.get(key, 1)) != 1:
+                raise ValueError(
+                    f"{key}={params[key]}: group-limited routing is not "
+                    "implemented (the family routes over all experts)")
+        first, count = self.experts_held
+        if first < 0 or first + count > self.n_routed_experts:
+            raise ValueError(
+                f"experts_held={list(self.experts_held)} lies outside the "
+                f"router's {self.n_routed_experts} experts")
+        if self.num_experts_per_tok > self.n_routed_experts:
+            raise ValueError("num_experts_per_tok > n_routed_experts")
+        if self.mamba_num_heads % self.n_groups:
+            raise ValueError("n_groups must divide mamba_num_heads")
+        if self.num_attention_heads % self.num_key_value_heads:
+            raise ValueError(
+                "num_key_value_heads must divide num_attention_heads")
+
+
 @dataclass(frozen=True)
 class TrainParams:
     """``train.params`` — network-shape hyperparameters."""
@@ -48,7 +174,10 @@ class TrainParams:
     # convergence parity; opt in via train.params.L2Reg.
     l2_reg: float = 0.0
     # ---- extensions beyond the reference (BASELINE.json configs) ----
-    model_type: str = "dnn"  # dnn | wide_deep | multi_task | sequence
+    # dnn | wide_deep | multi_task | sequence | hybrid_lm
+    model_type: str = "dnn"
+    # ModelType "hybrid_lm" (models/hybrid_lm.py): the public config keys
+    hybrid_lm: "HybridLMConfig | None" = None
     wide_column_nums: tuple[int, ...] = ()  # crossed/categorical cols for wide part
     cross_hash_size: int = 0  # >0: hashed-cross table for the wide part
     num_tasks: int = 1  # >1 => multi-task sigmoid heads sharing the trunk
@@ -75,6 +204,14 @@ class TrainParams:
     # activations instead of storing them — the standard long-context
     # memory lever (jax.checkpoint via nn.remat)
     seq_remat: bool = False
+
+    @property
+    def features_carry_ids(self) -> bool:
+        """Whether any feature column carries an integer the model reads
+        exactly — a category code that feeds a hash, or a token id that
+        indexes an embedding.  bfloat16 (8-bit mantissa) rounds integers
+        above 256, so such rows stream as float32."""
+        return self.uses_feature_hashing or self.model_type == "hybrid_lm"
 
     @property
     def uses_feature_hashing(self) -> bool:
@@ -132,6 +269,9 @@ class TrainParams:
             embedding_dim=int(params.get("EmbeddingDim", 8)),
             embedding_placement=str(
                 params.get("EmbeddingPlacement", "device")).lower(),
+            hybrid_lm=(HybridLMConfig.from_json(params)
+                       if str(params.get("ModelType", "dnn")).lower()
+                       == "hybrid_lm" else None),
             seq_len=int(params.get("SeqLen", 0)),
             seq_d_model=int(params.get("SeqDModel", 64)),
             seq_heads=int(params.get("SeqHeads", 4)),

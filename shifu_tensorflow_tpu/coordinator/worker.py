@@ -915,7 +915,7 @@ def _feature_dtype_for(cfg) -> str:
 
     return resolve_stream_feature_dtype(
         cfg.stream_feature_dtype,
-        uses_feature_hashing=cfg.model_config.params.uses_feature_hashing,
+        uses_feature_hashing=cfg.model_config.params.features_carry_ids,
         has_normalization_stats=bool(cfg.schema.means),
     )
 

@@ -59,7 +59,10 @@ def resolve_stream_feature_dtype(setting: str | None, *,
       ids are computed from raw float bits; bf16 rounding of category
       codes > 256 would re-bucket them, skewing training against the
       f32-hashing exported scorer.  An explicit bfloat16 request refuses
-      loudly rather than silently skewing;
+      loudly rather than silently skewing.  Rows of token ids
+      (ModelType=hybrid_lm) are the same case — an id above 256 would
+      round to another token — and callers pass
+      ``TrainParams.features_carry_ids`` here;
     - no ZSCALE normalization stats (``has_normalization_stats=False``):
       z-scaled features are O(1) where bf16's 8-bit mantissa is plenty,
       but RAW features (un-normalized numeric codes, large-magnitude
@@ -76,9 +79,10 @@ def resolve_stream_feature_dtype(setting: str | None, *,
     if s == "bfloat16" and uses_feature_hashing:
         raise ValueError(
             "shifu.tpu.stream-feature-dtype=bfloat16 is unsafe with "
-            "hashed feature columns: bucket ids are computed from raw "
-            "float bits, and bf16 rounding re-buckets category codes "
-            "> 256 — use auto (streams float32 for hashing models)"
+            "hashed feature columns or token ids: bucket ids are computed "
+            "from raw float bits and ids index an embedding, and bf16 "
+            "rounding moves integers > 256 — use auto (streams float32 "
+            "for such models)"
         )
     if s not in ("float32", "bfloat16"):
         raise ValueError(

@@ -45,7 +45,10 @@ import numpy as np
 
 from shifu_tensorflow_tpu.export.bucketing import bucket_size, pad_rows
 
-from shifu_tensorflow_tpu.config.model_config import ModelConfig
+from shifu_tensorflow_tpu.config.model_config import (
+    ModelConfig,
+    require_servable,
+)
 from shifu_tensorflow_tpu.export.saved_model import (
     GENERIC_CONFIG,
     INPUT_NAME,
@@ -96,6 +99,7 @@ class EvalModel:
         arch = json.loads(fs.read_text(os.path.join(self.model_dir, NATIVE_ARCH)))
         self.num_features = int(arch["num_features"])
         mc = ModelConfig.from_json(arch["model_config"])
+        require_servable(mc.params.model_type, "EvalModel (serve, score)")
         feature_columns = tuple(arch.get("feature_columns") or ())
         self._model = build_model(mc, feature_columns or None)
         # both layouts: flat npz, or a mesh-aware export's shard files

@@ -232,6 +232,9 @@ def export_native_bundle(
     the serialized executables under ``aot/``, digested into the
     manifest like every artifact: serve admission then deserializes
     instead of compiling, falling back per bucket on any mismatch."""
+    from shifu_tensorflow_tpu.config.model_config import require_servable
+
+    require_servable(model_config.params.model_type, "export")
     fs.mkdirs(export_dir)
     arch = {
         "format_version": 1,
@@ -615,8 +618,10 @@ def export_model(
     """
     import copy
 
+    from shifu_tensorflow_tpu.config.model_config import require_servable
     from shifu_tensorflow_tpu.models.factory import build_model
 
+    require_servable(trainer.model_config.params.model_type, "export")
     if feature_columns is None:
         # the training graph's column positions ARE the serving contract;
         # fall back to what the trainer was built with

@@ -72,6 +72,22 @@ def build_model(
     nodes = p.num_hidden_nodes[: p.num_hidden_layers]
     acts = p.activation_funcs[: p.num_hidden_layers]
 
+    if p.model_type == "hybrid_lm":
+        import jax
+
+        from shifu_tensorflow_tpu.models.hybrid_lm import HybridLM
+        from shifu_tensorflow_tpu.models.sequence import make_attention
+
+        # the Pallas kernel has no partitioning rule and no CPU lowering;
+        # the chunked scan is plain XLA and runs anywhere
+        single = mesh is None or mesh.size == 1
+        impl = ("flash" if single and jax.default_backend() == "tpu"
+                else "chunked")
+        return HybridLM(
+            cfg=p.hybrid_lm,
+            attention=make_attention(impl, None, causal=True),
+            dtype=dtype,
+        )
     if p.seq_len > 0 and p.model_type != "sequence":
         raise ValueError(
             f"SeqLen={p.seq_len} conflicts with ModelType={p.model_type!r}: "
@@ -141,3 +157,16 @@ def build_model(
     # widens the base model's input with the gathered embeddings, so the
     # device graph here is just the base net over the augmented features
     return base
+
+
+def family_loss(model: nn.Module):
+    """The loss a model family brings itself, or ``None`` for the families
+    whose loss is ``ops/losses.py``'s on a ``(B, 1)`` prediction: a
+    callable ``(params, batch) -> (loss, per-row loss (B, 1), counters)``
+    that the trainer's step builders differentiate in place of
+    ``get_loss(name)(apply(x), y, w)`` (train/trainer.py)."""
+    from shifu_tensorflow_tpu.models import hybrid_lm
+
+    if isinstance(model, hybrid_lm.HybridLM):
+        return hybrid_lm.batch_loss(model)
+    return None

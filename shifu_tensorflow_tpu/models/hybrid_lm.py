@@ -1,0 +1,412 @@
+"""Hybrid decoder family (``ModelType: hybrid_lm``): a causal language
+model whose layers are each ONE mixer in a pre-norm residual,
+``x <- x + mixer(RMSNorm(x))``, the mixer chosen per layer by a pattern
+string as the public ``nemotron_h`` configuration writes it:
+
+- ``M``  Mamba-2 (``ssm.conv`` + ``ssm.scan``: ops/ssm_scan.py's chunked
+  scan), gate before the grouped RMSNorm;
+- ``E``  routed experts (sigmoid gate over ALL ``n_routed_experts``, top-k
+  of score + correction bias, weights normalised over the k chosen and
+  scaled) + one shared expert, all ``W_down relu(W_up h)^2``.  The layer is
+  told which experts it holds (``experts_held`` = first id, count): it
+  routes over all of them, computes the held experts' part for the tokens
+  that chose them (ops/grouped.py: sort, grouped products, unsort) and
+  leaves out what the absent experts would add.  No token is dropped;
+- ``*``  causal grouped-query attention, no rotary embedding (the Mamba
+  layers carry position).
+
+Ingest compatibility (as models/sequence.py): a PSV row carries its
+``S`` token ids in the float32 feature block; the model casts them on
+device.  The loss is the family's own — mean next-token cross-entropy over
+the rows whose weight is not 0 — so the module exposes :meth:`loss`
+beside ``__call__`` (logits) and the trainer's step builders take it
+through ``models/factory.py`` ``family_loss``.  Every layer and the head
+are rematerialised in the backward pass.
+
+The phase names (``jax.named_scope``; obs/profile.py ``PHASE_SCOPES``):
+``embed.gather``, ``ssm.proj`` (in/out projections, gate and grouped
+norm), ``ssm.conv``, ``ssm.scan``, ``moe.route``, ``moe.experts``,
+``moe.shared``, ``attn.proj`` (q, k, v, o), ``attn.core``, ``lm.head``.  The
+residual stream's own norms and adds carry no scope.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Any, Callable
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from shifu_tensorflow_tpu.config.model_config import HybridLMConfig
+from shifu_tensorflow_tpu.ops import grouped, ssm_scan
+
+#: rows of a grouped-product tile.  A tile costs its rows' products or the
+#: read of its expert's weights, whichever is longer (2688 x 1856 float32
+#: twice: 40 MB, as long as ~480 rows' products on a v5e).  Sized for the
+#: deployed load, 16 data-parallel chips' 6,144 pairs an expert a step:
+#: 1,024 rows are twice the products a weight read hides and pad half a
+#: tile in six.  The one-chip benchmark cell sends an expert 384-650 pairs,
+#: where this size multiplies more padding than rows; PERF.md section 6
+#: has both sizes' numbers there, for a perf_opt to choose by
+EXPERT_TILE = 1024
+
+
+def _normal(std):
+    return nn.initializers.normal(stddev=std)
+
+
+def _matmul(x, kernel, dtype):
+    return jnp.dot(x.astype(dtype), kernel.astype(dtype))
+
+
+class RMSNorm(nn.Module):
+    eps: float
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
+                           jnp.float32)
+        x = x.astype(self.dtype)
+        inv = jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True)
+                            + jnp.asarray(self.eps, self.dtype))
+        return x * inv * scale.astype(self.dtype)
+
+
+class Weights(nn.Module):
+    """``<name>/kernel`` of a given shape, handed back as it is."""
+
+    shape: tuple
+    std: float
+
+    @nn.compact
+    def __call__(self):
+        return self.param("kernel", _normal(self.std), self.shape,
+                          jnp.float32)
+
+
+class Kernel(nn.Module):
+    """A bias-free projection whose parameter is ``<name>/kernel``."""
+
+    features: int
+    std: float
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        kernel = self.param("kernel", _normal(self.std),
+                            (x.shape[-1], self.features), jnp.float32)
+        return _matmul(x, kernel, self.dtype)
+
+
+def _dt_bias_init(cfg: HybridLMConfig):
+    """Inverse softplus of a log-uniform draw in [time_step_min,
+    time_step_max], floored: the published Mamba-2 initialisation."""
+    def init(key, shape, dtype=jnp.float32):
+        u = jax.random.uniform(key, shape, jnp.float32)
+        dt = jnp.exp(u * (math.log(cfg.time_step_max)
+                          - math.log(cfg.time_step_min))
+                     + math.log(cfg.time_step_min))
+        dt = jnp.maximum(dt, cfg.time_step_floor)
+        return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+
+    return init
+
+
+class ConvParams(nn.Module):
+    width: int
+    channels: int
+
+    @nn.compact
+    def __call__(self):
+        bound = 1.0 / math.sqrt(self.width)  # torch's Conv1d default
+        kernel = self.param(
+            "kernel",
+            lambda k, s, d=jnp.float32: jax.random.uniform(
+                k, s, d, -bound, bound),
+            (self.width, self.channels), jnp.float32)
+        bias = self.param("bias", nn.initializers.zeros, (self.channels,),
+                          jnp.float32)
+        return kernel, bias
+
+
+class MambaMixer(nn.Module):
+    cfg: HybridLMConfig
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        c, dt_ = self.cfg, self.dtype
+        heads, hd, groups, n = (c.mamba_num_heads, c.mamba_head_dim,
+                                c.n_groups, c.ssm_state_size)
+        inner = heads * hd
+        conv_dim = inner + 2 * groups * n
+        bsz, s, _ = x.shape
+        with jax.named_scope("ssm.proj"):
+            zxbcdt = Kernel(inner + conv_dim + heads, c.initializer_range,
+                            dt_, name="in_proj")(x)
+        z = zxbcdt[..., :inner]
+        xbc = zxbcdt[..., inner:inner + conv_dim]
+        dt = zxbcdt[..., inner + conv_dim:]
+        kernel, bias = ConvParams(c.conv_kernel, conv_dim, name="conv")()
+        with jax.named_scope("ssm.conv"):
+            padded = jnp.pad(xbc, ((0, 0), (c.conv_kernel - 1, 0), (0, 0)))
+            conv = bias.astype(dt_)
+            for j in range(c.conv_kernel):
+                conv = conv + padded[:, j:j + s] * kernel[j].astype(dt_)
+            xbc = nn.silu(conv)
+        xs = xbc[..., :inner].reshape(bsz, s, heads, hd)
+        b = xbc[..., inner:inner + groups * n].reshape(bsz, s, groups, n)
+        cc = xbc[..., inner + groups * n:].reshape(bsz, s, groups, n)
+        dt_bias = self.param("dt_bias", _dt_bias_init(c), (heads,),
+                             jnp.float32)
+        a_log = self.param(
+            "A_log",
+            lambda k, s_, d=jnp.float32: jnp.log(
+                jnp.arange(1, s_[0] + 1, dtype=d)),
+            (heads,), jnp.float32)
+        d_skip = self.param("D", nn.initializers.ones, (heads,), jnp.float32)
+        with jax.named_scope("ssm.scan"):
+            dt = nn.softplus(dt + dt_bias.astype(dt_))
+            y = ssm_scan.ssm_scan_chunked(
+                xs, dt, -jnp.exp(a_log), b, cc, c.chunk_size)
+            y = y.astype(dt_) + d_skip.astype(dt_)[:, None] * xs
+        with jax.named_scope("ssm.proj"):
+            y = y.reshape(bsz, s, inner) * nn.silu(z)
+            # grouped RMSNorm, the gate before the norm
+            y = GroupNormScale(groups, c.layer_norm_epsilon, dt_,
+                               name="norm")(y)
+            return Kernel(c.hidden_size, c.initializer_range, dt_,
+                          name="out_proj")(y)
+
+
+class GroupNormScale(nn.Module):
+    """RMSNorm over each of ``groups`` slices of the last axis, then one
+    scale over the whole axis."""
+
+    groups: int
+    eps: float
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, y):
+        scale = self.param("scale", nn.initializers.ones, (y.shape[-1],),
+                           jnp.float32)
+        g = y.reshape(y.shape[:-1] + (self.groups, -1))
+        g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True)
+                              + jnp.asarray(self.eps, self.dtype))
+        return g.reshape(y.shape) * scale.astype(self.dtype)
+
+
+class ExpertWeights(nn.Module):
+    held: int
+    width: int
+    std: float
+
+    @nn.compact
+    def __call__(self, hidden: int):
+        up = self.param("up", _normal(self.std),
+                        (self.held, hidden, self.width), jnp.float32)
+        down = self.param("down", _normal(self.std),
+                          (self.held, self.width, hidden), jnp.float32)
+        return up, down
+
+
+class SharedExpert(nn.Module):
+    width: int
+    std: float
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        h = Kernel(self.width, self.std, self.dtype, name="up")(x)
+        h = jnp.square(nn.relu(h))
+        return Kernel(x.shape[-1], self.std, self.dtype, name="down")(h)
+
+
+class MoEMixer(nn.Module):
+    """Returns ``(out, (pairs on held experts, largest held expert's
+    pairs))``: the counters ride the step's auxiliary output."""
+
+    cfg: HybridLMConfig
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        c, dt_ = self.cfg, self.dtype
+        first, held = c.experts_held
+        bsz, s, d = x.shape
+        flat = x.reshape(-1, d)
+        with jax.named_scope("moe.route"):
+            # the gate is float32 at full precision whatever the step's
+            # dtype: a rounded score flips choices between near-tied experts
+            w_r = Weights((d, c.n_routed_experts), c.initializer_range,
+                          name="router")()
+            scores = nn.sigmoid(jnp.dot(
+                flat.astype(jnp.float32), w_r,
+                precision=jax.lax.Precision.HIGHEST))
+            bias = self.param("e_score_correction_bias",
+                              nn.initializers.zeros, (c.n_routed_experts,),
+                              jnp.float32)
+            _, ids = jax.lax.top_k(scores + bias, c.num_experts_per_tok)
+            weights = jnp.take_along_axis(scores, ids, axis=-1)
+            if c.norm_topk_prob:
+                weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
+                                     + 1e-20)
+            weights = weights * c.routed_scaling_factor
+            pair, tile_expert, n_tiles, counts = grouped.plan_tiles(
+                ids, first, held, EXPERT_TILE)
+            k = c.num_experts_per_tok
+            token = jnp.where(pair < ids.size, pair // k, flat.shape[0])
+            gate = jnp.where(
+                pair < ids.size,
+                jnp.take(weights.reshape(-1), pair, mode="clip"), 0.0)
+        up, down = ExpertWeights(held, c.moe_intermediate_size,
+                                 c.initializer_range, name="experts")(d)
+        with jax.named_scope("moe.experts"):
+            routed = grouped.expert_mlp(
+                flat.astype(dt_), up.astype(dt_), down.astype(dt_), token,
+                gate, tile_expert, n_tiles, EXPERT_TILE)
+        with jax.named_scope("moe.shared"):
+            shared = SharedExpert(
+                c.moe_shared_expert_intermediate_size * c.n_shared_experts,
+                c.initializer_range, dt_, name="shared")(flat)
+        out = (routed.astype(dt_) + shared).reshape(bsz, s, d)
+        return out, jnp.stack([jnp.sum(counts), jnp.max(counts)])
+
+
+class AttentionMixer(nn.Module):
+    cfg: HybridLMConfig
+    attention: Callable
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        c, dt_ = self.cfg, self.dtype
+        nq, nkv, hd = c.num_attention_heads, c.num_key_value_heads, c.head_dim
+        bsz, s, _ = x.shape
+        std = c.initializer_range
+        with jax.named_scope("attn.proj"):
+            q = Kernel(nq * hd, std, dt_, name="q_proj")(x).reshape(
+                bsz, s, nq, hd)
+            k = Kernel(nkv * hd, std, dt_, name="k_proj")(x).reshape(
+                bsz, s, nkv, hd)
+            v = Kernel(nkv * hd, std, dt_, name="v_proj")(x).reshape(
+                bsz, s, nkv, hd)
+        with jax.named_scope("attn.core"):
+            # each KV head serves nq / nkv query heads: the repeat's
+            # transpose sums their gradients
+            k = jnp.repeat(k, nq // nkv, axis=2)
+            v = jnp.repeat(v, nq // nkv, axis=2)
+            y = self.attention(q, k, v)
+        with jax.named_scope("attn.proj"):
+            return Kernel(c.hidden_size, std, dt_, name="o_proj")(
+                y.reshape(bsz, s, nq * hd))
+
+
+class Layer(nn.Module):
+    kind: str
+    cfg: HybridLMConfig
+    attention: Callable
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        h = RMSNorm(self.cfg.layer_norm_epsilon, self.dtype, name="norm")(x)
+        stats = jnp.zeros((2,), jnp.int32)
+        if self.kind == "M":
+            y = MambaMixer(self.cfg, self.dtype, name="mixer")(h)
+        elif self.kind == "E":
+            y, stats = MoEMixer(self.cfg, self.dtype, name="mixer")(h)
+        else:
+            y = AttentionMixer(self.cfg, self.attention, self.dtype,
+                               name="mixer")(h)
+        return x + y, stats
+
+
+class LMHead(nn.Module):
+    """Untied head + next-token cross-entropy (``lm.head``): ``__call__``
+    gives ``(sum of the live rows' token losses, per-row mean loss (B,))``
+    and is what the backward pass rematerialises; ``logits`` the scores."""
+
+    vocab: int
+    hidden: int
+    std: float
+    dtype: Any = jnp.float32
+
+    def setup(self):
+        self.kernel = self.param("kernel", _normal(self.std),
+                                 (self.hidden, self.vocab), jnp.float32)
+
+    def logits(self, h):
+        return _matmul(h, self.kernel, self.dtype).astype(jnp.float32)
+
+    def __call__(self, h, ids, live):
+        with jax.named_scope("lm.head"):
+            logp = jax.nn.log_softmax(self.logits(h[:, :-1]), axis=-1)
+            nll = -jnp.take_along_axis(logp, ids[:, 1:, None], axis=-1)[..., 0]
+            return jnp.sum(nll * live[:, None]), jnp.mean(nll, axis=-1)
+
+
+class HybridLM(nn.Module):
+    """``__call__(x)``: logits (B, S, vocab held).  ``loss(x, w)``: what the
+    trainer differentiates (see :func:`batch_loss`)."""
+
+    cfg: HybridLMConfig
+    attention: Callable
+    dtype: Any = jnp.float32
+
+    def setup(self):
+        c = self.cfg
+        self.embed = nn.Embed(c.vocab_size, c.hidden_size,
+                              embedding_init=_normal(c.initializer_range),
+                              param_dtype=jnp.float32)
+        layer = nn.remat(Layer)
+        self.layers = [layer(kind, c, self.attention, self.dtype)
+                       for kind in c.hybrid_override_pattern]
+        self.final_norm = RMSNorm(c.layer_norm_epsilon, self.dtype)
+        self.lm_head = nn.remat(LMHead)(c.vocab_size, c.hidden_size,
+                                        c.initializer_range, self.dtype)
+
+    def hidden(self, x):
+        """(final-normed hidden states (B, S, d), ids (B, S), counters)."""
+        ids = x.astype(jnp.int32)
+        with jax.named_scope("embed.gather"):
+            h = jnp.take(self.embed.embedding, ids, axis=0).astype(self.dtype)
+        stats = jnp.zeros((2,), jnp.int32)
+        for layer in self.layers:
+            h, s = layer(h)
+            stats = jnp.stack([stats[0] + s[0], jnp.maximum(stats[1], s[1])])
+        return self.final_norm(h), ids, stats
+
+    def __call__(self, x):
+        h, _, _ = self.hidden(x)
+        return self.lm_head.logits(h)
+
+    def loss(self, x, w):
+        """``(mean token loss over the live rows, per-row mean loss (B, 1),
+        counters)``: a row of weight 0 is padding and joins neither sum."""
+        h, ids, stats = self.hidden(x)
+        live = (w.reshape(-1) != 0.0).astype(jnp.float32)
+        total, per_row = self.lm_head(h, ids, live)
+        count = jnp.sum(live) * (ids.shape[1] - 1)
+        return total / jnp.maximum(count, 1.0), per_row[:, None], stats
+
+
+#: what the counters of a step are called where they surface
+COUNTER_NAMES = ("moe_held_pairs", "moe_held_max")
+
+
+def batch_loss(model: HybridLM):
+    """``(params, batch) -> (loss, per-row loss, {counter: value})`` for
+    the trainer's step builders.  The counters sum (pairs that chose a held
+    expert) and take the largest (pairs on one held expert) over the
+    step's expert layers."""
+    def fn(params, batch):
+        loss, per_row, stats = model.apply(
+            {"params": params}, batch["x"], batch["w"], method="loss")
+        return loss, per_row, dict(zip(COUNTER_NAMES, stats))
+
+    return fn

@@ -33,6 +33,7 @@ Attention selection (``train.params.SeqAttention``):
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Callable, Sequence
 
 import flax.linen as nn
@@ -133,12 +134,15 @@ def make_attention(
     *,
     seq_len: int = 0,
     num_heads: int = 0,
+    causal: bool = False,
 ) -> AttentionFn:
     """Resolve ``SeqAttention`` to a callable; 'auto' picks ring iff the
     mesh has a 'seq' axis of size > 1.  Shape constraints (seq axis must
     divide SeqLen; Ulysses additionally needs it to divide SeqHeads) are
     validated HERE so misconfiguration is a config error naming the keys,
-    not an opaque shard_map/all_to_all trace failure."""
+    not an opaque shard_map/all_to_all trace failure.  ``causal`` masks
+    keys after the query (the single-device implementations; the decoder
+    family, models/hybrid_lm.py, passes it)."""
     from shifu_tensorflow_tpu.parallel import ring
 
     seq_axis = mesh.shape.get(ring.SEQ_AXIS, 1) if mesh is not None else 1
@@ -152,20 +156,29 @@ def make_attention(
         else:
             impl = "full"
     if impl == "full":
+        if causal:
+            return partial(ring.full_attention, causal=True)
         return ring.full_attention
     if impl == "chunked":
         def attention(q, k, v):
             return ring.chunked_attention(
-                q, k, v, block_size=_chunked_block())
+                q, k, v, causal=causal, block_size=_chunked_block())
 
         return attention
     if impl == "flash":
         from shifu_tensorflow_tpu.ops.pallas import flash_attention as fa
 
         def attention(q, k, v, _f=fa.flash_attention):
+            if causal:
+                return _f(q, k, v, True, CAUSAL_FLASH_BLOCK,
+                          CAUSAL_FLASH_BLOCK)
             return _f(q, k, v)
 
         return attention
+    if causal:
+        raise ValueError(
+            f"SeqAttention={impl!r} has no causal form here "
+            "(full | chunked | flash)")
     if impl in ("ring", "ulysses"):
         if not has_seq:
             raise ValueError(
@@ -216,6 +229,13 @@ def _chunked_min_seq() -> int:
         return int(os.environ.get("STPU_CHUNKED_MIN_SEQ", "0"))
     except ValueError:
         return 0
+
+
+#: query and key rows of a flash-kernel tile on the causal path.  The
+#: kernel's own default, 128, makes a grid step per 128 x 128 scores: at
+#: S 4,096 that is 1,024 steps a head and pass, and the steps' overhead
+#: outweighs their products; 512 keeps a tile's scores at 1 MB of VMEM.
+CAUSAL_FLASH_BLOCK = 512
 
 
 def _chunked_block() -> int:

@@ -180,11 +180,18 @@ def _capture(out_dir: str, seconds: float) -> None:
 
 # ---- reading a dump: the step's phases ----
 
-#: the ``jax.named_scope`` names the step is written with
-#: (models/embeddings.py, models/wide_deep.py, train/trainer.py,
-#: train/sagn.py); tests/test_phases.py holds the lowered step to them
-PHASE_SCOPES = ("embed.hash", "embed.gather", "wide.cross", "deep.mlp",
-                "loss", "optimizer.update")
+#: the ``jax.named_scope`` names the step is written with: the tabular
+#: families' (models/embeddings.py, models/wide_deep.py, train/trainer.py,
+#: train/sagn.py) and the decoder family's (models/hybrid_lm.py, whose
+#: token embedding is ``embed.gather`` too); tests/test_phases.py holds
+#: each family's lowered step to its names
+TABULAR_SCOPES = ("embed.hash", "embed.gather", "wide.cross", "deep.mlp",
+                  "loss", "optimizer.update")
+HYBRID_LM_SCOPES = ("embed.gather", "ssm.proj", "ssm.conv", "ssm.scan",
+                    "moe.route", "moe.experts", "moe.shared", "attn.proj",
+                    "attn.core", "lm.head", "optimizer.update")
+PHASE_SCOPES = TABULAR_SCOPES + tuple(
+    s for s in HYBRID_LM_SCOPES if s not in TABULAR_SCOPES)
 #: the per-step program's module name (``jax.jit`` of ``train_step``);
 #: for the scan or accumulate path pass ``jit_scan_epoch`` / ``jit_accum_step``
 STEP_PROGRAM = "jit_train_step"

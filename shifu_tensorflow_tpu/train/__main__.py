@@ -727,7 +727,7 @@ def run_single(args, conf, model_config: ModelConfig, schema: RecordSchema) -> i
                     conf.get(K.STREAM_FEATURE_DTYPE,
                              K.DEFAULT_STREAM_FEATURE_DTYPE),
                     uses_feature_hashing=(
-                        model_config.params.uses_feature_hashing),
+                        model_config.params.features_carry_ids),
                     has_normalization_stats=bool(schema.means),
                 )
                 # staged-ingest knobs (shifu.tpu.data-*): explicit values

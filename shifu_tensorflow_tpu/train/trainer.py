@@ -39,7 +39,7 @@ from shifu_tensorflow_tpu.data.dataset import (
     close_stream,
     prefetch_to_device,
 )
-from shifu_tensorflow_tpu.models.factory import build_model
+from shifu_tensorflow_tpu.models.factory import build_model, family_loss
 from shifu_tensorflow_tpu.obs import journal as obs_journal
 from shifu_tensorflow_tpu.obs import compile as obs_compile
 from shifu_tensorflow_tpu.obs import fleet as _obs_fleet
@@ -528,8 +528,26 @@ def apply_if_rows(state, grads, has_rows):
     )
 
 
+def prediction_loss(apply_fn, loss_name: str = "mse"):
+    """The loss of the families that predict one ``(B, 1)`` score a row, in
+    the form the step builders differentiate: ``(params, batch) -> (loss,
+    prediction, None)`` with ``ops/losses.py``'s ``loss_name`` on
+    ``apply_fn``'s prediction.  A family that brings its own loss
+    (models/factory.py ``family_loss``) hands in the same form, with the
+    per-row loss for a prediction and its step counters for ``None``."""
+    loss_fn = get_loss(loss_name)
+
+    def batch_loss(params, batch):
+        pred = apply_fn({"params": params},
+                        _widen_features(params, batch["x"]))
+        with jax.named_scope("loss"):
+            return loss_fn(pred, batch["y"], batch["w"]), pred, None
+
+    return batch_loss
+
+
 def make_train_step_body(apply_fn, loss_name: str = "mse", l2: float = 0.0,
-                         with_grad_norm: bool = False):
+                         with_grad_norm: bool = False, batch_loss=None):
     """The un-jitted (state, batch) -> (state, loss) transition — jitted
     per-batch by make_train_step, lax.scan'ed over stacked batches by
     make_scan_epoch.  One definition, so the two paths cannot drift.
@@ -539,19 +557,28 @@ def make_train_step_body(apply_fn, loss_name: str = "mse", l2: float = 0.0,
     cheap on-device reduction over gradients the step already computed,
     letting the guard catch an exploding/NaN gradient before the loss
     itself goes non-finite.
+
+    ``batch_loss`` is the seam for a family that brings its own loss
+    (:func:`prediction_loss` says the form; models/factory.py
+    ``family_loss`` finds it): it is differentiated in place of
+    ``loss_name`` on ``apply_fn``'s ``(B, 1)`` prediction, and the step's
+    auxiliary output gains its counters: ``(state, (loss, counters))``, or
+    ``(state, (loss, global_grad_norm, counters))``.  Guard, norm and
+    update are the same.
     """
-    loss_fn = get_loss(loss_name)
+    if batch_loss is None:
+        batch_loss = prediction_loss(apply_fn, loss_name)
 
     def compute_loss(params, batch):
-        pred = apply_fn({"params": params}, _widen_features(params, batch["x"]))
-        with jax.named_scope("loss"):
-            loss = loss_fn(pred, batch["y"], batch["w"])
-            if l2:
+        loss, _, counters = batch_loss(params, batch)
+        if l2:
+            with jax.named_scope("loss"):
                 loss = loss + l2_penalty(params, l2)
-        return loss
+        return loss, counters
 
     def train_step(state: TrainState, batch: Batch):
-        loss, grads = jax.value_and_grad(compute_loss)(state.params, batch)
+        (loss, counters), grads = jax.value_and_grad(
+            compute_loss, has_aux=True)(state.params, batch)
         # An all-padding (weight-0) batch must be a true no-op: the data
         # loss is 0 but the l2 term still has gradients, and Adam-style
         # momentum produces nonzero updates even from zero grads — either
@@ -571,14 +598,18 @@ def make_train_step_body(apply_fn, loss_name: str = "mse", l2: float = 0.0,
             import optax
 
             gnorm = jnp.where(has_rows, optax.global_norm(grads), 0.0)
+            if counters is not None:
+                return state, (loss, gnorm, counters)
             return state, (loss, gnorm)
+        if counters is not None:
+            return state, (loss, counters)
         return state, loss
 
     return train_step
 
 
 def make_train_step(apply_fn, loss_name: str = "mse", l2: float = 0.0,
-                    with_grad_norm: bool = False):
+                    with_grad_norm: bool = False, batch_loss=None):
     """Build the jitted SPMD train step.
 
     state is donated (its device buffers are reused in place — every
@@ -589,7 +620,8 @@ def make_train_step(apply_fn, loss_name: str = "mse", l2: float = 0.0,
     single-chip and multi-chip).
     """
     body = make_train_step_body(apply_fn, loss_name, l2,
-                                with_grad_norm=with_grad_norm)
+                                with_grad_norm=with_grad_norm,
+                                batch_loss=batch_loss)
     return obs_compile.observe(jax.jit(body, donate_argnums=(0,)),
                                "train.step")
 
@@ -708,15 +740,17 @@ def make_accum_step(apply_fn, loss_name: str = "mse", l2: float = 0.0):
     return obs_compile.observe(accum_step, "train.accum_step")
 
 
-def make_eval_step_body(apply_fn, loss_name: str = "mse"):
+def make_eval_step_body(apply_fn, loss_name: str = "mse", batch_loss=None):
     """Un-jitted (params, batch) -> (loss, pred) — shared by the per-batch
     eval step and the device-resident scanned eval, so the all-padding
-    NaN contract cannot drift between them."""
-    loss_fn = get_loss(loss_name)
+    NaN contract cannot drift between them.  With a family's own
+    ``batch_loss`` (make_train_step_body) ``pred`` is the per-row loss
+    ``(B, 1)``: such a family has no score to rank."""
+    if batch_loss is None:
+        batch_loss = prediction_loss(apply_fn, loss_name)
 
     def eval_step(params, batch: Batch):
-        pred = apply_fn({"params": params}, _widen_features(params, batch["x"]))
-        loss = loss_fn(pred, batch["y"], batch["w"])
+        loss, pred, _ = batch_loss(params, batch)
         has_rows = jnp.sum(batch["w"] != 0.0) > 0
         return jnp.where(has_rows, loss, jnp.nan), pred
 
@@ -746,9 +780,9 @@ def _sketch_fit_scope(fn):
     return wrapper
 
 
-def make_eval_step(apply_fn, loss_name: str = "mse"):
+def make_eval_step(apply_fn, loss_name: str = "mse", batch_loss=None):
     return obs_compile.observe(
-        jax.jit(make_eval_step_body(apply_fn, loss_name)),
+        jax.jit(make_eval_step_body(apply_fn, loss_name, batch_loss)),
         "train.eval_step")
 
 
@@ -911,6 +945,22 @@ class Trainer:
         self.tx = make_optimizer(model_config.params)
         self.loss_name = loss
         self.seed = seed
+        # a family with a loss of its own (a language model's per-token
+        # cross-entropy): the per-step path differentiates it; the other
+        # epoch paths are written around a (B, 1) prediction
+        self._batch_loss = family_loss(self.model)
+        #: the last epoch's step counters of such a family, ``{name:
+        #: [value per step]}`` (host numpy), else ``{}``
+        self.epoch_counters: dict = {}
+        if self._batch_loss is not None and (
+                self.scan_steps > 1 or self.accum_steps > 1
+                or self._host_emb is not None or p.algorithm == "sagn"
+                or (mesh is not None and mesh.size > 1)):
+            raise ValueError(
+                f"ModelType={p.model_type!r} trains on the per-step path "
+                "of one device: drop scan-steps / accum-steps / "
+                "Algorithm=sagn / EmbeddingPlacement=host, and give a "
+                "mesh of one device (--mesh none)")
 
         # host-embedding runs widen the device model's input with the
         # gathered embeddings; num_features stays the RAW feature count
@@ -919,7 +969,12 @@ class Trainer:
             len(self._host_emb_pos) * p.embedding_dim
             if self._host_emb is not None else 0
         )
-        params = self.model.init(
+        init = self.model.init
+        if self._batch_loss is not None:
+            # one program: run eagerly, a deep model's init compiles and
+            # launches every op of a forward pass whose result it drops
+            init = jax.jit(init)
+        params = init(
             jax.random.key(seed),
             jnp.zeros((1, self._model_input_width), dtype)
         )["params"]
@@ -975,7 +1030,8 @@ class Trainer:
         )
 
         self._train_step = make_train_step(
-            self.model.apply, loss, model_config.params.l2_reg
+            self.model.apply, loss, model_config.params.l2_reg,
+            batch_loss=self._batch_loss,
         )
         # training-health guard (shifu.tpu.health-*): divergence/hang
         # detection + the coordinator's rollback directives.  The guard
@@ -999,7 +1055,7 @@ class Trainer:
         self._health_step = (
             make_train_step(
                 self.model.apply, loss, model_config.params.l2_reg,
-                with_grad_norm=True,
+                with_grad_norm=True, batch_loss=self._batch_loss,
             )
             if (self.health_guard is not None and health.check_finite
                 and self.scan_steps == 1 and self.accum_steps == 1
@@ -1013,7 +1069,8 @@ class Trainer:
             )
             if self._host_emb is not None else None
         )
-        self._eval_step = make_eval_step(self.model.apply, loss)
+        self._eval_step = make_eval_step(self.model.apply, loss,
+                                         self._batch_loss)
         # chunked-scan epochs (conf key shifu.tpu.scan-steps, validated
         # at the top of __init__): batches per lax.scan dispatch; 1 = the
         # plain per-step path.  accum_steps (shifu.tpu.accum-steps):
@@ -1285,16 +1342,22 @@ class Trainer:
             return self._train_epoch_accum(batches)
         losses = []
         gnorms = []
+        counters = []
         step_fn = self._health_step or self._train_step
         feed = self._infeed(batches, self._put, tracer)
         try:
             for batch in feed:
                 with obs_trace.maybe_span(tracer, "step.dispatch"):
-                    if self._health_step is not None:
-                        self.state, (loss, gnorm) = step_fn(self.state, batch)
-                        gnorms.append(gnorm)
-                    else:
-                        self.state, loss = step_fn(self.state, batch)
+                    self.state, out = step_fn(self.state, batch)
+                if self._batch_loss is not None:
+                    *out, step_counters = out
+                    counters.append(step_counters)
+                    out = out[0] if len(out) == 1 else tuple(out)
+                if self._health_step is not None:
+                    loss, gnorm = out
+                    gnorms.append(gnorm)
+                else:
+                    loss = out
                 losses.append(loss)
                 if guard is not None:
                     guard.tick()
@@ -1308,6 +1371,11 @@ class Trainer:
             vals = np.asarray(jax.device_get(losses))
             gvals = (np.asarray(jax.device_get(gnorms))
                      if gnorms else None)
+            if counters:
+                fetched = jax.device_get(counters)
+                self.epoch_counters = {
+                    k: np.asarray([c[k] for c in fetched])
+                    for k in fetched[0]}
         if guard is not None:
             guard.note_losses(vals, gvals, mode="aligned")
         # all-padding batches report NaN by contract (make_train_step);
@@ -1927,6 +1995,15 @@ class Trainer:
             self._infeed_root = None
             close_stream(source)
 
+    def _no_validation(self) -> dict[str, float]:
+        """What an epoch without a validation pass reports: KS 0 / AUC 0.5
+        (no skill) for a ranked family, absent (NaN) for a family with a
+        loss of its own, which has no score to rank."""
+        if self._batch_loss is not None:
+            return {"loss": float("nan"), "ks": float("nan"),
+                    "auc": float("nan")}
+        return {"loss": float("nan"), "ks": 0.0, "auc": 0.5}
+
     def _evaluate_inner(self, batches: Iterable[Batch]) -> dict[str, float]:
         losses, scores, labels, weights = [], [], [], []
         if self._cross_process:
@@ -1963,16 +2040,19 @@ class Trainer:
             finally:
                 close_stream(feed)
         if not losses:
-            return {"loss": float("nan"), "ks": 0.0, "auc": 0.5}
+            return self._no_validation()
         s = np.concatenate(scores)[:, 0]
         y = np.concatenate(labels)[:, 0]
         w = np.concatenate(weights)[:, 0]
         vals = np.asarray(jax.device_get(losses))
         real = vals[~np.isnan(vals)]
+        # a family with its own loss has no (B, 1) score to rank: KS and
+        # AUC are absent (NaN), not computed from something else
+        ranked = self._batch_loss is None
         return {
             "loss": float(np.mean(real)) if real.size else float("nan"),
-            "ks": M.ks_statistic(s, y, w),
-            "auc": M.auc(s, y, w),
+            "ks": M.ks_statistic(s, y, w) if ranked else float("nan"),
+            "auc": M.auc(s, y, w) if ranked else float("nan"),
         }
 
     def _epoch_stats(self, epoch: int, train_loss: float, ev: dict,
@@ -2076,6 +2156,11 @@ class Trainer:
         through fit_stream; this path is for datasets that fit in HBM
         (demo/eval scale, the reference's own regime).
         """
+        if self._batch_loss is not None:
+            raise ValueError(
+                "fit_device_resident scans the (B, 1)-prediction step: "
+                f"ModelType={self.model_config.params.model_type!r} trains "
+                "through fit / fit_stream")
         if self._cross_process:
             raise ValueError(
                 "fit_device_resident is single-controller; multi-process "
@@ -2155,7 +2240,7 @@ class Trainer:
             train_loss = float(np.mean(real)) if real.size else float("nan")
             train_time = time.time() - t0
 
-            ev = {"loss": float("nan"), "ks": 0.0, "auc": 0.5}
+            ev = self._no_validation()
             valid_time = 0.0
             if eval_fn is not None:
                 t1 = time.time()
@@ -2306,7 +2391,7 @@ class Trainer:
                             else self.tracer.take_summary())
                 with obs_trace.maybe_span(self.tracer, "epoch.turn"):
                     autotuner.observe_epoch(summ)
-            ev = {"loss": float("nan"), "ks": 0.0, "auc": 0.5}
+            ev = self._no_validation()
             valid_time = 0.0
             if make_valid_stream is not None:
                 t1 = time.time()

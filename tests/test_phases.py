@@ -25,7 +25,9 @@ import pytest
 from shifu_tensorflow_tpu.obs import profile as profile_mod
 from shifu_tensorflow_tpu.obs.profile import (
     COLLECTIVE,
+    HYBRID_LM_SCOPES,
     PHASE_SCOPES,
+    TABULAR_SCOPES,
     UNSCOPED,
     phase_of,
     reduce_phases,
@@ -88,12 +90,57 @@ def _op_names(compiled: str) -> set[str]:
 
 
 @pytest.mark.parametrize("path", ["per_step", "scan", "accum"])
-@pytest.mark.parametrize("scope", PHASE_SCOPES)
+@pytest.mark.parametrize("scope", TABULAR_SCOPES)
 def test_compiled_step_carries_every_scope(path, scope):
     texts = _step_texts(path)
     assert scope in texts["lowered"]
     phases = {phase_of(n) for n in _op_names(texts["compiled"])}
     assert phases & {scope, scope + ".fwd", scope + ".bwd"}, phases
+
+
+def _lm_step_text() -> str:
+    """The compiled per-step program of a tiny ``hybrid_lm`` trainer (the
+    health guard's step, as the CLI builds it)."""
+    if "lm" not in _STEP_TEXTS:
+        from shifu_tensorflow_tpu.config.model_config import ModelConfig
+        from shifu_tensorflow_tpu.train.trainer import HealthConfig, Trainer
+
+        mc = ModelConfig.from_json({"train": {"params": {
+            "ModelType": "hybrid_lm", "Optimizer": "adam",
+            "LearningRate": 1e-3, "hidden_size": 32,
+            "hybrid_override_pattern": "ME*", "vocab_size": 64,
+            "mamba_num_heads": 2, "mamba_head_dim": 8, "n_groups": 1,
+            "ssm_state_size": 8, "chunk_size": 8, "n_routed_experts": 4,
+            "experts_held": [0, 2], "num_experts_per_tok": 2,
+            "moe_intermediate_size": 16,
+            "moe_shared_expert_intermediate_size": 16,
+            "num_attention_heads": 2, "num_key_value_heads": 1,
+            "head_dim": 8}}})
+        trainer = Trainer(mc, 16, health=HealthConfig())
+        batch = {"x": np.ones((2, 16), np.float32),
+                 "y": np.ones((2, 1), np.float32),
+                 "w": np.ones((2, 1), np.float32)}
+        _STEP_TEXTS["lm"] = trainer._health_step.lower(
+            trainer.state, batch).compile().as_text()
+    return _STEP_TEXTS["lm"]
+
+
+@pytest.mark.parametrize("scope", HYBRID_LM_SCOPES)
+def test_compiled_lm_step_carries_every_scope(scope):
+    text = _lm_step_text()
+    assert re.search(r"^HloModule (\w+)", text, re.M).group(1) == \
+        profile_mod.STEP_PROGRAM
+    phases = {phase_of(n) for n in _op_names(text)}
+    assert phases & {scope, scope + ".fwd", scope + ".bwd"}, phases
+
+
+def test_phase_scopes_are_both_families_and_each_name_once():
+    assert set(PHASE_SCOPES) == set(TABULAR_SCOPES) | set(HYBRID_LM_SCOPES)
+    assert len(set(PHASE_SCOPES)) == len(PHASE_SCOPES)
+    assert phase_of("jit(train_step)/transpose(jvp(HybridLM))/layers_0/"
+                    "checkpoint/mixer/ssm.scan/mul") == "ssm.scan.bwd"
+    assert phase_of("jit(train_step)/jvp(HybridLM)/layers_1/mixer/"
+                    "moe.experts/while/body/dot_general") == "moe.experts.fwd"
 
 
 @pytest.mark.parametrize("path", ["per_step", "scan", "accum"])

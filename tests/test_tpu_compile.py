@@ -91,6 +91,77 @@ def test_flash_attention_lowers_for_v5e(topo, grad, seq_len, head_dim,
     assert _kernels(compiled) == (3 if grad else 1)
 
 
+# the language-model cell's kernels at its published widths
+# (benchmark/configs/nemotron3_nano_ep16.json): 2 rows x 4,096 tokens
+
+
+def test_causal_grouped_query_flash_lowers_at_the_lm_cells_shape(topo):
+    """32 query heads over 2 KV heads of 128 at S 4,096, through
+    ``make_attention("flash", causal=True)`` with its 512-row tiles:
+    forward, dQ and dK/dV kernels, the KV heads repeated outside."""
+    from shifu_tensorflow_tpu.models.sequence import make_attention
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    attention = make_attention("flash", None, causal=True)
+    q = jax.ShapeDtypeStruct((2, 4096, 32, 128), jnp.float32,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((2, 4096, 2, 128), jnp.float32,
+                              sharding=one_chip)
+
+    def loss(q, k, v):
+        k, v = (jnp.repeat(x, 16, axis=2) for x in (k, v))
+        return jnp.sum(attention(q, k, v) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, kv, kv).compile()
+    assert _kernels(compiled) == 3
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+
+
+def test_chunked_scan_lowers_at_the_lm_cells_shape(topo):
+    """64 heads of 64, state 128, 8 groups, chunk 128, forward + backward:
+    no (S, S) anything, under 3 GB of temporaries."""
+    from shifu_tensorflow_tpu.ops.ssm_scan import ssm_scan_chunked
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def on(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    def loss(x, dt, a, b, c):
+        return jnp.sum(ssm_scan_chunked(x, dt, a, b, c, 128) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2, 3, 4))).lower(
+        on(2, 4096, 64, 64), on(2, 4096, 64), on(64),
+        on(2, 4096, 8, 128), on(2, 4096, 8, 128)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 << 30
+
+
+def test_grouped_experts_lower_at_the_lm_cells_shape(topo):
+    """8 held experts of 2688 x 1856 over the 49,152 (token, choice) pairs
+    of a step: the tile walk is a ``while`` in both directions and the
+    buffers are the tokens', not the worst case's."""
+    from shifu_tensorflow_tpu.ops import grouped
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def on(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(h, up, down, weights, ids):
+        pair, tile_expert, n_tiles, _ = grouped.plan_tiles(ids, 0, 8, 512)
+        token = jnp.where(pair < ids.size, pair // 6, h.shape[0])
+        gate = jnp.where(pair < ids.size,
+                         jnp.take(weights.reshape(-1), pair, mode="clip"), 0.)
+        return jnp.sum(grouped.expert_mlp(h, up, down, token, gate,
+                                          tile_expert, n_tiles, 512) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2, 3))).lower(
+        on((8192, 2688)), on((8, 2688, 1856)), on((8, 1856, 2688)),
+        on((8192, 6)), on((8192, 6), jnp.int32)).compile()
+    assert compiled.as_text().count(" while(") >= 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
 def test_embedding_gather_lowers_for_v5e(topo):
     """81,920 ids (16,384 rows x 5 hashed columns) into the 1,048,576 x 8
     table of the flagship: gather forward, scatter-add backward."""
