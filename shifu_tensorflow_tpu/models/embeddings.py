@@ -12,7 +12,16 @@ lookup implementations:
 
 - ``xla``   — hash + ``jnp.take``; under pjit the table's
   ``nn.partitioning`` annotation shards it over the 'model' axis and XLA
-  handles the collective lookup;
+  handles the collective lookup.  The lookup's BACKWARD is its own
+  (``take_rows`` below, ``ops/embedding_grad.py``), not XLA's transpose
+  of the take: the lookups are sorted so that those of one table row are
+  neighbours, and their gradient rows are added into lines of whole
+  lanes (where XLA's scatter reads and writes each distinct line once)
+  that a last pass turns into the table's layout.  On a mesh each device
+  does that for its own lookups and its own rows under ``shard_map``, and
+  the sum over 'data' is the dense all-reduce.  Rows that do not tile a
+  128-lane line (``EmbeddingDim`` other than 8, 16, 32 or 64) keep XLA's
+  transpose;
 - ``pallas`` — the fused hash/one-hot-matmul TPU kernel
   (ops/pallas/embedding.py) for the replicated-table case, keeping the
   gather on the MXU.
@@ -24,11 +33,19 @@ default 0 = never — see the constant's docstring); xla everywhere else.
 
 from __future__ import annotations
 
+import functools
+
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax import lax
+from jax.sharding import PartitionSpec as P
 
 from shifu_tensorflow_tpu.ops import hashing
+from shifu_tensorflow_tpu.ops.embedding_grad import dense_row_grad, rows_a_line
+from shifu_tensorflow_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
+from shifu_tensorflow_tpu.parallel.sharding import clamp_spec
+from shifu_tensorflow_tpu.parallel.shmap import shard_map
 
 # re-exports kept for callers that used the old locations
 hash_to_buckets = hashing.hash_to_buckets
@@ -84,6 +101,66 @@ def _resolve_impl(impl: str, sharded: bool, hash_size: int = 0) -> str:
     return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 
+TABLE_SPEC = P(MODEL_AXIS, None)  # what ``shard_table`` annotates
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def take_rows(table: jax.Array, ids: jax.Array, mesh=None,
+              shard_table: bool = True) -> jax.Array:
+    """``jnp.take(table, ids, axis=0)`` for a flat ``ids (N,)``, whose
+    backward is ``ops/embedding_grad.py``'s ``dense_row_grad``.  ``mesh``
+    is the mesh the enclosing ``jit`` partitions over, or ``None`` on one
+    device; ``shard_table`` says whether the table rests split over its
+    'model' axis."""
+    return jnp.take(table, ids, axis=0)
+
+
+def _take_rows_fwd(table, ids, mesh, shard_table):
+    # the table rides along for its shape alone: nothing reads its values
+    return take_rows(table, ids, mesh, shard_table), (table, ids)
+
+
+def _take_rows_bwd(mesh, shard_table, residuals, rows):
+    table, ids = residuals
+    return _table_grad(mesh, shard_table, table, ids, rows), None
+
+
+take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+def _table_grad(mesh, shard_table, table, ids, rows):
+    num_rows, dim = table.shape
+    if not rows_a_line(dim):
+        return jnp.zeros_like(table).at[ids].add(
+            rows)  # what XLA makes of take's transpose, partitioned by it
+    if (mesh is None or mesh.size == 1
+            or jax.sharding.get_abstract_mesh().manual_axes):
+        # one device, or a trace that is per device already (SAGN
+        # differentiates inside its own ``shard_map``, where the ids are
+        # the shard's and the table is whole)
+        return dense_row_grad(ids, rows, num_rows)
+    # a sort along a sharded axis would make the partitioner gather every
+    # shard's ids and rows, so each device sorts its own lookups and keeps
+    # its own rows.  Where they rest is the placement rules' to say
+    # (parallel/sharding.py): what does not divide stays whole
+    table_spec = clamp_spec(TABLE_SPEC if shard_table else P(None, None),
+                            table, mesh)
+    batch = clamp_spec(P(DATA_AXIS), ids, mesh)[0]
+    shards = mesh.shape[MODEL_AXIS] if table_spec[0] else 1
+
+    def local_table_grad(ids, rows):
+        first = lax.axis_index(MODEL_AXIS) * (num_rows // shards) \
+            if shards > 1 else 0
+        return dense_row_grad(ids, rows, num_rows // shards, first)[None]
+
+    # a part a data shard; their sum is left to the partitioner, whose
+    # ``all-reduce`` the traces' readers know by that name (a ``psum``
+    # in here is an all-reduce named ``psum``)
+    return shard_map(
+        local_table_grad, mesh, in_specs=(P(batch), P(batch, None)),
+        out_specs=P(batch, *table_spec), comm_label=None)(ids, rows).sum(0)
+
+
 class HashedEmbedding(nn.Module):
     """Per-column hashed lookup: (B, C) float categories -> (B, C*dim)."""
 
@@ -92,21 +169,22 @@ class HashedEmbedding(nn.Module):
     dtype: jnp.dtype = jnp.float32
     shard_table: bool = True  # annotate the table for the 'model' axis
     impl: str = "auto"  # auto | xla | pallas
+    mesh: "jax.sharding.Mesh | None" = None  # what the step's jit spans
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
         init = nn.initializers.normal(stddev=0.05)
         table = self.param(
             "table",
-            nn.with_partitioning(init, ("model", None)) if self.shard_table
+            nn.with_partitioning(init, tuple(TABLE_SPEC)) if self.shard_table
             else init,
             (self.hash_size, self.features),
             self.dtype,
         )
         impl = _resolve_impl(self.impl, self.shard_table, self.hash_size)
         # the scopes are the phase names `obs profile --phases` reads a
-        # device trace by: the backward of the gather (the scatter-add of
-        # the gradient rows) carries transpose(jvp(...embed.gather))
+        # device trace by: the backward of the gather (take_rows's: sort,
+        # rows, scatter-add) carries transpose(jvp(...embed.gather))
         with jax.named_scope("embed.hash"):
             # one flat index, not a (B, C) one: the same rows in the same
             # order, but XLA:TPU takes 22 s to compile the (B, C)-indexed
@@ -120,7 +198,8 @@ class HashedEmbedding(nn.Module):
 
                 emb = embedding_gather(ids, table)
             else:
-                emb = jnp.take(table, ids, axis=0)  # (B*C, dim)
+                emb = take_rows(table, ids, self.mesh,
+                                self.shard_table)  # (B*C, dim)
             return emb.reshape(x.shape[0], -1)
 
 

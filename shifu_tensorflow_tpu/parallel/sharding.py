@@ -78,7 +78,7 @@ def _path_str(path) -> str:
     return "/".join(parts)
 
 
-def _sanitize_spec(spec: P, value, mesh: Mesh) -> P:
+def clamp_spec(spec: P, value, mesh: Mesh) -> P:
     """Clamp a rule/annotation spec to what the mesh and leaf can hold.
 
     Axis names absent from the mesh become None (replicated on that dim);
@@ -141,7 +141,7 @@ def match_partition_rules(rules, params, mesh: Mesh):
             spec = P(*leaf.names)
         if spec is None:
             spec = P()
-        out.append(NamedSharding(mesh, _sanitize_spec(spec, value, mesh)))
+        out.append(NamedSharding(mesh, clamp_spec(spec, value, mesh)))
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
@@ -149,7 +149,7 @@ def _spec_for_leaf(leaf, mesh: Mesh) -> NamedSharding:
     """flax Partitioned boxes carry their axis names; plain arrays
     replicate."""
     if _is_partitioned(leaf):
-        spec = _sanitize_spec(P(*leaf.names), leaf.value, mesh)
+        spec = clamp_spec(P(*leaf.names), leaf.value, mesh)
         return NamedSharding(mesh, spec)
     return replicate(mesh)
 

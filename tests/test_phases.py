@@ -39,7 +39,7 @@ FIXTURE = os.path.join(os.path.dirname(__file__), "fixtures",
 
 # ---- the scopes in the program ----
 
-def _wdl_trainer(**kw):
+def _wdl_trainer(embedding_dim=4, **kw):
     from shifu_tensorflow_tpu.config.model_config import ModelConfig
     from shifu_tensorflow_tpu.train.trainer import Trainer
 
@@ -49,7 +49,7 @@ def _wdl_trainer(**kw):
         "Optimizer": "adam", "ModelType": "wide_deep",
         "WideColumnNums": [3, 4], "CrossHashSize": 64,
         "EmbeddingColumnNums": [3, 4], "EmbeddingHashSize": 32,
-        "EmbeddingDim": 4}}})
+        "EmbeddingDim": embedding_dim}}})
     return Trainer(mc, 4, feature_columns=(1, 2, 3, 4), **kw)
 
 
@@ -62,27 +62,37 @@ def _batch(*lead):
 _STEP_TEXTS: dict = {}
 
 
-def _step_texts(path: str) -> dict:
+def _step_texts(path: str, embedding_dim: int = 4) -> dict:
     """``{"lowered": ..., "compiled": ..., "module": ...}`` of the tiny
-    wide-deep step on one path (built once per path)."""
-    if path not in _STEP_TEXTS:
+    wide-deep step on one path (built once per path).  Embeddings of 4
+    floats keep XLA's transpose of the lookup; 8 tile a 128-lane line and
+    take ``ops/embedding_grad.py``'s backward."""
+    if (path, embedding_dim) not in _STEP_TEXTS:
         if path == "per_step":
-            trainer = _wdl_trainer()
+            trainer = _wdl_trainer(embedding_dim)
             fn, batch = trainer._train_step, _batch(8)
         elif path == "scan":
-            trainer = _wdl_trainer(scan_steps=2)
+            trainer = _wdl_trainer(embedding_dim, scan_steps=2)
             fn, batch = trainer._scan_epoch, _batch(2, 8)
-        else:
-            trainer = _wdl_trainer(accum_steps=2)
+        elif path == "accum":
+            trainer = _wdl_trainer(embedding_dim, accum_steps=2)
             fn, batch = trainer._accum_step, _batch(2, 8)
+        else:
+            import jax
+
+            from shifu_tensorflow_tpu.parallel.mesh import make_mesh
+
+            trainer = _wdl_trainer(embedding_dim, mesh=make_mesh(
+                "data:2,model:2", devices=jax.devices()[:4]))
+            fn, batch = trainer._train_step, _batch(8)
         lowered = fn.lower(trainer.state, batch)
         compiled = lowered.compile().as_text()
-        _STEP_TEXTS[path] = {
+        _STEP_TEXTS[path, embedding_dim] = {
             "lowered": lowered.as_text(debug_info=True),
             "compiled": compiled,
             "module": re.search(r"^HloModule (\w+)", compiled, re.M).group(1),
         }
-    return _STEP_TEXTS[path]
+    return _STEP_TEXTS[path, embedding_dim]
 
 
 def _op_names(compiled: str) -> set[str]:
@@ -158,6 +168,24 @@ def test_gather_scope_has_both_directions_and_update_holds_the_select(path):
     assert any(n.endswith("/optimizer.update/jit(_where)/select_n")
                for n in update), sorted(update)
     assert not [n for n in update if "/cond" in n]
+
+
+@pytest.mark.parametrize("path", ["per_step", "scan", "accum", "mesh"])
+def test_the_lookups_own_backward_is_filed_under_the_gather(path):
+    """``ops/embedding_grad.py``: the sort of the lookups, the gather of
+    the gradient rows into that order, their scatter into lines and the
+    turning of the lines (the branch ``lax.platform_dependent`` keeps)
+    are a third of the chip's step; none may go to ``(unscoped)``, on one
+    device or under the mesh's ``shard_map``."""
+    names = _op_names(_step_texts(path, embedding_dim=8)["compiled"])
+    for op in ("sort", "gather", "scatter-add", "transpose"):
+        ours = [n for n in names if re.search(
+            r"/embed\.gather/(shard_map/)?(cond/branch_\d_fun/)?%s$" % op, n)]
+        assert ours, (op, sorted(names))
+        assert {phase_of(n) for n in ours} == {"embed.gather.bwd"}, ours
+    assert any("/shard_map/" in n for n in names) == (path == "mesh")
+    sorts = [n for n in names if n.endswith("/sort")]
+    assert {phase_of(n) for n in sorts} == {"embed.gather.bwd"}, sorts
 
 
 @pytest.mark.parametrize("path,module", [

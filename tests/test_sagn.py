@@ -150,6 +150,39 @@ def test_mesh_window1_matches_unsharded(rows):
     )
 
 
+@pytest.mark.parametrize("window", [1, 2])
+def test_mesh_with_a_replicated_embedding_table_matches_unsharded(window):
+    """``EmbeddingDim`` 8 takes the lookup's own backward
+    (models/embeddings.py ``take_rows``), which opens a ``shard_map`` of
+    its own on a mesh.  SAGN differentiates inside one already: there the
+    backward must run on the shard's lookups as on one device."""
+    mc = _mc(window=window)
+    params = dict(mc.raw["train"]["params"], EmbeddingColumnNums=[8, 9],
+                  EmbeddingHashSize=256, EmbeddingDim=8)
+    mc = ModelConfig.from_json({"train": {"params": params}})
+    data = _synth(128)
+    data["x"][:, 8:] = np.random.default_rng(1).integers(
+        0, 40, (128, 2)).astype(np.float32)
+    columns = tuple(range(N_FEATS))
+    single = SAGNTrainer(mc, N_FEATS, seed=5, feature_columns=columns)
+    sharded = SAGNTrainer(mc, N_FEATS, seed=5, feature_columns=columns,
+                          mesh=make_mesh("data:4", jax.devices()[:4]))
+    if window == 1:
+        # one step: the count-weighted sum of the shards' gradients is the
+        # batch's (local steps of a longer window see a shard's rows only)
+        batches = [{k: v[:64] for k, v in data.items()}]
+        single.train_epoch(iter(batches))
+        sharded.train_epoch(iter(batches))
+        np.testing.assert_allclose(_flat(single.state.params),
+                                   _flat(sharded.state.params),
+                                   rtol=1e-4, atol=1e-5)
+    else:
+        before = _flat(sharded.state.params)
+        sharded.train_epoch(_batches(data, 32))
+        after = _flat(sharded.state.params)
+        assert np.isfinite(after).all() and np.abs(after - before).max() > 0
+
+
 def test_sagn_rejects_partitioned_params_on_mesh():
     mc = ModelConfig.from_json(
         {

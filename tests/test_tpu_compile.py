@@ -13,6 +13,7 @@ programs RUN on the chip is ``chip_smoke.py``'s job.  Shapes only
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else logs under /tmp
 
@@ -246,7 +247,6 @@ def test_table_step_keeps_its_resting_layout_on_one_v5e_chip(topo):
     temporaries a step.  The guard's select leaves the dense gradient's
     0.54 GB and no such copy."""
     import json
-    import re
 
     from shifu_tensorflow_tpu.config.model_config import ModelConfig
 
@@ -267,6 +267,77 @@ def test_table_step_keeps_its_resting_layout_on_one_v5e_chip(topo):
     text = compiled.as_text()
     assert "conditional" not in text
     assert not re.findall(r"= f32\[%d,%d\]\{1,0[^}]*\} copy\(" % table, text)
+    _assert_the_gradient_is_scattered_in_lines(text, table)
+
+
+def _assert_the_gradient_is_scattered_in_lines(text, table):
+    """``ops/embedding_grad.py``: no scatter into the table's own shape
+    (rows on the lanes: 126-147 ns a lookup); the one into lines of
+    ``128 // dim`` rows is told that its indices are sorted, and one
+    kernel turns the lines into the table's layout."""
+    rows, dim = table
+    assert not re.findall(r"= f32\[%d,%d\][^ ]* scatter\(" % table, text)
+    scatters = re.findall(
+        r"= f32\[%d,128\]\{1,0[^ ]* scatter\(.*" % (rows * dim // 128), text)
+    assert len(scatters) == 1, scatters
+    assert "indices_are_sorted=true" in scatters[0], scatters[0]
+    assert len(re.findall(r"= f32\[%d,%d\]\{1,0[^ ]* custom-call\(.*"
+                          r"tpu_custom_call" % (dim, rows), text)) == 1
+
+
+def _placed_on(mesh, state):
+    """What ``shard_params`` does, on shapes."""
+    from shifu_tensorflow_tpu.parallel.sharding import (
+        DEFAULT_PARTITION_RULES,
+        _is_partitioned,
+        params_shardings,
+    )
+
+    shardings = params_shardings(state, mesh, rules=DEFAULT_PARTITION_RULES)
+
+    def place(leaf, sh):
+        if _is_partitioned(leaf):
+            return leaf.replace(value=place(leaf.value, sh))
+        return jax.ShapeDtypeStruct(leaf.shape, leaf.dtype, sharding=sh)
+
+    return jax.tree_util.tree_map(place, state, shardings,
+                                  is_leaf=_is_partitioned), shardings
+
+
+def test_table_step_runs_its_backward_per_device_on_the_2x2_mesh(topo):
+    """The four-chip cell's step (``benchmark/configs/wdl_criteo_x4.json``:
+    8,388,608 x 32 rows over ``model:2``, global batch 32,768 over
+    ``data:2``): every device sorts its own 425,984 lookups and scatters
+    into its own 4,194,304 rows.  A sort along the sharded batch under
+    the partitioner would gather every data shard's gradient rows first."""
+    import dataclasses
+    import json
+
+    from shifu_tensorflow_tpu.config.model_config import ModelConfig
+    from shifu_tensorflow_tpu.parallel.mesh import make_mesh
+    from shifu_tensorflow_tpu.parallel.sharding import batch_sharding
+
+    cell = os.path.join(os.path.dirname(__file__), os.pardir, "benchmark",
+                        "configs", "wdl_criteo_x4.json")
+    with open(cell) as f:
+        config = json.load(f)
+    assert config["mesh"] == "data:2,model:2"
+    mesh = make_mesh(config["mesh"], devices=list(topo.devices))
+    mc = ModelConfig.from_json(config["model_config"])
+    mc = dataclasses.replace(mc, batch_size=2 * mc.batch_size)
+    rows, dim = mc.params.embedding_hash_size, mc.params.embedding_dim
+    assert (mc.batch_size, rows, dim) == (32_768, 8_388_608, 32)
+    features = config["data"]["numeric"] + config["data"]["categorical"]
+    body, state, batch = _step_and_shapes(
+        mc, tuple(range(1, features + 1)), mesh, with_grad_norm=True)
+    state, _ = _placed_on(mesh, state)
+    compiled = jax.jit(body, donate_argnums=(0,)).lower(
+        state, _on(batch_sharding(mesh), batch)).compile()
+    text = compiled.as_text()
+    _assert_the_gradient_is_scattered_in_lines(text, (rows // 2, dim))
+    assert re.search(r"f32\[%d,%d\][^ ]* all-reduce\(" % (rows // 2, dim),
+                     text), "the sum over data is the dense all-reduce"
+    assert not re.findall(r"f32\[\d+,%d\][^ ]* all-gather" % dim, text)
 
 
 def test_flagship_train_step_lowers_for_the_2x2_mesh(topo):
@@ -274,24 +345,11 @@ def test_flagship_train_step_lowers_for_the_2x2_mesh(topo):
     over the host's chips, the table (and its Adam mirrors) sharded
     row-wise on ``model``, the batch on ``data``."""
     from shifu_tensorflow_tpu.parallel.mesh import make_mesh
-    from shifu_tensorflow_tpu.parallel.sharding import (
-        DEFAULT_PARTITION_RULES,
-        _is_partitioned,
-        batch_sharding,
-        params_shardings,
-    )
+    from shifu_tensorflow_tpu.parallel.sharding import batch_sharding
 
     mesh = make_mesh("data:2,model:2", devices=list(topo.devices))
     body, state, batch = _flagship_step_and_shapes(mesh)
-    shardings = params_shardings(state, mesh, rules=DEFAULT_PARTITION_RULES)
-
-    def place(leaf, sh):  # what shard_params does, on shapes
-        if _is_partitioned(leaf):
-            return leaf.replace(value=place(leaf.value, sh))
-        return jax.ShapeDtypeStruct(leaf.shape, leaf.dtype, sharding=sh)
-
-    state = jax.tree_util.tree_map(place, state, shardings,
-                                   is_leaf=_is_partitioned)
+    state, shardings = _placed_on(mesh, state)
     tables = [sh for sh in jax.tree_util.tree_leaves(shardings)
               if isinstance(sh, NamedSharding) and "model" in sh.spec]
     assert len(tables) == 3, "table + Adam mu/nu shard on the model axis"
