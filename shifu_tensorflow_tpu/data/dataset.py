@@ -504,7 +504,7 @@ def prefetch_to_device(
       the consumer thread while filling the deque, so placement time is
       consumer-visible.  The host-embedding path DEPENDS on this (its
       zero-staleness contract needs gather→update ordering in one thread —
-      trainer._train_epoch_host_emb).
+      trainer.Trainer._apply_emb_grad).
     - **pipelined** (``pipelined=True``): a producer thread runs
       ``next(batches)`` + ``put`` and feeds a bounded queue, so host batch
       production AND device placement of batch k+1 overlap the dispatch of

@@ -692,10 +692,6 @@ def export_model(
         serve_mc,
         tuple(feature_columns) if feature_columns else None,
         shard_embeddings=False,
-        # 'auto' could resolve to the Pallas TPU kernel on a TPU backend,
-        # which jax2tf would bake (TPU-only) into the SavedModel; the
-        # portable gather is the only correct serving lookup
-        embedding_impl="xla",
     )
     from flax.core import meta as flax_meta
 

@@ -30,7 +30,6 @@ class EmbeddingAugmented(nn.Module):
     embed_dim: int
     dtype: jnp.dtype = jnp.float32
     shard_table: bool = True
-    embedding_impl: str = "auto"
     mesh: "jax.sharding.Mesh | None" = None
 
     @nn.compact
@@ -38,7 +37,7 @@ class EmbeddingAugmented(nn.Module):
         emb = HashedEmbedding(
             hash_size=self.hash_size, features=self.embed_dim,
             dtype=self.dtype, shard_table=self.shard_table,
-            impl=self.embedding_impl, mesh=self.mesh, name="hashed_columns",
+            mesh=self.mesh, name="hashed_columns",
         )(x[:, jnp.asarray(self.embed_indices)])
         return self.base(jnp.concatenate([x, emb], axis=-1))
 
@@ -59,18 +58,13 @@ def build_model(
     feature_columns: tuple[int, ...] | None = None,
     dtype: jnp.dtype = jnp.float32,
     shard_embeddings: bool = True,
-    embedding_impl: str = "auto",
     mesh=None,
 ) -> nn.Module:
     """``shard_embeddings=False`` (no 'model' mesh axis present) drops the
-    table's partitioning annotation.  ``embedding_impl`` selects the lookup
-    implementation; pass "xla" whenever the computation runs over a
-    multi-device mesh — the Pallas kernel has no GSPMD partitioning rule, so
-    "auto" is only safe single-device (models/embeddings._resolve_impl).
-    ``mesh`` is what the step's ``jit`` partitions over: the sequence
-    family picks its attention by it (ring/Ulysses need the 'seq' axis) and
-    the hashed embedding runs its backward per device on it
-    (models/embeddings.py ``take_rows``)."""
+    table's partitioning annotation.  ``mesh`` is what the step's ``jit``
+    partitions over: the sequence family picks its attention by it
+    (ring/Ulysses need the 'seq' axis) and the hashed embedding runs its
+    backward per device on it (models/embeddings.py ``take_rows``)."""
     p: TrainParams = model_config.params
     nodes = p.num_hidden_nodes[: p.num_hidden_layers]
     acts = p.activation_funcs[: p.num_hidden_layers]
@@ -153,7 +147,7 @@ def build_model(
                 base=base, embed_indices=embed_idx,
                 hash_size=p.embedding_hash_size, embed_dim=p.embedding_dim,
                 dtype=dtype, shard_table=shard_embeddings,
-                embedding_impl=embedding_impl, mesh=mesh,
+                mesh=mesh,
             )
     # EmbeddingPlacement=host: the gather happens on the HOST (the table
     # exceeds HBM by assumption — models/host_embedding.py); the Trainer

@@ -1,13 +1,13 @@
-"""On-device feature hashing shared by the XLA and Pallas embedding paths.
+"""On-device feature hashing for the hashed embedding and cross tables.
 
-One source of truth: both `models.embeddings` (XLA gather) and
-`ops.pallas.embedding` (fused TPU kernel) call these functions, so bucket
-assignment is bit-identical whichever implementation runs — the same
-parity discipline the data layer applies to its native/Python parsers.
+One source of truth: `models.embeddings` calls these functions and
+`models.host_embedding` mirrors them in numpy (parity pinned by
+tests/test_host_embedding.py), so bucket assignment is bit-identical
+wherever the table lives — the same parity discipline the data layer
+applies to its native/Python parsers.
 
 The hash is multiplicative (Fibonacci) hashing over the raw float bits:
-elementwise uint32 ops only, so it fuses into surrounding XLA and is legal
-inside a Pallas kernel body.
+elementwise uint32 ops only, so it fuses into surrounding XLA.
 """
 
 from __future__ import annotations

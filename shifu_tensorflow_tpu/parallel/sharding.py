@@ -39,9 +39,8 @@ try:  # flax optional: stdlib-only obs CLIs never trip this
 except Exception:  # pragma: no cover - exercised on flax-less hosts
     nn = None
 
-# Default rule set: embedding tables (models/embeddings.py `table` params,
-# including the ops/pallas/embedding.py gather path which reads the same
-# leaves) shard row-wise along `model`; everything else replicates.  The
+# Default rule set: embedding tables (models/embeddings.py `table` params)
+# shard row-wise along `model`; everything else replicates.  The
 # same suffix matches inside optax mu/nu mirrors.
 DEFAULT_PARTITION_RULES: tuple[tuple[str, P], ...] = (
     (r"(^|/)table$", P(MODEL_AXIS, None)),

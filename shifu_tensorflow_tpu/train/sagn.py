@@ -37,26 +37,28 @@ stateless-SPMD equivalent and keeps the step a pure function).
 from __future__ import annotations
 
 from functools import partial
-from typing import Iterable
+from typing import Iterable, Iterator
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 
 from shifu_tensorflow_tpu.config.model_config import ModelConfig
-from shifu_tensorflow_tpu.data.dataset import (
-    Batch,
-    close_stream,
-    prefetch_to_device,
-)
+from shifu_tensorflow_tpu.data.dataset import Batch
 from shifu_tensorflow_tpu.obs import compile as obs_compile
-from shifu_tensorflow_tpu.obs import trace as obs_trace
 from shifu_tensorflow_tpu.ops.losses import get_loss, l2_penalty
 from shifu_tensorflow_tpu.parallel.mesh import DATA_AXIS
 from shifu_tensorflow_tpu.train.optimizers import make_base_optimizer
-from shifu_tensorflow_tpu.train.trainer import Trainer, apply_if_rows
+from shifu_tensorflow_tpu.train.trainer import (
+    EpochPath,
+    Trainer,
+    Unit,
+    _placing,
+    _widen_features,
+    apply_update,
+)
 
 from shifu_tensorflow_tpu.parallel.shmap import shard_map
 
@@ -73,15 +75,14 @@ def make_sagn_step(
 
     Takes ``(state, window_batch)`` where window_batch leaves are
     ``(K, B, ...)``; the window size K is whatever the stacked batch
-    carries.  Returns ``(state, mean_window_loss)``.
+    carries.  Returns ``(state, aux)`` with the window's mean loss in
+    ``aux["loss"]`` (train/trainer.py ``apply_update``).
     """
     loss_fn = get_loss(loss_name)
 
     def compute_loss(params, micro):
         # same compact-transport seam as the plain step: bf16-streamed
         # features widen to the params' precision on device
-        from shifu_tensorflow_tpu.train.trainer import _widen_features
-
         pred = apply_fn({"params": params},
                         _widen_features(params, micro["x"]))
         with jax.named_scope("loss"):
@@ -147,10 +148,7 @@ def make_sagn_step(
         # all-padding window: keep the state as it was (zero grads would
         # still move Adam-style momentum / increment step) and report NaN
         # so epoch means exclude it — same contract as make_train_step
-        has_rows = jnp.sum(window_batch["w"] != 0.0) > 0
-        with jax.named_scope("optimizer.update"):
-            state = apply_if_rows(state, avg_grads, has_rows)
-        return state, jnp.where(has_rows, loss, jnp.nan)
+        return apply_update(state, avg_grads, loss, window_batch["w"])
 
     return obs_compile.observe(sagn_step, "train.sagn_step")
 
@@ -197,25 +195,20 @@ class SAGNTrainer(Trainer):
             )
         # SAGN's window step already batches update_window microbatches
         # per dispatch — the scan_steps chunking would compose confusingly
-        # with it for no additional amortization.  Forced to 1 BEFORE
-        # super().__init__ so the parent never scales the hang-watchdog
-        # timeout for a scan path that will not run.
+        # with it for no additional amortization
         kw["scan_steps"] = 1
+        self.update_window = max(int(p0.update_window), 1)
+        self._local_optimizer = local_optimizer or p0.optimizer
         super().__init__(model_config, num_features, **kw)
-        self.scan_steps = 1
-        self._scan_epoch = None
-        p = model_config.params
-        self.update_window = max(int(p.update_window), 1)
-        if self.health_guard is not None:
-            # one SAGN dispatch spans the whole communication window — the
-            # per-step hang timeout must stretch with it (same contract as
-            # the parent's scan/accum scaling)
-            self.health_guard.scale_watchdog(
-                self.update_window,
-                "SAGN window: one dispatch spans update_window microbatches",
-            )
-        local_name = local_optimizer or p.optimizer
-        local_tx = make_base_optimizer(local_name, p.learning_rate)
+
+    def _choose_path(self, loss: str) -> EpochPath:
+        """Windows of ``update_window`` microbatches, one dispatch each,
+        unthreaded; a trailing partial window is plain synchronous steps
+        (the parent's per-step path without the guard's norm).  The losses
+        are per WINDOW — a NaN may be an all-padding window, so only the
+        guard's inf and epoch-mean checks apply — and the epoch mean
+        counts a K-micro window K times."""
+        p = self.model_config.params
         if self.mesh is not None:
             import flax.linen as nn
 
@@ -229,33 +222,36 @@ class SAGNTrainer(Trainer):
                     "model-parallel (Partitioned) tables are not supported — "
                     "use the plain Trainer for embedding-sharded models"
                 )
-        self._sagn_step = make_sagn_step(
+        step = make_sagn_step(
             self.model.apply,
-            local_tx,
-            loss_name=self.loss_name,
+            make_base_optimizer(self._local_optimizer, p.learning_rate),
+            loss_name=loss,
             l2=p.l2_reg,
             mesh=self.mesh,
         )
-        self._window_sharding = (
-            NamedSharding(self.mesh, P(None, DATA_AXIS))
-            if self.mesh is not None
-            else None
-        )
+        return EpochPath(
+            "train.sagn_step", step, _placing(self._put_window),
+            units=self._windows, group=self.update_window,
+            loss_mode="loose", threaded=False, weighted=True,
+            tail=self._per_step_path(self._train_step))
+
+    def _windows(self, batches: Iterable[Batch]) -> Iterator[Unit]:
+        K = self.update_window
+        buf: list[Batch] = []
+        for batch in batches:
+            buf.append(batch)
+            if len(buf) == K:
+                yield Unit([self._pad_for_mesh(b) for b in buf],
+                           sum(b["x"].shape[0] for b in buf), K)
+                buf = []
+        for batch in buf:
+            yield Unit(batch, batch["x"].shape[0], tail=True)
 
     def _put_window(self, micros: list[Batch]) -> Batch:
-        stacked = {
+        return self._put_stacked({
             k: np.stack([np.asarray(m[k]) for m in micros], axis=0)
             for k in micros[0]
-        }
-        if self._cross_process:
-            from shifu_tensorflow_tpu.parallel.distributed import (
-                put_process_local,
-            )
-
-            return put_process_local(stacked, self._window_sharding)
-        if self._window_sharding is not None:
-            return jax.device_put(stacked, self._window_sharding)
-        return jax.device_put(stacked)
+        })
 
     def fit_device_resident(self, *a, **kw):
         """The inherited device-resident epoch scans the PLAIN train-step
@@ -264,86 +260,4 @@ class SAGNTrainer(Trainer):
         raise NotImplementedError(
             "fit_device_resident trains with plain-SSGD semantics; the SAGN "
             "window algorithm uses fit/fit_stream"
-        )
-
-    def train_epoch(self, batches: Iterable[Batch]) -> tuple[float, int]:
-        """SAGN window epoch; the source is closed on every exit (same
-        stream-teardown contract as the parent's train_epoch)."""
-        source = batches
-        try:
-            return self._train_epoch_sagn(batches)
-        finally:
-            close_stream(source)
-
-    def _train_epoch_sagn(self, batches: Iterable[Batch]) -> tuple[float, int]:
-        K = self.update_window
-        losses: list = []
-        weights: list[int] = []
-        n_micro = 0
-        tail: list[Batch] = []
-        guard = self.health_guard
-        if guard is not None:
-            # same instrumentation seam as the parent's train_epoch:
-            # real-row bookkeeping, rollback skip-window, nan injection
-            batches = guard.filter_batches(batches)
-        tracer = self.tracer
-        if tracer is not None:
-            # same step-phase seams as the parent (obs plane): raw batch
-            # production is "step.host", window placement "step.infeed",
-            # one dispatch per SAGN window
-            batches = tracer.wrap_iter("step.host", batches)
-
-        def windows():
-            buf: list[Batch] = []
-            for batch in batches:
-                buf.append(self._pad_for_mesh(batch))
-                if len(buf) == K:
-                    yield buf
-                    buf = []
-            tail.extend(buf)
-
-        # overlap host-side window stacking + transfer with device compute,
-        # same double-buffering the plain trainer gets from prefetch_to_device
-        put_window = (tracer.timed("step.infeed", self._put_window)
-                      if tracer is not None else self._put_window)
-        for wb in prefetch_to_device(windows(), put=put_window,
-                                     depth=self.prefetch_depth):
-            with obs_trace.maybe_span(tracer, "step.dispatch"):
-                self.state, loss = self._sagn_step(self.state, wb)
-            losses.append(loss)
-            weights.append(K)
-            n_micro += K
-            if guard is not None:
-                guard.tick()
-        # trailing partial window: plain sync steps (window of 1); the
-        # placement is timed as step.infeed like the main path, not
-        # swallowed into the dispatch span
-        put = (tracer.timed("step.infeed", self._put)
-               if tracer is not None else self._put)
-        for batch in tail:
-            dev = put(batch)
-            with obs_trace.maybe_span(tracer, "step.dispatch"):
-                self.state, loss = self._train_step(self.state, dev)
-            losses.append(loss)
-            weights.append(1)
-            n_micro += 1
-            if guard is not None:
-                guard.tick()
-        if not losses:
-            return float("nan"), 0
-        # microbatch-weighted epoch mean: a K-micro window counts K times;
-        # NaN losses mark all-padding windows (skipped by contract)
-        with obs_trace.maybe_span(tracer, "step.block"):
-            vals = np.asarray(jax.device_get(losses), np.float64)
-        if guard is not None:
-            # per-WINDOW losses: a NaN may be an all-padding window, so
-            # only the inf and epoch-mean divergence checks apply
-            guard.note_losses(vals, mode="loose")
-        ws = np.asarray(weights, np.float64)
-        mask = ~np.isnan(vals)
-        return (
-            float(np.average(vals[mask], weights=ws[mask]))
-            if mask.any()
-            else float("nan"),
-            n_micro,
         )

@@ -24,7 +24,7 @@ import threading
 import time
 from dataclasses import dataclass, field
 from functools import partial, wraps
-from typing import Any, Callable, Iterable, Sequence
+from typing import Any, Callable, Iterable, Iterator, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -479,8 +479,8 @@ class HealthGuard:
 def _fault_lagged(batches: "Iterable[Batch]", worker_index: int):
     """Straggler-drill chaos seam: consult the fault plan once per host
     batch at ``train.step.w<index>`` (the `slow` kind sleeps there; an
-    exception kind raises, like any other seam).  Installed by
-    ``_train_epoch_dispatch`` only while a plan is active."""
+    exception kind raises, like any other seam).  Installed by the epoch
+    loop only while a plan is active."""
     from shifu_tensorflow_tpu.utils import faults
 
     site = f"train.step.w{worker_index}"
@@ -520,12 +520,46 @@ def apply_if_rows(state, grads, has_rows):
 
     Computes the update unconditionally and selects per leaf: the select
     fuses into the optimizer's element-wise fusion and the leaves keep
-    the layout they rest in.  The one guard of every step builder here
-    and of train/sagn.py; the why is in make_train_step_body."""
+    the layout they rest in.  Called by :func:`apply_update` alone."""
     new = state.apply_gradients(grads=grads)
     return jax.tree_util.tree_map(
         lambda n, o: jnp.where(has_rows, n, o), new, state
     )
+
+
+def apply_update(state, grads, loss, w, *, grad_norm: bool = False,
+                 counters=None, emb_grad=None):
+    """The tail of every step builder here and of train/sagn.py, and the
+    one place that knows what the update receives: count the rows of the
+    weights ``w``, apply ``grads`` where there are any, and return
+    ``(state, aux)``.  ``aux`` is the one auxiliary shape of a step, a
+    mapping: ``loss`` always; ``grad_norm`` (the global norm of ``grads``)
+    where asked for; ``counters`` and ``emb_grad`` where handed in.
+
+    An all-padding (weight-0) batch must be a true no-op: the data loss
+    is 0 but the l2 term still has gradients, and Adam-style momentum
+    produces nonzero updates even from zero grads — either would let the
+    fixed-step SPMD padding batches (data/dataset.py fixed_step_batches)
+    drift parameters.  The count is over the GLOBAL batch, so every SPMD
+    process selects the same.  The loss reports NaN for such batches so
+    epoch means (nanmean) skip them instead of being biased toward zero;
+    the norm and the embedding gradient report 0.  The guard is a select
+    (apply_if_rows), not a lax.cond: a branch computation's parameters
+    get XLA's default layout, so a conditional makes every step copy a
+    big table and its moments into that layout and back."""
+    has_rows = jnp.sum(w != 0.0) > 0
+    with jax.named_scope("optimizer.update"):
+        state = apply_if_rows(state, grads, has_rows)
+    aux = {"loss": jnp.where(has_rows, loss, jnp.nan)}
+    if grad_norm:
+        import optax
+
+        aux["grad_norm"] = jnp.where(has_rows, optax.global_norm(grads), 0.0)
+    if counters is not None:
+        aux["counters"] = counters
+    if emb_grad is not None:
+        aux["emb_grad"] = jnp.where(has_rows, emb_grad, 0.0)
+    return state, aux
 
 
 def prediction_loss(apply_fn, loss_name: str = "mse"):
@@ -548,23 +582,21 @@ def prediction_loss(apply_fn, loss_name: str = "mse"):
 
 def make_train_step_body(apply_fn, loss_name: str = "mse", l2: float = 0.0,
                          with_grad_norm: bool = False, batch_loss=None):
-    """The un-jitted (state, batch) -> (state, loss) transition — jitted
+    """The un-jitted (state, batch) -> (state, aux) transition — jitted
     per-batch by make_train_step, lax.scan'ed over stacked batches by
     make_scan_epoch.  One definition, so the two paths cannot drift.
+    ``aux`` is :func:`apply_update`'s mapping.
 
     ``with_grad_norm=True`` (health guard, shifu.tpu.health-check-finite)
-    returns ``(state, (loss, global_grad_norm))`` instead — the norm is a
-    cheap on-device reduction over gradients the step already computed,
-    letting the guard catch an exploding/NaN gradient before the loss
-    itself goes non-finite.
+    adds ``grad_norm`` — a cheap on-device reduction over gradients the
+    step already computed, letting the guard catch an exploding/NaN
+    gradient before the loss itself goes non-finite.
 
     ``batch_loss`` is the seam for a family that brings its own loss
     (:func:`prediction_loss` says the form; models/factory.py
     ``family_loss`` finds it): it is differentiated in place of
-    ``loss_name`` on ``apply_fn``'s ``(B, 1)`` prediction, and the step's
-    auxiliary output gains its counters: ``(state, (loss, counters))``, or
-    ``(state, (loss, global_grad_norm, counters))``.  Guard, norm and
-    update are the same.
+    ``loss_name`` on ``apply_fn``'s ``(B, 1)`` prediction, and ``aux``
+    gains its ``counters``.  Guard, norm and update are the same.
     """
     if batch_loss is None:
         batch_loss = prediction_loss(apply_fn, loss_name)
@@ -579,31 +611,8 @@ def make_train_step_body(apply_fn, loss_name: str = "mse", l2: float = 0.0,
     def train_step(state: TrainState, batch: Batch):
         (loss, counters), grads = jax.value_and_grad(
             compute_loss, has_aux=True)(state.params, batch)
-        # An all-padding (weight-0) batch must be a true no-op: the data
-        # loss is 0 but the l2 term still has gradients, and Adam-style
-        # momentum produces nonzero updates even from zero grads — either
-        # would let the fixed-step SPMD padding batches (data/dataset.py
-        # fixed_step_batches) drift parameters.  The count is over the
-        # GLOBAL batch, so every SPMD process selects the same.  The
-        # loss reports NaN for such batches so epoch means (nanmean) skip
-        # them instead of being biased toward zero.  The guard is a select
-        # (apply_if_rows), not a lax.cond: a branch computation's
-        # parameters get XLA's default layout, so a conditional makes every
-        # step copy a big table and its moments into that layout and back.
-        has_rows = jnp.sum(batch["w"] != 0.0) > 0
-        with jax.named_scope("optimizer.update"):
-            state = apply_if_rows(state, grads, has_rows)
-        loss = jnp.where(has_rows, loss, jnp.nan)
-        if with_grad_norm:
-            import optax
-
-            gnorm = jnp.where(has_rows, optax.global_norm(grads), 0.0)
-            if counters is not None:
-                return state, (loss, gnorm, counters)
-            return state, (loss, gnorm)
-        if counters is not None:
-            return state, (loss, counters)
-        return state, loss
+        return apply_update(state, grads, loss, batch["w"],
+                            grad_norm=with_grad_norm, counters=counters)
 
     return train_step
 
@@ -630,8 +639,8 @@ def make_host_emb_train_step(apply_fn, raw_width: int,
                              loss_name: str = "mse", l2: float = 0.0):
     """Train step for host-resident embeddings (EmbeddingPlacement=host):
     ``batch["x"]`` arrives as ``[raw features | host-gathered embeddings]``
-    and the step ALSO returns dLoss/d(embedding slice) so the host can
-    apply the sparse Adagrad update (models/host_embedding.py).  Same
+    and the step's ``emb_grad`` is dLoss/d(embedding slice) so the host
+    can apply the sparse Adagrad update (models/host_embedding.py).  Same
     no-op gate for all-padding batches as make_train_step — zero-weight
     rows produce zero embedding grads, so padded rows update nothing."""
     loss_fn = get_loss(loss_name)
@@ -650,11 +659,8 @@ def make_host_emb_train_step(apply_fn, raw_width: int,
         loss, (gp, gx) = jax.value_and_grad(compute, argnums=(0, 1))(
             state.params, x, batch
         )
-        has_rows = jnp.sum(batch["w"] != 0.0) > 0
-        with jax.named_scope("optimizer.update"):
-            state = apply_if_rows(state, gp, has_rows)
-        g_emb = jnp.where(has_rows, gx[:, raw_width:], 0.0)
-        return state, jnp.where(has_rows, loss, jnp.nan), g_emb
+        return apply_update(state, gp, loss, batch["w"],
+                            emb_grad=gx[:, raw_width:])
 
     return obs_compile.observe(step, "train.host_emb_step")
 
@@ -722,8 +728,7 @@ def make_accum_step(apply_fn, loss_name: str = "mse", l2: float = 0.0):
         (g_sum, s_tot, n_tot), _ = jax.lax.scan(
             body, (zeros, jnp.asarray(0.0), jnp.asarray(0.0)), stacked
         )
-        has_rows = n_tot > 0
-        denom = jnp.where(has_rows, n_tot, 1.0)
+        denom = jnp.maximum(n_tot, 1.0)  # a count: 0 for all padding
         grads = jax.tree_util.tree_map(lambda g: g / denom, g_sum)
         loss = s_tot / denom
         if l2:
@@ -733,9 +738,7 @@ def make_accum_step(apply_fn, loss_name: str = "mse", l2: float = 0.0):
             )(state.params)
             grads = jax.tree_util.tree_map(jnp.add, grads, l2_g)
             loss = loss + l2_loss
-        with jax.named_scope("optimizer.update"):
-            state = apply_if_rows(state, grads, has_rows)
-        return state, jnp.where(has_rows, loss, jnp.nan)
+        return apply_update(state, grads, loss, stacked["w"])
 
     return obs_compile.observe(accum_step, "train.accum_step")
 
@@ -784,6 +787,65 @@ def make_eval_step(apply_fn, loss_name: str = "mse", batch_loss=None):
     return obs_compile.observe(
         jax.jit(make_eval_step_body(apply_fn, loss_name, batch_loss)),
         "train.eval_step")
+
+
+class Unit(NamedTuple):
+    """What one ``step.dispatch`` takes.  ``data`` is its host side until
+    the path's ``put`` has run, its device side after."""
+
+    data: Any
+    rows: int  # the real (unpadded) rows in it: what the step timer counts
+    batches: int = 1  # the host batches it stands for
+    tail: bool = False  # put and stepped by the path's ``tail``
+    note: Any = None  # from ``put`` to ``after_step``, host side
+
+
+def _one_each(batches: Iterable[Batch]) -> Iterator[Unit]:
+    for batch in batches:
+        yield Unit(batch, batch["x"].shape[0])
+
+
+def _placing(put: Callable[[Batch], Batch]) -> Callable[[Unit], Unit]:
+    """A path's ``put`` from a placement of the unit's data alone."""
+    return lambda unit: unit._replace(data=put(unit.data))
+
+
+@dataclass(frozen=True)
+class EpochPath:
+    """One way through an epoch, as data.  ``Trainer.__init__`` chooses it
+    once (``_choose_path``) and ``Trainer._run_epoch`` is the one loop that
+    runs it; ``warm_step`` runs one all-padding unit of it.  The loop owns
+    everything the paths share — the straggler seam, the guard's filter,
+    span names, infeed, ``step.dispatch``, ticks, the step timer, the
+    closes, the ``step.block`` fetch, the NaN-skipping mean — and reads
+    here what differs."""
+
+    #: the compiled step's ``obs_compile.observe`` name
+    name: str
+    #: ``(state, unit.data) -> (state, aux)``, ``aux`` the mapping of
+    #: :func:`apply_update`
+    step: Callable
+    #: host unit -> the unit with its data on the device
+    put: Callable[[Unit], Unit]
+    #: the keys of ``aux``, exactly
+    aux: tuple[str, ...] = ("loss",)
+    #: host batches -> dispatch units
+    units: Callable[[Iterable[Batch]], Iterator[Unit]] = _one_each
+    #: host batches in a full unit
+    group: int = 1
+    #: how ``HealthGuard.note_losses`` pairs losses with batches
+    loss_mode: str = "aligned"
+    #: whether the infeed may run on the put thread; an unthreaded path
+    #: records ``step.infeed`` and ``step.host``
+    threaded: bool = True
+    #: infeed lookahead where the path pins it, else ``prefetch_depth``
+    depth: int | None = None
+    #: ``(aux, unit)`` after each dispatch, before the tick
+    after_step: Callable[[dict, Unit], None] | None = None
+    #: the epoch mean weighs a unit's loss by its ``batches``
+    weighted: bool = False
+    #: the path of the units marked ``tail``
+    tail: "EpochPath | None" = None
 
 
 class Trainer:
@@ -923,31 +985,21 @@ class Trainer:
                         "updates sparsely and is exempt (device "
                         "placement regularizes its table)"
                     )
-        import collections
-
-        self._emb_ids: "collections.deque" = collections.deque()
-        self._collect_emb_ids = False
         #: keep-best snapshot of the host table (parallel to best_params)
         self.best_host_table = None
 
-        # shard embedding tables only when a >1 'model' axis exists; the
-        # fused Pallas lookup is only eligible single-device — it has no
-        # GSPMD partitioning rule, so under a multi-device mesh (even pure
-        # data-parallel) the lookup must stay on XLA's partitioned gather
+        # shard embedding tables only when a >1 'model' axis exists
         shard_emb = mesh is not None and mesh.shape.get("model", 1) > 1
-        single_device = mesh is None or mesh.size == 1
         self.model = build_model(
             model_config, feature_columns, dtype=dtype,
-            shard_embeddings=shard_emb,
-            embedding_impl="auto" if single_device else "xla",
-            mesh=mesh,
+            shard_embeddings=shard_emb, mesh=mesh,
         )
         self.tx = make_optimizer(model_config.params)
         self.loss_name = loss
         self.seed = seed
         # a family with a loss of its own (a language model's per-token
         # cross-entropy): the per-step path differentiates it; the other
-        # epoch paths are written around a (B, 1) prediction
+        # paths' steps are written around a (B, 1) prediction
         self._batch_loss = family_loss(self.model)
         #: the last epoch's step counters of such a family, ``{name:
         #: [value per step]}`` (host numpy), else ``{}``
@@ -1029,6 +1081,8 @@ class Trainer:
             else self._data_axis
         )
 
+        # the plain per-batch step: the per-step path's without the guard's
+        # norm, SAGN's tail, and what the step-rate harnesses time
         self._train_step = make_train_step(
             self.model.apply, loss, model_config.params.l2_reg,
             batch_loss=self._batch_loss,
@@ -1042,61 +1096,25 @@ class Trainer:
             HealthGuard(health, worker_index=worker_index)
             if health is not None else None
         )
+        self._path = self._choose_path(loss)
         if self.health_guard is not None:
-            # chunked paths tick the watchdog once per DISPATCH, which
-            # spans scan_steps (or accum_steps) optimizer steps
+            # the watchdog is ticked once per DISPATCH, which spans the
+            # path's group of host batches (scan steps, microbatches, a
+            # SAGN window)
             self.health_guard.scale_watchdog(
-                max(self.scan_steps, self.accum_steps),
-                "scan/accum chunking: one dispatch spans many steps",
+                self._path.group,
+                f"{self._path.name}: one dispatch spans a group of batches",
             )
-        # per-step path only: the health step also returns the on-device
-        # global grad norm; scan/accum/host-emb paths fall back to the
-        # guard's loss-count checks
-        self._health_step = (
-            make_train_step(
-                self.model.apply, loss, model_config.params.l2_reg,
-                with_grad_norm=True, batch_loss=self._batch_loss,
-            )
-            if (self.health_guard is not None and health.check_finite
-                and self.scan_steps == 1 and self.accum_steps == 1
-                and self._host_emb is None)
-            else None
-        )
-        self._host_emb_step = (
-            make_host_emb_train_step(
-                self.model.apply, num_features, loss,
-                model_config.params.l2_reg,
-            )
-            if self._host_emb is not None else None
-        )
         self._eval_step = make_eval_step(self.model.apply, loss,
                                          self._batch_loss)
-        # chunked-scan epochs (conf key shifu.tpu.scan-steps, validated
-        # at the top of __init__): batches per lax.scan dispatch; 1 = the
-        # plain per-step path.  accum_steps (shifu.tpu.accum-steps):
-        # microbatches per ONE optimizer update — effective batch sizes
-        # beyond HBM.
-        self._scan_epoch = (
-            make_scan_epoch(self.model.apply, loss,
-                            model_config.params.l2_reg)
-            if self.scan_steps > 1
-            else None
-        )
-        self._accum_step = (
-            make_accum_step(self.model.apply, loss,
-                            model_config.params.l2_reg)
-            if self.accum_steps > 1
-            else None
-        )
         # device-infeed lookahead (conf key shifu.tpu.prefetch-depth;
         # shifu.tpu.data-prefetch / the ingest autotuner may retarget it
         # between streaming epochs)
         self.prefetch_depth = max(1, int(prefetch_depth))
         # pipelined infeed: production + device placement of batch k+1 on
         # a put thread, overlapping batch k's dispatch (data/dataset.py
-        # _PipelinedPrefetch).  Default on for the per-step/scan/accum/
-        # eval paths; the host-embedding path ignores it (zero-staleness
-        # contract pins an unthreaded depth-1 lookahead).
+        # _PipelinedPrefetch).  Default on for the paths that allow it
+        # (EpochPath.threaded) and the eval pass.
         self.infeed_pipelined = True
         # the epoch's ROOT stream (the ShardStream under the generator
         # chain), stashed by train_epoch/evaluate so _PipelinedPrefetch
@@ -1133,36 +1151,133 @@ class Trainer:
         self.best_epoch: int | None = None
         self.best_metric = float("inf") if keep_best == "valid_loss" else float("-inf")
 
+    # ---- the epoch path ----
+    def _choose_path(self, loss: str) -> EpochPath:
+        """The epoch path of this trainer, from what ``__init__`` knows."""
+        p = self.model_config.params
+        apply_fn = self.model.apply
+        if self._host_emb is not None:
+            return EpochPath(
+                "train.host_emb_step",
+                make_host_emb_train_step(apply_fn, self.num_features, loss,
+                                         p.l2_reg),
+                self._put_host_emb, aux=("loss", "emb_grad"),
+                threaded=False, depth=1, after_step=self._apply_emb_grad)
+        if self.scan_steps > 1:
+            # chunked-scan epochs (shifu.tpu.scan-steps): K batches stacked
+            # per dispatch, K sequential optimizer updates in it.  Update
+            # semantics are the per-step path's — same body, same order.
+            # The losses are per batch but chunking lost their order: the
+            # guard checks that every real batch produced a finite one.
+            return EpochPath(
+                "train.scan_epoch", make_scan_epoch(apply_fn, loss, p.l2_reg),
+                _placing(self._put_stacked),
+                units=partial(self._stacked_chunks, K=self.scan_steps),
+                group=self.scan_steps, loss_mode="counted")
+        if self.accum_steps > 1:
+            # accumulated epochs (shifu.tpu.accum-steps): A microbatches
+            # stacked per ONE optimizer update, so global_step advances
+            # once a group; the epoch loss is the nanmean of per-UPDATE
+            # losses, where a NaN may be a padding group: only the guard's
+            # inf and epoch-mean checks apply.
+            return EpochPath(
+                "train.accum_step", make_accum_step(apply_fn, loss, p.l2_reg),
+                _placing(self._put_stacked),
+                units=partial(self._stacked_chunks, K=self.accum_steps),
+                group=self.accum_steps, loss_mode="loose")
+        # per-step: with the guard's finite check on, the step also returns
+        # the on-device global grad norm (the other paths fall back to the
+        # guard's loss-count checks)
+        grad_norm = (self.health_guard is not None
+                     and self.health_guard.cfg.check_finite)
+        step = (
+            make_train_step(apply_fn, loss, p.l2_reg, with_grad_norm=True,
+                            batch_loss=self._batch_loss)
+            if grad_norm else self._train_step
+        )
+        return self._per_step_path(step, grad_norm)
+
+    def _per_step_path(self, step, grad_norm: bool = False) -> EpochPath:
+        aux = ["loss"]
+        if grad_norm:
+            aux.append("grad_norm")
+        if self._batch_loss is not None:
+            aux.append("counters")
+        return EpochPath("train.step", step, _placing(self._put),
+                         aux=tuple(aux))
+
     # ---- device placement ----
-    def _augment_host_emb(self, batch: Batch) -> Batch:
+    def _augment_host_emb(self, batch: Batch) -> tuple[Batch, np.ndarray]:
         """Host-side gather for EmbeddingPlacement=host: hash the
         designated columns, gather their table rows, and append the
         embeddings to the features — only the working set crosses the
-        link.  During a training epoch (``_collect_emb_ids``) the bucket
-        ids queue up FIFO so the epoch loop can pair each step's
-        embedding gradient with its rows; prefetch preserves order."""
+        link.  Also returns the bucket ids, which pair a step's embedding
+        gradient with its rows."""
         x = np.asarray(batch["x"], np.float32)
         emb, ids = self._host_emb.lookup(x[:, list(self._host_emb_pos)])
-        if self._collect_emb_ids:
-            self._emb_ids.append(ids)
         return {**batch,
                 "x": np.concatenate([x, emb.reshape(x.shape[0], -1)],
-                                    axis=1)}
+                                    axis=1)}, ids
 
     def _put(self, batch: Batch) -> Batch:
         if self._host_emb is not None:
-            batch = self._augment_host_emb(batch)
+            batch, _ = self._augment_host_emb(batch)
+        return self._place(batch)
+
+    def _place(self, batch: Batch) -> Batch:
+        if self._batch_sharding is not None:
+            batch = self._pad_for_mesh(batch)
+        return self._to_device(batch, self._batch_sharding)
+
+    def _to_device(self, tree, sharding):
         if self._cross_process:
             from shifu_tensorflow_tpu.parallel.distributed import (
                 put_process_local,
             )
 
-            batch = self._pad_for_mesh(batch)
-            return put_process_local(batch, self._batch_sharding)
-        if self._batch_sharding is not None:
-            batch = self._pad_for_mesh(batch)
-            return jax.device_put(batch, self._batch_sharding)
-        return jax.device_put(batch)
+            return put_process_local(tree, sharding)
+        if sharding is not None:
+            return jax.device_put(tree, sharding)
+        return jax.device_put(tree)
+
+    def _put_host_emb(self, unit: Unit) -> Unit:
+        """The host-embedding path's ``put``: the unit carries the ids of
+        its gather to ``_apply_emb_grad``."""
+        batch, ids = self._augment_host_emb(unit.data)
+        return unit._replace(data=self._place(batch), note=ids)
+
+    def _apply_emb_grad(self, aux: dict, unit: Unit) -> None:
+        """The host-embedding path's ``after_step``: the step returned the
+        gradient of its gathered-embedding slice; pair it with the ids of
+        the unit's gather and apply the sparse Adagrad update before the
+        NEXT batch is gathered.  The device_get per step serializes the
+        pipeline on the gradient fetch — the price of a table the device
+        cannot hold.
+
+        STALENESS CONTRACT: ZERO.  ``prefetch_to_device`` is an
+        unthreaded generator (data/dataset.py) — there is no producer
+        thread — so at depth 1 the gather for batch N runs strictly
+        AFTER step N-1's gradient fetch and table update complete in
+        this same thread.  Every batch reads fully-updated table values;
+        the price is that gather and step never overlap (no infeed
+        pipelining on this path).  Prefetch depth is pinned to 1 here
+        regardless of ``shifu.tpu.prefetch-depth``: a deeper (or ever
+        threaded) lookahead would introduce staleness scaled by a knob
+        documented as an infeed setting — any future move of the gather
+        onto a real producer thread must bring a synchronization story
+        for the numpy table it would then share with ``apply_grads``.
+        Zero staleness is strictly tighter than the reference's
+        fully-async PS reads (arbitrary staleness, ssgd_monitor's PS
+        architecture); the device-placement path also has none (its
+        gather is inside the differentiated step)."""
+        ids = unit.note
+        # the per-step gradient fetch is this path's real completion wait
+        # (the table cannot update without it)
+        with obs_trace.maybe_span(self.tracer, "step.block"):
+            g = np.asarray(jax.device_get(aux["emb_grad"]))[: ids.shape[0]]
+        self._host_emb.apply_grads(
+            ids, g.reshape(ids.shape[0], len(self._host_emb_pos),
+                           self._host_emb.dim))
 
     def _pad_for_mesh(self, batch: Batch) -> Batch:
         """Row count must divide this process's share of the data axis; pad
@@ -1184,15 +1299,7 @@ class Trainer:
 
     def _put_stacked(self, stacked: Batch) -> Batch:
         """Device-place one (S, B, ...) chunk; batch dim sharded."""
-        if self._cross_process:
-            from shifu_tensorflow_tpu.parallel.distributed import (
-                put_process_local,
-            )
-
-            return put_process_local(stacked, self._stacked_sharding)
-        if self._stacked_sharding is not None:
-            return jax.device_put(stacked, self._stacked_sharding)
-        return jax.device_put(stacked)
+        return self._to_device(stacked, self._stacked_sharding)
 
     def align_batch_size(self, batch_size: int) -> int:
         """Round a requested (per-process) batch size up to a divisible one."""
@@ -1223,43 +1330,20 @@ class Trainer:
         b = self.align_batch_size(batch_size)
         xd = np.dtype(x_dtype if x_dtype is not None else np.float32)
 
-        def zeros(rows: int) -> Batch:
+        def zeros() -> Batch:
             return {
-                "x": np.zeros((rows, self.num_features), xd),
-                "y": np.zeros((rows, 1), np.float32),
-                "w": np.zeros((rows, 1), np.float32),
+                "x": np.zeros((b, self.num_features), xd),
+                "y": np.zeros((b, 1), np.float32),
+                "w": np.zeros((b, 1), np.float32),
             }
 
         warmed: list[str] = []
-        if self.scan_steps > 1:
-            stacked = self._put_stacked({
-                k: np.stack([v] * self.scan_steps)
-                for k, v in zeros(b).items()
-            })
-            self.state, _ = self._scan_epoch(self.state, stacked)
-            warmed.append("train.scan_epoch")
-        elif self.accum_steps > 1:
-            stacked = self._put_stacked({
-                k: np.stack([v] * self.accum_steps)
-                for k, v in zeros(b).items()
-            })
-            self.state, _ = self._accum_step(self.state, stacked)
-            warmed.append("train.accum_step")
-        elif self._host_emb_step is not None:
-            batch = self._put(zeros(b))  # _put augments host embeddings
-            self.state, _, _ = self._host_emb_step(self.state, batch)
-            warmed.append("train.host_emb_step")
-        elif self._health_step is not None:
-            batch = self._put(zeros(b))
-            self.state, _ = self._health_step(self.state, batch)
-            warmed.append("train.step")
-        else:
-            batch = self._put(zeros(b))
-            self.state, _ = self._train_step(self.state, batch)
-            warmed.append("train.step")
+        for path in filter(None, (self._path, self._path.tail)):
+            unit = next(iter(path.units(zeros() for _ in range(path.group))))
+            self.state, _ = path.step(self.state, path.put(unit).data)
+            warmed.append(path.name)
         # the eval/validation step shares the batch shape
-        batch = self._put(zeros(b))
-        loss, _ = self._eval_step(self.state.params, batch)
+        loss, _ = self._eval_step(self.state.params, self._put(zeros()))
         jax.block_until_ready(loss)
         jax.block_until_ready(self.state.step)
         warmed.append("train.eval_step")
@@ -1276,28 +1360,33 @@ class Trainer:
         source = batches
         self._infeed_root = source
         try:
-            return self._train_epoch_dispatch(batches)
+            return self._run_epoch(batches)
         finally:
             self._infeed_root = None
             close_stream(source)
 
-    def _infeed(self, batches: Iterable[Batch], put, tracer):
-        """The device-placement stage for an epoch path: pipelined (put
-        thread overlaps dispatch; step.infeed.wait/put split) by default,
-        the inline generator otherwise.  Callers close() the result."""
-        if self.infeed_pipelined:
-            return prefetch_to_device(batches, put=put,
-                                      depth=self.prefetch_depth,
+    def _infeed(self, batches, put, tracer, *, threaded: bool = True,
+                depth: int | None = None):
+        """The device-placement stage of an epoch or eval pass: pipelined
+        (put thread overlaps dispatch; step.infeed.wait/put split) by
+        default, the inline generator otherwise (``threaded=False``: the
+        path forbids the thread).  Callers close() the result."""
+        depth = depth or self.prefetch_depth
+        if self.infeed_pipelined and threaded:
+            return prefetch_to_device(batches, put=put, depth=depth,
                                       pipelined=True, tracer=tracer,
                                       root=self._infeed_root)
         timed = (tracer.timed("step.infeed", put)
                  if tracer is not None else put)
-        return prefetch_to_device(batches, put=timed,
-                                  depth=self.prefetch_depth)
+        return prefetch_to_device(batches, put=timed, depth=depth)
 
-    def _train_epoch_dispatch(self, batches: Iterable[Batch]) -> tuple[float, int]:
+    def _run_epoch(self, batches: Iterable[Batch]) -> tuple[float, int]:
+        """The one epoch loop: ``self._path`` (:class:`EpochPath`) is what
+        differs between the per-step, scanned, accumulated, host-embedding
+        and SAGN epochs."""
         from shifu_tensorflow_tpu.utils import faults as _faults
 
+        path = self._path
         if _faults.active() is not None:
             # straggler-drill seam (utils/faults.py `slow` kind): one
             # check per host batch under site train.step.w<index>, so a
@@ -1310,19 +1399,18 @@ class Trainer:
             batches = _fault_lagged(batches, self.worker_index)
         guard = self.health_guard
         if guard is not None:
-            # instrument the stream BEFORE path dispatch: real-row
-            # bookkeeping, the rollback skip-window, and the nan-loss
-            # injection seam apply to every epoch path identically
+            # instrument the stream of HOST BATCHES, before the path groups
+            # them: real-row bookkeeping, the rollback skip-window, and the
+            # nan-loss injection seam apply to every path identically
             batches = guard.filter_batches(batches)
         tracer = self.tracer
         if tracer is not None:
-            # host-batch production (parse / stack / filter) — wrapped
-            # before path dispatch so every epoch path shares the phase
-            # definition.  Chunk stacking (scan/accum) and device
-            # placement are NOT in here; placement is "step.infeed" at
-            # each path's put, stacking lands in the budget's "other"
-            # slice.  SPAN NAME depends on WHERE production runs: on the
-            # unthreaded paths (host-emb, infeed_pipelined off) it stalls
+            # host-batch production (parse / stack / filter).  Grouping
+            # into units (scan/accum stacking) and device placement are
+            # NOT in here; placement is "step.infeed*" at the put,
+            # stacking lands in the budget's "other" slice.  SPAN NAME
+            # depends on WHERE production runs: unthreaded (the path
+            # forbids the put thread, or infeed_pipelined is off) it stalls
             # the consumer and is the disjoint "step.host" phase; under
             # pipelined infeed it runs on the put thread and OVERLAPS
             # dispatch, so it records as "step.host.produce" — reported
@@ -1330,130 +1418,70 @@ class Trainer:
             # from the wall-clock budget, where counting it would
             # double-book the overlapped seconds (the consumer-visible
             # stall is step.infeed.wait alone).
-            overlapped = self.infeed_pipelined and self._host_emb is None
+            overlapped = self.infeed_pipelined and path.threaded
             batches = tracer.wrap_iter(
                 "step.host.produce" if overlapped else "step.host",
                 batches)
-        if self._host_emb is not None:
-            return self._train_epoch_host_emb(batches)
-        if self._scan_epoch is not None:
-            return self._train_epoch_scan(batches)
-        if self._accum_step is not None:
-            return self._train_epoch_accum(batches)
-        losses = []
-        gnorms = []
-        counters = []
-        step_fn = self._health_step or self._train_step
-        feed = self._infeed(batches, self._put, tracer)
+        with_norm = "grad_norm" in path.aux
+        with_counters = "counters" in path.aux
+        losses, gnorms, counters, weights = [], [], [], []
+
+        def via(unit: Unit) -> EpochPath:
+            return path.tail if unit.tail else path
+
+        feed = self._infeed(
+            path.units(batches), lambda unit: via(unit).put(unit),
+            tracer, threaded=path.threaded, depth=path.depth)
         try:
-            for batch in feed:
+            for unit in feed:
                 with obs_trace.maybe_span(tracer, "step.dispatch"):
-                    self.state, out = step_fn(self.state, batch)
-                if self._batch_loss is not None:
-                    *out, step_counters = out
-                    counters.append(step_counters)
-                    out = out[0] if len(out) == 1 else tuple(out)
-                if self._health_step is not None:
-                    loss, gnorm = out
-                    gnorms.append(gnorm)
-                else:
-                    loss = out
-                losses.append(loss)
+                    self.state, aux = via(unit).step(self.state, unit.data)
+                if path.after_step is not None:
+                    path.after_step(aux, unit)
+                losses.append(aux["loss"])
+                if with_norm:
+                    gnorms.append(aux["grad_norm"])
+                if with_counters:
+                    counters.append(aux["counters"])
+                weights.append(unit.batches)
                 if guard is not None:
                     guard.tick()
                 if self.step_timer is not None:
-                    self.step_timer.step(loss, rows=batch["x"].shape[0])
+                    self.step_timer.step(aux["loss"], rows=unit.rows)
         finally:
             close_stream(feed)
         if not losses:
             return float("nan"), 0
         with obs_trace.maybe_span(tracer, "step.block"):
-            vals = np.asarray(jax.device_get(losses))
+            # one loss a unit, or (scan) one a batch of it
+            vals = np.asarray(jax.device_get(losses)).reshape(-1)
             gvals = (np.asarray(jax.device_get(gnorms))
-                     if gnorms else None)
-            if counters:
+                     if with_norm else None)
+            if with_counters:
                 fetched = jax.device_get(counters)
                 self.epoch_counters = {
                     k: np.asarray([c[k] for c in fetched])
                     for k in fetched[0]}
         if guard is not None:
-            guard.note_losses(vals, gvals, mode="aligned")
-        # all-padding batches report NaN by contract (make_train_step);
+            guard.note_losses(vals, gvals, mode=path.loss_mode)
+        # all-padding units report NaN by contract (apply_update);
         # exclude them from the epoch mean instead of biasing it
-        real = vals[~np.isnan(vals)]
-        return (
-            float(np.mean(real)) if real.size else float("nan"),
-            len(losses),
-        )
+        real = ~np.isnan(vals)
+        if not real.any():
+            mean = float("nan")
+        elif path.weighted:
+            mean = float(np.average(
+                vals[real].astype(np.float64),
+                weights=np.asarray(weights, np.float64)[real]))
+        else:
+            mean = float(np.mean(vals[real]))
+        return mean, sum(weights)
 
-    def _train_epoch_host_emb(self, batches: Iterable[Batch]) -> tuple[float, int]:
-        """Per-step epoch for host-resident embeddings: each step returns
-        the gradient of its gathered-embedding slice; the host pairs it
-        with the FIFO'd bucket ids (queued by _augment_host_emb under
-        prefetch, order-preserving) and applies the sparse Adagrad update
-        before the ids of the NEXT consumed batch are popped.  The
-        device_get per step serializes the pipeline on the gradient
-        fetch — the price of a table the device cannot hold.
-
-        STALENESS CONTRACT: ZERO.  ``prefetch_to_device`` is an
-        unthreaded generator (data/dataset.py) — there is no producer
-        thread — so at depth 1 the gather for batch N runs strictly
-        AFTER step N-1's gradient fetch and table update complete in
-        this same thread.  Every batch reads fully-updated table values;
-        the price is that gather and step never overlap (no infeed
-        pipelining on this path).  Prefetch depth is pinned to 1 here
-        regardless of ``shifu.tpu.prefetch-depth``: a deeper (or ever
-        threaded) lookahead would introduce staleness scaled by a knob
-        documented as an infeed setting — any future move of the gather
-        onto a real producer thread must bring a synchronization story
-        for the numpy table it would then share with ``apply_grads``.
-        Zero staleness is strictly tighter than the reference's
-        fully-async PS reads (arbitrary staleness, ssgd_monitor's PS
-        architecture); the device-placement path also has none (its
-        gather is inside the differentiated step)."""
-        losses = []
-        self._emb_ids.clear()
-        self._collect_emb_ids = True
-        tracer = self.tracer
-        put = (tracer.timed("step.infeed", self._put)
-               if tracer is not None else self._put)
-        try:
-            for batch in prefetch_to_device(batches, put=put,
-                                            depth=1):
-                with obs_trace.maybe_span(tracer, "step.dispatch"):
-                    self.state, loss, g_emb = self._host_emb_step(
-                        self.state, batch)
-                ids = self._emb_ids.popleft()
-                # the per-step gradient fetch is this path's real
-                # completion wait (the table cannot update without it)
-                with obs_trace.maybe_span(tracer, "step.block"):
-                    g = np.asarray(jax.device_get(g_emb))[: ids.shape[0]]
-                self._host_emb.apply_grads(
-                    ids, g.reshape(ids.shape[0], len(self._host_emb_pos),
-                                   self._host_emb.dim))
-                losses.append(loss)
-                if self.health_guard is not None:
-                    self.health_guard.tick()
-                if self.step_timer is not None:
-                    self.step_timer.step(loss, rows=ids.shape[0])
-        finally:
-            self._collect_emb_ids = False
-            self._emb_ids.clear()
-        if not losses:
-            return float("nan"), 0
-        with obs_trace.maybe_span(tracer, "step.block"):
-            vals = np.asarray(jax.device_get(losses))
-        if self.health_guard is not None:
-            self.health_guard.note_losses(vals, mode="aligned")
-        real = vals[~np.isnan(vals)]
-        return (
-            float(np.mean(real)) if real.size else float("nan"),
-            len(losses),
-        )
-
-    def _stacked_chunks(self, batches: Iterable[Batch], K: int):
-        """Group K batches into stacked ``(K, B, ...)`` chunks for the
-        scan/accum paths; returns ``(generator, rows_meta, counts)``.
+    def _stacked_chunks(self, batches: Iterable[Batch],
+                        K: int) -> Iterator[Unit]:
+        """Group K batches into stacked ``(K, B, ...)`` chunks, the units
+        of the scan/accum paths: each carries its real (unpadded) row
+        count and the real batches in it.
 
         The last chunk pads with zero-weight no-op batches (exact no-ops
         by the step bodies' has_rows/zero-count gates).  The stacked row
@@ -1465,18 +1493,8 @@ class Trainer:
         sizes.  Cross-process SPMD stays in lockstep because
         fixed_step_batches already guarantees identical per-process batch
         counts, hence identical chunk counts and padding.
-
-        ``rows_meta`` is a FIFO of each chunk's real (unpadded) row
-        count: prefetch runs the producer ahead of the consumer, but
-        order is preserved, so the head entry always describes the chunk
-        currently being consumed.  ``counts["real"]`` accumulates the
-        real batch count.
         """
-        import collections
-
         fixed_rows: int | None = None
-        rows_meta: collections.deque[int] = collections.deque()
-        counts = {"real": 0}
 
         def _pad_rows(b: Batch, rows: int) -> Batch:
             """Zero-weight-pad a batch up to ``rows`` — free under the
@@ -1515,102 +1533,18 @@ class Trainer:
                 for k in buf[0]
             }
 
-        def gen():
-            buf: list[Batch] = []
-            for b in batches:
-                buf.append(b)
-                if len(buf) == K:
-                    counts["real"] += K
-                    rows_meta.append(sum(c["x"].shape[0] for c in buf))
-                    yield _emit(buf)
-                    buf = []
-            if buf:
-                counts["real"] += len(buf)
-                rows_meta.append(sum(c["x"].shape[0] for c in buf))
-                yield _emit(buf)
+        def unit(buf: list[Batch]) -> Unit:
+            return Unit(_emit(buf), sum(c["x"].shape[0] for c in buf),
+                        len(buf))
 
-        return gen(), rows_meta, counts
-
-    def _train_epoch_scan(self, batches: Iterable[Batch]) -> tuple[float, int]:
-        """Chunked-scan epoch: K batches stacked per device dispatch —
-        K sequential optimizer updates in ONE dispatch.  Update semantics
-        are identical to the per-step path — same body, same order; only
-        the dispatch granularity changes (see _stacked_chunks for the
-        shape discipline)."""
-        chunks, rows_meta, counts = self._stacked_chunks(
-            batches, self.scan_steps
-        )
-        tracer = self.tracer
-        losses = []  # (K,) device arrays, chunk-pad entries NaN
-        feed = self._infeed(chunks, self._put_stacked, tracer)
-        try:
-            for stacked in feed:
-                with obs_trace.maybe_span(tracer, "step.dispatch"):
-                    self.state, chunk_losses = self._scan_epoch(
-                        self.state, stacked)
-                losses.append(chunk_losses)
-                chunk_rows = rows_meta.popleft()
-                if self.health_guard is not None:
-                    self.health_guard.tick()
-                if self.step_timer is not None:
-                    self.step_timer.step(chunk_losses, rows=chunk_rows)
-        finally:
-            close_stream(feed)
-        if not losses:
-            return float("nan"), 0
-        with obs_trace.maybe_span(tracer, "step.block"):
-            vals = np.concatenate(
-                [np.atleast_1d(np.asarray(v))
-                 for v in jax.device_get(losses)]
-            )
-        if self.health_guard is not None:
-            # per-batch losses, but chunking lost the batch order; the
-            # guard checks that every real batch produced a finite loss
-            self.health_guard.note_losses(vals, mode="counted")
-        real = vals[~np.isnan(vals)]
-        return (
-            float(np.mean(real)) if real.size else float("nan"),
-            counts["real"],
-        )
-
-    def _train_epoch_accum(self, batches: Iterable[Batch]) -> tuple[float, int]:
-        """Accumulated epoch: A microbatches stacked per ONE optimizer
-        update (make_accum_step) — the update equals a single step on the
-        concatenated batch, so global_step advances once per group.  The
-        reported batch count stays the real microbatch count (data
-        accounting); the epoch loss is the nanmean of per-UPDATE losses
-        (a short tail group's zero-weight pad micros contribute nothing)."""
-        chunks, rows_meta, counts = self._stacked_chunks(
-            batches, self.accum_steps
-        )
-        tracer = self.tracer
-        losses = []  # scalars, one per update; all-padding groups NaN
-        feed = self._infeed(chunks, self._put_stacked, tracer)
-        try:
-            for stacked in feed:
-                with obs_trace.maybe_span(tracer, "step.dispatch"):
-                    self.state, loss = self._accum_step(self.state, stacked)
-                losses.append(loss)
-                chunk_rows = rows_meta.popleft()
-                if self.health_guard is not None:
-                    self.health_guard.tick()
-                if self.step_timer is not None:
-                    self.step_timer.step(loss, rows=chunk_rows)
-        finally:
-            close_stream(feed)
-        if not losses:
-            return float("nan"), 0
-        with obs_trace.maybe_span(tracer, "step.block"):
-            vals = np.asarray(jax.device_get(losses))
-        if self.health_guard is not None:
-            # one loss per UPDATE group — a NaN may be a padding group, so
-            # only the inf and epoch-mean checks apply here
-            self.health_guard.note_losses(vals, mode="loose")
-        real = vals[~np.isnan(vals)]
-        return (
-            float(np.mean(real)) if real.size else float("nan"),
-            counts["real"],
-        )
+        buf: list[Batch] = []
+        for b in batches:
+            buf.append(b)
+            if len(buf) == K:
+                yield unit(buf)
+                buf = []
+        if buf:
+            yield unit(buf)
 
     #: best-snapshot persistence filename inside the checkpoint directory
     _BEST_FILE = "keep-best.npz"
@@ -2230,12 +2164,12 @@ class Trainer:
             # budget degenerates to dispatch + block (no per-step
             # host/infeed phases exist to measure)
             with obs_trace.maybe_span(self.tracer, "step.dispatch"):
-                self.state, losses = epoch_fn(
+                self.state, aux = epoch_fn(
                     self.state, train_dev,
                     jax.random.fold_in(base_key, epoch)
                 )
             with obs_trace.maybe_span(self.tracer, "step.block"):
-                vals = np.asarray(jax.device_get(losses))
+                vals = np.asarray(jax.device_get(aux["loss"]))
             real = vals[~np.isnan(vals)]
             train_loss = float(np.mean(real)) if real.size else float("nan")
             train_time = time.time() - t0

@@ -238,10 +238,10 @@ def test_scan_steps_key_reaches_trainer():
     )
     trainer = make_trainer(mc, 2, feature_columns=(0, 1), scan_steps=8)
     assert trainer.scan_steps == 8
-    assert trainer._scan_epoch is not None
+    assert trainer._path.name == "train.scan_epoch"
     # default stays on the per-step path
     trainer = make_trainer(mc, 2, feature_columns=(0, 1))
-    assert trainer.scan_steps == 1 and trainer._scan_epoch is None
+    assert trainer.scan_steps == 1 and trainer._path.name == "train.step"
 
 
 def test_accum_steps_key_reaches_trainer():
@@ -265,10 +265,10 @@ def test_accum_steps_key_reaches_trainer():
     )
     trainer = make_trainer(mc, 2, feature_columns=(0, 1), accum_steps=4)
     assert trainer.accum_steps == 4
-    assert trainer._accum_step is not None
+    assert trainer._path.name == "train.accum_step"
     # default stays on the per-step path
     trainer = make_trainer(mc, 2, feature_columns=(0, 1))
-    assert trainer.accum_steps == 1 and trainer._accum_step is None
+    assert trainer.accum_steps == 1 and trainer._path.name == "train.step"
 
 
 def test_keep_best_key_reaches_trainer():

@@ -261,20 +261,21 @@ def test_ladder_disabled_knob_reproduces_raw_shape_churn(tmp_path):
 def test_attribute_region_records_eager_pallas_compiles(tmp_path,
                                                         pallas_interpret):
     """The attribute() seam catches compiles with no jitted callable to
-    lower: an eager Pallas embedding gather journals under the pallas
+    lower: an eager Pallas flash attention journals under the pallas
     name (timing only — no signature/analysis, by contract)."""
     import jax.numpy as jnp
 
-    from shifu_tensorflow_tpu.ops.pallas.embedding import embedding_gather
+    from shifu_tensorflow_tpu.ops.pallas.flash_attention import (
+        flash_attention,
+    )
 
     path = _journal(tmp_path)
     _recorder()
-    ids = jnp.arange(8, dtype=jnp.int32)
-    table = jnp.ones((32, 4), jnp.float32)
-    np.asarray(embedding_gather(ids, table))
+    qkv = jnp.ones((1, 16, 1, 8), jnp.float32)
+    np.asarray(flash_attention(qkv, qkv, qkv))
     journal_mod.uninstall()
     evs = [e for e in read_events(path) if e["event"] == "compile"]
-    pallas = [e for e in evs if e["name"] == "pallas.embedding_gather"]
+    pallas = [e for e in evs if e["name"] == "pallas.flash_attention"]
     assert pallas, [e["name"] for e in evs]
     assert pallas[0]["compile_s"] > 0
 

@@ -145,3 +145,29 @@ def test_wide_deep_keeps_embedding_columns():
     x = jnp.ones((2, 3), jnp.float32)
     variables = model.init(jax.random.key(0), x)
     assert model.apply(variables, x).shape == (2, 1)
+
+
+def test_trainer_with_embeddings_still_trains(model_config_json):
+    """The factory threads shard_embeddings through; a trainer without a
+    'model' axis must build and train the embedding-augmented model."""
+    from shifu_tensorflow_tpu.config.model_config import ModelConfig
+    from shifu_tensorflow_tpu.train.trainer import Trainer
+
+    mc = dict(model_config_json)
+    mc["train"] = dict(mc["train"])
+    mc["train"]["params"] = dict(
+        mc["train"]["params"],
+        EmbeddingColumnNums=[2, 3],
+        EmbeddingHashSize=64,
+        EmbeddingDim=4,
+    )
+    trainer = Trainer(ModelConfig.from_json(mc), 4,
+                      feature_columns=(0, 1, 2, 3))
+    rng = np.random.default_rng(1)
+    batch = {
+        "x": rng.normal(size=(32, 4)).astype(np.float32),
+        "y": (rng.random((32, 1)) < 0.5).astype(np.float32),
+        "w": np.ones((32, 1), np.float32),
+    }
+    loss, n = trainer.train_epoch(iter([batch]))
+    assert n == 1 and np.isfinite(loss)

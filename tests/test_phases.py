@@ -73,10 +73,10 @@ def _step_texts(path: str, embedding_dim: int = 4) -> dict:
             fn, batch = trainer._train_step, _batch(8)
         elif path == "scan":
             trainer = _wdl_trainer(embedding_dim, scan_steps=2)
-            fn, batch = trainer._scan_epoch, _batch(2, 8)
+            fn, batch = trainer._path.step, _batch(2, 8)
         elif path == "accum":
             trainer = _wdl_trainer(embedding_dim, accum_steps=2)
-            fn, batch = trainer._accum_step, _batch(2, 8)
+            fn, batch = trainer._path.step, _batch(2, 8)
         else:
             import jax
 
@@ -130,7 +130,7 @@ def _lm_step_text() -> str:
         batch = {"x": np.ones((2, 16), np.float32),
                  "y": np.ones((2, 1), np.float32),
                  "w": np.ones((2, 1), np.float32)}
-        _STEP_TEXTS["lm"] = trainer._health_step.lower(
+        _STEP_TEXTS["lm"] = trainer._path.step.lower(
             trainer.state, batch).compile().as_text()
     return _STEP_TEXTS["lm"]
 
@@ -198,27 +198,6 @@ def test_step_programs_keep_their_module_names(path, module):
     read ``jit_train_step``: the jitted functions are not renamed."""
     assert module == _step_texts(path)["module"]
     assert profile_mod.STEP_PROGRAM == "jit_train_step"
-
-
-def test_pallas_gather_carries_the_same_scope(pallas_interpret):
-    """Either implementation of the lookup is ``embed.gather``."""
-    import jax
-    import jax.numpy as jnp
-
-    from shifu_tensorflow_tpu.models.embeddings import HashedEmbedding
-
-    model = HashedEmbedding(hash_size=128, features=8, impl="pallas",
-                            shard_table=False)
-    x = jnp.ones((8, 2), jnp.float32)
-    variables = model.init(jax.random.key(0), x)
-
-    def loss(v):
-        return model.apply(v, x).sum()
-
-    text = jax.jit(jax.grad(loss)).lower(variables).as_text(debug_info=True)
-    assert "embed.hash" in text
-    assert re.search(r"jvp\([^\"]*embed\.gather", text), text[:2000]
-    assert re.search(r"transpose\(jvp\([^\"]*embed\.gather", text)
 
 
 @pytest.mark.parametrize("op_name,phase", [

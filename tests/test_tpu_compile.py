@@ -22,7 +22,6 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import NamedSharding, SingleDeviceSharding
 
-from shifu_tensorflow_tpu.ops.pallas.embedding import embedding_gather
 from shifu_tensorflow_tpu.ops.pallas.flash_attention import flash_attention
 
 
@@ -163,21 +162,6 @@ def test_grouped_experts_lower_at_the_lm_cells_shape(topo):
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
-def test_embedding_gather_lowers_for_v5e(topo):
-    """81,920 ids (16,384 rows x 5 hashed columns) into the 1,048,576 x 8
-    table of the flagship: gather forward, scatter-add backward."""
-    one_chip = SingleDeviceSharding(topo.devices[0])
-    ids = jax.ShapeDtypeStruct((81_920,), jnp.int32, sharding=one_chip)
-    table = jax.ShapeDtypeStruct((1_048_576, 8), jnp.float32,
-                                 sharding=one_chip)
-
-    def loss(t, i):
-        return jnp.sum(embedding_gather(i, t) ** 2)
-
-    compiled = jax.jit(jax.grad(loss)).lower(table, ids).compile()
-    assert _kernels(compiled) == 2
-
-
 # ----------------------------------------------------- the flagship step
 
 
@@ -193,8 +177,7 @@ def _step_and_shapes(mc, columns, mesh=None, with_grad_norm=False):
     )
 
     sharded = mesh is not None and mesh.shape.get("model", 1) > 1
-    model = build_model(mc, columns, shard_embeddings=sharded,
-                        embedding_impl="xla", mesh=mesh)
+    model = build_model(mc, columns, shard_embeddings=sharded, mesh=mesh)
     tx = make_optimizer(mc.params)
 
     def init():

@@ -323,8 +323,7 @@ def test_all_padding_batch_keeps_every_leaf_and_rows_apply_gradients(
         if stacked:
             batch = {k: v[None] for k, v in batch.items()}
         # the steps donate their state: hand each a copy of its own
-        out = step(jax.tree_util.tree_map(jnp.copy, state), batch)
-        return out[0], jax.tree_util.tree_leaves(out[1:])
+        return step(jax.tree_util.tree_map(jnp.copy, state), batch)
 
     # one real update first, so mu, nu, count and step are all nonzero
     state, _ = run(trainer.state, np.ones((32, 1), np.float32))
@@ -332,8 +331,8 @@ def test_all_padding_batch_keeps_every_leaf_and_rows_apply_gradients(
     assert int(before.step) == 1
 
     kept, aux = run(state, np.zeros((32, 1), np.float32))
-    assert np.isnan(np.asarray(aux[0])).all()  # the loss(es)
-    for extra in aux[1:]:  # gradient norm / embedding gradients
+    assert np.isnan(np.asarray(aux.pop("loss"))).all()  # the loss(es)
+    for extra in aux.values():  # gradient norm / embedding gradients
         assert not np.asarray(extra).any()
     old, new = (jax.tree_util.tree_leaves_with_path(t)
                 for t in (before, jax.device_get(kept)))
@@ -350,7 +349,7 @@ def test_all_padding_batch_keeps_every_leaf_and_rows_apply_gradients(
     want = jax.device_get(state.apply_gradients(
         grads=jax.grad(loss)(state.params)))
     got, aux = run(state, w)
-    assert np.isfinite(np.asarray(aux[0])).all()
+    assert np.isfinite(np.asarray(aux["loss"])).all()
     assert int(got.step) == int(want.step) == 2
     for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(want),
                             jax.tree_util.tree_leaves(jax.device_get(got))):
@@ -387,12 +386,12 @@ def test_scan_epoch_fixed_shape_and_timer_rows():
     assert n == 5
     assert rows_seen == [64, 28, 16]  # real rows per chunk, in order
     assert np.isfinite(loss)
-    sizes = trainer._scan_epoch._cache_size()
+    sizes = trainer._path.step._cache_size()
     assert sizes == 1, f"expected one compiled scan shape, got {sizes}"
     # a LARGER later batch regrows once — exactly one extra compile
     loss2, n2 = trainer.train_epoch(iter([mk(48), mk(32)]))
     assert n2 == 2
-    assert trainer._scan_epoch._cache_size() == 2
+    assert trainer._path.step._cache_size() == 2
 
 
 # ---- gradient accumulation (shifu.tpu.accum-steps) ----
@@ -933,8 +932,8 @@ def test_bf16_transport_widens_on_device_fp32_compute():
         for i in range(0, n, 128):
             sl = slice(i, i + 128)
             batch = tr._put({"x": x[sl], "y": y[sl], "w": w[sl]})
-            tr.state, loss = tr._train_step(tr.state, batch)
-            losses.append(float(loss))
+            tr.state, aux = tr._train_step(tr.state, batch)
+            losses.append(float(aux["loss"]))
         return tr, losses
 
     tr32, l32 = run(x32)
