@@ -1,4 +1,5 @@
-"""The dense gradient of an embedding lookup, built in lines of whole lanes.
+"""An embedding table written and read in lines of whole lanes: the dense
+gradient of a lookup, and the lookup of many rows.
 
 ``jnp.take(table, ids, axis=0)`` transposes to a scatter-add of one
 gradient row a lookup into a zeroed table.  On the TPU a ``(rows, 32)``
@@ -28,6 +29,22 @@ nothing for a lookup that repeats the line before it.  So
 Only the order in which equal ids' rows are added differs from XLA's
 transpose.  ``models/embeddings.py`` ``take_rows`` is the lookup that
 carries this backward, and runs it per device on a mesh.
+
+The READ pays the same layout: XLA's gather out of the rows-on-lanes
+table costs 38 ns a lookup of 32 floats, a tile at a time.  Where the
+lookups are many against the table (``lines_pay``), the forward,
+``rows_by_lines``, goes the other way through the same lines (PERF.md
+section 6, PR 29):
+
+1. turns the table into lines, once a step, whatever the batch (1.7 ms
+   at 4,194,304 x 32: what the table pays for not resting in lines);
+2. fetches a lookup's whole line, 512 contiguous bytes, in 10 ns;
+3. picks each row off its line and writes the rows on the lanes, which
+   is the layout their readers want.
+
+It is a copy of float32 words, equal to ``jnp.take`` bit for bit.
+Nothing rests differently: parameters, moments, checkpoints and the
+backward keep the ``(rows, dim)`` table.
 """
 
 from __future__ import annotations
@@ -48,6 +65,43 @@ def rows_a_line(dim: int) -> int:
     keeps XLA's transpose (sorted lookups scattered into the table's own
     shape cost 59.7 ms and their sort and gather, against its 54.9)."""
     return LANES // dim if dim % 8 == 0 and LANES % dim == 0 else 0
+
+
+# Turning this many table rows into lines costs what one lookup gains by
+# fetching a line: the turn is paid for the whole table whatever the
+# batch.  Break-even on the chip is 64-77 at 32 floats a row and 42-64 at
+# 16 (PERF.md section 6, PR 29).
+ROWS_TURNED_FOR_A_LOOKUP = 64
+# ... where a row lies over two (8, 128) tiles of the rows-on-lanes table
+# or more, which XLA's gather pays one by one (21 ns a lookup at 16
+# floats, 38 at 32).  A row of 8 floats lies in one tile and comes in
+# 4-13 ns, a line in 10.
+LINES_FROM_DIM = 16
+
+
+def lines_pay(rows: int, dim: int, dtype, lookups) -> bool:
+    """Whether ``lookups`` lookups into a ``(rows, dim)`` table are many
+    enough to read it through lines.  A count that is no concrete integer
+    (an exported program's symbolic batch) is not."""
+    return bool(rows_a_line(dim)) and dim >= LINES_FROM_DIM \
+        and dtype == jnp.float32 and isinstance(lookups, int) \
+        and lookups * ROWS_TURNED_FOR_A_LOOKUP >= rows
+
+
+def _blocks(rows: int, pack: int) -> int:
+    """Blocks of 128 lines that hold ``rows`` rows, the last one in part."""
+    return -(-rows // (LANES * pack))
+
+
+def _line_of(row: jax.Array, pack: int) -> jax.Array:
+    """The line that holds table row ``row``: row ``(q * pack + j) * 128
+    + l`` rests in line ``q * 128 + l``."""
+    return row // (LANES * pack) * LANES + row % LANES
+
+
+def _place_of(row: jax.Array, pack: int) -> jax.Array:
+    """... and its place ``j`` there: lanes ``j * dim ...``."""
+    return row // LANES % pack
 
 
 def _rows_from_lines(lines: jax.Array, dim: int) -> jax.Array:
@@ -93,6 +147,126 @@ def _turned_by_the_kernel(lines: jax.Array, dim: int) -> jax.Array:
                                        lines.dtype))(lines).T
 
 
+def _lines_from_rows(table: jax.Array, dim: int) -> jax.Array:
+    """``(blocks * 128, 128)`` lines from a ``(rows, dim)`` table, the
+    inverse of ``_rows_from_lines`` under the same map; ``blocks`` is
+    ``rows / (128 * pack)`` rounded up, and what the last block holds
+    beyond ``rows`` is not defined.  The kernel reads ``table.T``, which
+    costs nothing in the table's resting layout."""
+    return lax.platform_dependent(
+        table, tpu=functools.partial(_lined_by_the_kernel, dim=dim),
+        default=functools.partial(_lined_by_xla, dim=dim))
+
+
+def _lined_by_xla(table: jax.Array, dim: int) -> jax.Array:
+    pack = LANES // dim
+    blocks = _blocks(table.shape[0], pack)
+    table = jnp.pad(
+        table, ((0, blocks * LANES * pack - table.shape[0]), (0, 0)))
+    return table.T.reshape(dim, blocks, pack, LANES).transpose(
+        1, 3, 2, 0).reshape(blocks * LANES, LANES)
+
+
+def _lined_by_the_kernel(table: jax.Array, dim: int) -> jax.Array:
+    from jax.experimental import pallas as pl
+
+    pack = LANES // dim
+    blocks = _blocks(table.shape[0], pack)
+    step = math.gcd(blocks, 8)  # blocks a grid step: 512 KB in, 512 KB out
+
+    def turn(rows_ref, lines_ref):
+        for q in range(step):
+            at = q * pack * LANES
+            lines_ref[q * LANES:(q + 1) * LANES, :] = jnp.concatenate(
+                [rows_ref[:, at + j * LANES:at + (j + 1) * LANES]
+                 for j in range(pack)], axis=0).T
+
+    return pl.pallas_call(
+        turn, grid=(blocks // step,),
+        in_specs=[pl.BlockSpec((dim, step * pack * LANES),
+                               lambda i: (0, i))],
+        out_specs=pl.BlockSpec((step * LANES, LANES), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((blocks * LANES, LANES),
+                                       table.dtype))(table.T)
+
+
+def _rows_off_lines(taken: jax.Array, place: jax.Array,
+                    dim: int) -> jax.Array:
+    """``(N, dim)``: of each fetched line ``taken[n]`` the ``dim`` lanes
+    at ``place[n]``, zeros where the place is -1.  A copy, so a select
+    and no sum over the places (``-0.0 + 0.0`` is ``+0.0``).  XLA:TPU
+    writes the selected rows lane-padded, rows on the sublanes, and then
+    copies them into the rows-on-lanes layout every reader wants: 4.3 ms
+    for 425,984 lookups, as much as fetching the lines; the kernel turns
+    a block of lines in VMEM and writes ``.T`` of the result, 0.45 ms
+    (PERF.md section 6, PR 29)."""
+    return lax.platform_dependent(
+        taken, place, tpu=functools.partial(_picked_by_the_kernel, dim=dim),
+        default=functools.partial(_picked_by_xla, dim=dim))
+
+
+def _picked_by_xla(taken: jax.Array, place: jax.Array,
+                   dim: int) -> jax.Array:
+    rows = jnp.zeros((taken.shape[0], dim), taken.dtype)
+    for j in range(LANES // dim):
+        rows = jnp.where(place[:, None] == j,
+                         taken[:, j * dim:(j + 1) * dim], rows)
+    return rows
+
+
+def _picked_by_the_kernel(taken: jax.Array, place: jax.Array,
+                          dim: int) -> jax.Array:
+    from jax.experimental import pallas as pl
+
+    n = taken.shape[0]
+    lookups = 2048  # a grid step: 1 MB of lines in, 256 KB of rows out
+
+    def pick(taken_ref, place_ref, rows_ref):
+        turned, at = taken_ref[...].T, place_ref[...]  # (128, n), (1, n)
+        rows = jnp.zeros(rows_ref.shape, rows_ref.dtype)
+        for j in range(LANES // dim):
+            rows = jnp.where(at == j, turned[j * dim:(j + 1) * dim], rows)
+        rows_ref[...] = rows
+
+    return pl.pallas_call(
+        pick, grid=(pl.cdiv(n, lookups),),
+        in_specs=[pl.BlockSpec((lookups, LANES), lambda i: (i, 0)),
+                  pl.BlockSpec((1, lookups), lambda i: (0, i))],
+        out_specs=pl.BlockSpec((dim, lookups), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((dim, n), taken.dtype))(
+            taken, place[None, :]).T
+
+
+def rows_by_lines(table: jax.Array, ids: jax.Array,
+                  row_offset=None) -> jax.Array:
+    """``table[ids]`` for ids of any shape into a ``(rows, D)`` table with
+    ``rows_a_line(D)``, bit for bit: the table is turned into lines once,
+    each lookup fetches the 512 contiguous bytes of its line (10 ns, where
+    32 words strided over four tiles cost 38), and its row is picked off
+    the line.  With a ``row_offset`` the table is the shard that starts at
+    that row, and a lookup of another shard's row reads zeros; without,
+    every id is promised to be a row of the table."""
+    num_rows, dim = table.shape
+    pack = rows_a_line(dim)
+    with jax.named_scope("lines.turn"):
+        lines = _lines_from_rows(table, dim)
+    with jax.named_scope("lines.take"):
+        local = ids.reshape(-1)
+        if row_offset is None:
+            place = _place_of(local, pack)
+        else:
+            local = local - row_offset
+            mine = (local >= 0) & (local < num_rows)
+            # another shard's lookup fetches some line of this shard and
+            # picks nothing off it; spread over the table they cost what
+            # the shard's own do, all sent to one line 1 ms more
+            local = local % num_rows
+            place = jnp.where(mine, _place_of(local, pack), -1)
+        taken = lines.at[_line_of(local, pack)].get(
+            mode="promise_in_bounds")
+        return _rows_off_lines(taken, place, dim).reshape(*ids.shape, dim)
+
+
 def dense_row_grad(ids: jax.Array, rows: jax.Array, num_rows: int,
                    row_offset=0) -> jax.Array:
     """``zeros((num_rows, D)).at[ids - row_offset].add(rows)`` for ids
@@ -100,11 +274,9 @@ def dense_row_grad(ids: jax.Array, rows: jax.Array, num_rows: int,
     range dropped: sums in float32, cast once to ``rows.dtype``."""
     n, dim = rows.shape
     pack = rows_a_line(dim)
-    num_lines = -(-num_rows // (LANES * pack)) * LANES  # whole blocks
+    num_lines = _blocks(num_rows, pack) * LANES
     local = ids - row_offset
-    # line * pack + place on the line
-    key = ((local // (LANES * pack) * LANES + local % LANES) * pack
-           + local // LANES % pack)
+    key = _line_of(local, pack) * pack + _place_of(local, pack)
     mine = (local >= 0) & (local < num_rows)
     key, order = lax.sort_key_val(
         jnp.where(mine, key, num_lines * pack), lax.iota(jnp.int32, n))

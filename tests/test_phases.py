@@ -60,6 +60,7 @@ def _batch(*lead):
 
 
 _STEP_TEXTS: dict = {}
+LINES_DIM = 32  # four rows to a 128-lane line; the tiny table is 32 rows
 
 
 def _step_texts(path: str, embedding_dim: int = 4) -> dict:
@@ -186,6 +187,29 @@ def test_the_lookups_own_backward_is_filed_under_the_gather(path):
     assert any("/shard_map/" in n for n in names) == (path == "mesh")
     sorts = [n for n in names if n.endswith("/sort")]
     assert {phase_of(n) for n in sorts} == {"embed.gather.bwd"}, sorts
+
+
+@pytest.mark.parametrize("path", ["per_step", "scan", "mesh"])
+def test_the_lookups_forward_by_lines_is_filed_under_the_gather(path):
+    """``ops/embedding_grad.py`` ``rows_by_lines``: the turn of the table
+    into lines, the gather of a line a lookup and the pick of the rows off
+    them carry inner scopes (``lines.turn``, ``lines.take``) below
+    ``embed.gather`` and are the forward's; the backward's ops stay the
+    backward's."""
+    names = _op_names(_step_texts(path, embedding_dim=LINES_DIM)["compiled"])
+    inner = r"/embed\.gather/(shard_map/)?lines\.%s/(cond/branch_\d_fun/)?%s$"
+    for scope, op in (("turn", "transpose"), ("take", "gather"),
+                      ("take", r"(jit\(_where\)/)?select_n")):
+        ours = [n for n in names if re.search(inner % (scope, op), n)]
+        assert ours, (scope, op, sorted(names))
+        assert {phase_of(n) for n in ours} == {"embed.gather.fwd"}, ours
+    assert any("/shard_map/lines." in n for n in names) == (path == "mesh")
+    assert not [n for n in names if n.endswith("jit(_take)/gather")
+                and "/embed.gather/" in n], "no gather of table rows"
+    for op in ("sort", "scatter-add"):
+        theirs = [n for n in names if "/embed.gather/" in n
+                  and n.endswith("/" + op)]
+        assert {phase_of(n) for n in theirs} == {"embed.gather.bwd"}, theirs
 
 
 @pytest.mark.parametrize("path,module", [

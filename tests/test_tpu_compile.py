@@ -251,6 +251,8 @@ def test_table_step_keeps_its_resting_layout_on_one_v5e_chip(topo):
     assert "conditional" not in text
     assert not re.findall(r"= f32\[%d,%d\]\{1,0[^}]*\} copy\(" % table, text)
     _assert_the_gradient_is_scattered_in_lines(text, table)
+    _assert_the_rows_are_read_by_lines(text, table)
+    assert _kernels(compiled) == 3  # the two turns and the forward's pick
 
 
 def _assert_the_gradient_is_scattered_in_lines(text, table):
@@ -266,6 +268,64 @@ def _assert_the_gradient_is_scattered_in_lines(text, table):
     assert "indices_are_sorted=true" in scatters[0], scatters[0]
     assert len(re.findall(r"= f32\[%d,%d\]\{1,0[^ ]* custom-call\(.*"
                           r"tpu_custom_call" % (dim, rows), text)) == 1
+
+
+def _gather_operands(text) -> list[str]:
+    """The shape (with its layout) of what every gather reads."""
+    shape_of = dict(re.findall(r"%(\S+) = (\S+) ", text))
+    return [shape_of[operand]
+            for operand in re.findall(r" gather\(%([^,)]+)", text)]
+
+
+def _assert_the_rows_are_read_by_lines(text, table):
+    """``ops/embedding_grad.py`` ``rows_by_lines``: no gather out of the
+    table's own shape (rows on the lanes: 38 ns a lookup); one kernel
+    turns the table into lines, one gather fetches a line a lookup, one
+    kernel picks the rows off them and writes them rows-on-lanes."""
+    rows, dim = table
+    read = _gather_operands(text)
+    assert not [s for s in read if s.startswith("f32[%d,%d]" % table)], read
+    assert len([s for s in read if s.startswith(
+        "f32[%d,128]{1,0" % (rows * dim // 128))]) == 1, read
+    assert len(re.findall(r"= f32\[%d,128\]\{1,0[^ ]* custom-call\(.*"
+                          r"tpu_custom_call" % (rows * dim // 128),
+                          text)) == 1
+    assert len(re.findall(r"= f32\[%d,\d+\]\{1,0[^ ]* custom-call\(.*"
+                          r"tpu_custom_call" % dim, text)) == 2
+
+
+def test_a_scoring_forward_of_the_table_model_keeps_the_plain_gather(topo):
+    """512 rows are 13,312 lookups into 4,194,304 rows: turning the whole
+    table into lines (1.7 ms) would cost three times what XLA's gather of
+    them does (0.58 ms), so the forward ``EvalModel``, the AOT ladder and
+    the SavedModel run reads the table as it rests and holds no kernel."""
+    import json
+
+    from shifu_tensorflow_tpu.config.model_config import ModelConfig
+    from shifu_tensorflow_tpu.models.factory import build_model
+
+    cell = os.path.join(os.path.dirname(__file__), os.pardir, "benchmark",
+                        "configs", "wdl_criteo.json")
+    with open(cell) as f:
+        config = json.load(f)
+    mc = ModelConfig.from_json(config["model_config"])
+    table = (mc.params.embedding_hash_size, mc.params.embedding_dim)
+    features = config["data"]["numeric"] + config["data"]["categorical"]
+    model = build_model(mc, tuple(range(1, features + 1)))
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.key(0),
+                           jnp.zeros((1, features)))["params"])
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    compiled = jax.jit(
+        lambda params, x: model.apply({"params": params}, x)).lower(
+            _on(one_chip, params),
+            jax.ShapeDtypeStruct((512, features), jnp.float32,
+                                 sharding=one_chip)).compile()
+    assert _kernels(compiled) == 0
+    read = _gather_operands(compiled.as_text())
+    assert len([s for s in read
+                if s.startswith("f32[%d,%d]" % table)]) == 1, read
+    assert not [s for s in read if ",128]" in s], read
 
 
 def _placed_on(mesh, state):
@@ -292,7 +352,10 @@ def test_table_step_runs_its_backward_per_device_on_the_2x2_mesh(topo):
     8,388,608 x 32 rows over ``model:2``, global batch 32,768 over
     ``data:2``): every device sorts its own 425,984 lookups and scatters
     into its own 4,194,304 rows.  A sort along the sharded batch under
-    the partitioner would gather every data shard's gradient rows first."""
+    the partitioner would gather every data shard's gradient rows first.
+    The forward is per device too: a device turns its own shard into
+    lines and reads its data shard's lookups from them, and the parts'
+    sum over ``model`` stays the partitioner's ``all-reduce``."""
     import dataclasses
     import json
 
@@ -318,8 +381,11 @@ def test_table_step_runs_its_backward_per_device_on_the_2x2_mesh(topo):
         state, _on(batch_sharding(mesh), batch)).compile()
     text = compiled.as_text()
     _assert_the_gradient_is_scattered_in_lines(text, (rows // 2, dim))
+    _assert_the_rows_are_read_by_lines(text, (rows // 2, dim))
     assert re.search(r"f32\[%d,%d\][^ ]* all-reduce\(" % (rows // 2, dim),
                      text), "the sum over data is the dense all-reduce"
+    assert re.search(r"f32\[16384,%d\][^ ]* all-reduce\(" % (26 * dim),
+                     text), "the sum of the model shards' rows, as it was"
     assert not re.findall(r"f32\[\d+,%d\][^ ]* all-gather" % dim, text)
 
 
