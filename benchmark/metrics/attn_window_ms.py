@@ -1,0 +1,21 @@
+"""``attn_window_ms`` — layer: models models/ ops/.  Unit ``ms``, source
+``device_trace``; should move ``train_rows_per_s``.
+
+Device ms a step inside ``attn.window``, forward + backward summed (the
+backward's recomputed forward included): the sliding-window layers' cores:
+the banded flash kernels, which walk the blocks of the band only, and the
+repeat of the KV heads.  From ``obs.profile.phases`` on the run's own
+capture, handed on by the plane; ``None`` on a reading without the phase
+or of another configuration's kind.
+"""
+
+LAYER = "models models/ ops/"
+UNIT = "ms"
+SOURCE = "device_trace"
+MOVES = "train_rows_per_s"
+
+from benchmark.swa_lm_readings import swa_phase_ms
+
+
+def read(r):
+    return swa_phase_ms(r, "attn.window")

@@ -54,22 +54,95 @@ def require_servable(model_type: str, plane: str) -> None:
         raise UnsupportedModelType(str(model_type).lower(), plane)
 
 
+#: ``layer_types`` / ``mlp_layer_types`` entries -> pattern characters
+_ATTENTION_KINDS = {"full_attention": "*", "sliding_attention": "W"}
+_PATTERN_LAYER_TYPES = {v: k for k, v in _ATTENTION_KINDS.items()}
+
+
+@dataclass(frozen=True)
+class RopeParameters:
+    """One entry of a public config's ``rope_parameters``: ``default``
+    (``theta^(-m / (head_dim / 2))``) or ``yarn`` (those frequencies
+    blended with their ``1 / factor`` interpolation between the
+    ``beta_fast`` and ``beta_slow`` correction dimensions, cos and sin
+    scaled by ``attention_factor``; 0 = ``0.1 ln(factor) + 1``)."""
+
+    rope_type: str = "default"
+    rope_theta: float = 10000.0
+    factor: float = 1.0
+    original_max_position_embeddings: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 0.0
+
+    @classmethod
+    def from_json(cls, obj: Mapping[str, Any]) -> "RopeParameters":
+        import dataclasses
+
+        known = {f.name: f.type for f in dataclasses.fields(cls)}
+        unknown = sorted(set(obj) - set(known))
+        if unknown:
+            raise ValueError(
+                f"rope_parameters keys {unknown} are not implemented "
+                f"(known: {sorted(known)})")
+        cast = {"str": str, "float": float, "int": int}
+        rope = cls(**{k: cast[known[k]](v) for k, v in obj.items()})
+        if rope.rope_type not in ("default", "yarn"):
+            raise ValueError(
+                f"rope_type={rope.rope_type!r} is not implemented "
+                "(default | yarn)")
+        if rope.rope_type == "yarn" and (
+                rope.factor <= 1.0
+                or rope.original_max_position_embeddings <= 0):
+            raise ValueError(
+                "rope_type=yarn needs factor > 1 and "
+                "original_max_position_embeddings")
+        return rope
+
+
 @dataclass(frozen=True)
 class HybridLMConfig:
-    """``ModelType: hybrid_lm`` — the keys of a public ``nemotron_h``
-    ``config.json`` under their own names (``train.params`` carries them
-    beside ``ModelType``), plus the share this chip holds.
+    """``ModelType: hybrid_lm`` — the keys of a public ``config.json``
+    under their own names (``train.params`` carries them beside
+    ``ModelType``), plus the share this chip holds.  Two public shapes are
+    read:
+
+    - ``nemotron_h``: ``hybrid_override_pattern``, one mixer a layer (``M``
+      Mamba-2, ``E`` experts, ``*`` causal attention, ``W`` causal
+      attention inside ``sliding_window``), sigmoid-scored ``relu2``
+      experts beside a shared expert, no rotary;
+    - ``mellum``: ``layer_types`` + ``mlp_layer_types``, a block a list
+      entry; block ``i`` becomes two layers of the pattern, its attention
+      (``sliding_attention`` -> ``W``, ``full_attention`` -> ``*``) and
+      its ``sparse`` experts (``E``); ``rope_parameters`` per layer type
+      (``default`` | ``yarn``), ``hidden_act: silu`` (the gated expert
+      ``W_down(silu(W_gate h) * W_up h)``), ``scoring_func: softmax``,
+      ``n_shared_experts: 0``, ``num_experts`` for the router's width and
+      ``rms_norm_eps`` for the norms'.
 
     ``n_routed_experts`` is the router's width (all the experts there
     are); ``experts_held`` = (first id, count) the experts whose weights
     live here; ``vocab_size`` is the slice of the vocabulary the embedding
-    and the head hold (a sliced vocabulary is a smaller vocabulary)."""
+    and the head hold (a sliced vocabulary is a smaller vocabulary);
+    ``expert_tile`` the rows of a grouped-product tile (0: the family's,
+    models/hybrid_lm.py ``EXPERT_TILE``), sized with the share: a held
+    expert's pairs a step should fill whole tiles with room to spare, not
+    end on a tile's edge.  A public config states one ``initializer_range``;
+    a training recipe may state two more, each 0 for "the same":
+    ``embedding_initializer_range`` (the token embedding's) and
+    ``output_initializer_range`` (every mixer's projection back onto the
+    residual stream: ``o_proj``, the experts' and the shared expert's
+    ``down``, Mamba's ``out_proj``; the scaled initialisation of
+    pre-training recipes, ``initializer_range / sqrt(2 x blocks)``).  A
+    combination the code does not implement is a config error by name."""
 
     hidden_size: int
     hybrid_override_pattern: str
     vocab_size: int
     layer_norm_epsilon: float = 1e-5
     initializer_range: float = 0.02
+    embedding_initializer_range: float = 0.0  # 0 = initializer_range
+    output_initializer_range: float = 0.0  # 0 = initializer_range
     # Mamba-2
     mamba_num_heads: int = 64
     mamba_head_dim: int = 64
@@ -89,15 +162,77 @@ class HybridLMConfig:
     norm_topk_prob: bool = True
     routed_scaling_factor: float = 1.0
     experts_held: tuple[int, int] = (0, 0)  # (first id, count); 0 = all
+    expert_tile: int = 0  # rows of a grouped-product tile; 0 = EXPERT_TILE
+    hidden_act: str = "relu2"  # relu2 | silu (gated)
+    scoring_func: str = "sigmoid"  # sigmoid (+ correction bias) | softmax
     # attention
     num_attention_heads: int = 4
     num_key_value_heads: int = 1
     head_dim: int = 16
+    sliding_window: int = 0  # keys a ``W`` layer's query sees, itself one
+    #: ((layer type, RopeParameters), ...); a type without one: no rotary
+    rope_parameters: tuple = ()
+
+    #: public spellings of the same number
+    ALIASES = (("num_experts", "n_routed_experts"),
+               ("rms_norm_eps", "layer_norm_epsilon"))
+
+    @property
+    def embedding_std(self) -> float:
+        return self.embedding_initializer_range or self.initializer_range
+
+    @property
+    def output_std(self) -> float:
+        return self.output_initializer_range or self.initializer_range
+
+    def rope_for(self, kind: str) -> "RopeParameters | None":
+        """The rotary parametrisation of a ``*`` or ``W`` layer."""
+        return dict(self.rope_parameters).get(_PATTERN_LAYER_TYPES[kind])
+
+    @staticmethod
+    def pattern_of(params: Mapping[str, Any]) -> str:
+        """``layer_types`` + ``mlp_layer_types`` as a pattern string."""
+        kinds = [str(k) for k in params["layer_types"]]
+        mlps = [str(k) for k in params.get("mlp_layer_types",
+                                           ["sparse"] * len(kinds))]
+        if len(mlps) != len(kinds):
+            raise ValueError(
+                f"mlp_layer_types has {len(mlps)} entries, layer_types "
+                f"{len(kinds)}")
+        bad = sorted(set(kinds) - set(_ATTENTION_KINDS))
+        if bad:
+            raise ValueError(
+                f"layer_types {bad} are not implemented "
+                f"({' | '.join(_ATTENTION_KINDS)})")
+        if set(mlps) - {"sparse"}:
+            raise ValueError(
+                f"mlp_layer_types {sorted(set(mlps) - {'sparse'})} are not "
+                "implemented (sparse: the family has no dense gated "
+                "feed-forward)")
+        return "".join(_ATTENTION_KINDS[k] + "E" for k in kinds)
 
     @classmethod
     def from_json(cls, params: Mapping[str, Any]) -> "HybridLMConfig":
         import dataclasses
 
+        params = dict(params)
+        for public, ours in cls.ALIASES:
+            if public in params:
+                if ours in params and params[ours] != params[public]:
+                    raise ValueError(
+                        f"{public}={params[public]} and {ours}="
+                        f"{params[ours]} name the same number")
+                params.setdefault(ours, params[public])
+        blocks = None
+        if "layer_types" in params:
+            pattern = cls.pattern_of(params)
+            if params.get("hybrid_override_pattern", pattern) != pattern:
+                raise ValueError(
+                    "hybrid_override_pattern="
+                    f"{params['hybrid_override_pattern']!r} but layer_types "
+                    f"+ mlp_layer_types give {pattern!r}")
+            params["hybrid_override_pattern"] = pattern
+            blocks = len(params["layer_types"])
         known = {f.name: f for f in dataclasses.fields(cls)}
         missing = [k for k in ("hidden_size", "hybrid_override_pattern",
                                "vocab_size") if k not in params]
@@ -111,8 +246,12 @@ class HybridLMConfig:
             v = params[name]
             if name == "experts_held":
                 kw[name] = (int(v[0]), int(v[1]))
+            elif name == "rope_parameters":
+                kw[name] = tuple(sorted(
+                    (str(kind), RopeParameters.from_json(r))
+                    for kind, r in v.items()))
             elif f.type in ("int",):
-                kw[name] = int(v)
+                kw[name] = int(v or 0)
             elif f.type in ("float",):
                 kw[name] = float(v)
             elif f.type in ("bool",):
@@ -123,26 +262,33 @@ class HybridLMConfig:
         if cfg.experts_held[1] <= 0:
             cfg = dataclasses.replace(
                 cfg, experts_held=(0, cfg.n_routed_experts))
-        cfg.validate(params)
+        cfg.validate(params, blocks)
         return cfg
 
-    def validate(self, params: Mapping[str, Any] = ()) -> None:
-        bad = set(self.hybrid_override_pattern) - set("ME*")
-        if bad or not self.hybrid_override_pattern:
+    def validate(self, params: Mapping[str, Any] = (),
+                 blocks: "int | None" = None) -> None:
+        pattern = self.hybrid_override_pattern
+        bad = set(pattern) - set("MEW*")
+        if bad or not pattern:
             raise ValueError(
                 "hybrid_override_pattern is a string of M (Mamba-2), E "
-                f"(experts) and * (attention); got {sorted(bad)}")
+                "(experts), * (attention) and W (attention inside "
+                f"sliding_window); got {sorted(bad)}")
         layers = params.get("num_hidden_layers") if params else None
-        if layers is not None and int(layers) != len(
-                self.hybrid_override_pattern):
+        expect = len(pattern) if blocks is None else blocks
+        if layers is not None and int(layers) != expect:
             raise ValueError(
-                f"num_hidden_layers={layers} but hybrid_override_pattern "
-                f"has {len(self.hybrid_override_pattern)} characters")
+                f"num_hidden_layers={layers} but "
+                + (f"hybrid_override_pattern has {expect} characters"
+                   if blocks is None else f"layer_types has {expect} entries"))
         for key in ("n_group", "topk_group"):
             if params and int(params.get(key, 1)) != 1:
                 raise ValueError(
                     f"{key}={params[key]}: group-limited routing is not "
                     "implemented (the family routes over all experts)")
+        for key in ("attention_bias", "mlp_bias"):
+            if params and _parse_bool(params.get(key, False)):
+                raise ValueError(f"{key}=true is not implemented")
         first, count = self.experts_held
         if first < 0 or first + count > self.n_routed_experts:
             raise ValueError(
@@ -150,6 +296,39 @@ class HybridLMConfig:
                 f"router's {self.n_routed_experts} experts")
         if self.num_experts_per_tok > self.n_routed_experts:
             raise ValueError("num_experts_per_tok > n_routed_experts")
+        if self.expert_tile < 0 or self.expert_tile % 8:
+            raise ValueError(
+                f"expert_tile={self.expert_tile}: a tile is a multiple of 8 "
+                "rows (0: the family's)")
+        for key in ("initializer_range", "embedding_initializer_range",
+                    "output_initializer_range"):
+            if getattr(self, key) < 0:
+                raise ValueError(f"{key}={getattr(self, key)} is negative")
+        if self.hidden_act not in ("relu2", "silu"):
+            raise ValueError(
+                f"hidden_act={self.hidden_act!r} is not implemented "
+                "(relu2 | silu, the gated expert)")
+        if self.scoring_func not in ("sigmoid", "softmax"):
+            raise ValueError(
+                f"scoring_func={self.scoring_func!r} is not implemented "
+                "(sigmoid | softmax)")
+        if self.n_shared_experts < 0 or (
+                self.n_shared_experts and self.hidden_act != "relu2"):
+            raise ValueError(
+                f"n_shared_experts={self.n_shared_experts} with hidden_act="
+                f"{self.hidden_act!r} is not implemented (the shared "
+                "expert is relu2; a gated model states n_shared_experts 0)")
+        if "W" in pattern and self.sliding_window <= 0:
+            raise ValueError(
+                "a W (sliding_attention) layer needs sliding_window > 0")
+        unknown = sorted(set(dict(self.rope_parameters))
+                         - set(_ATTENTION_KINDS))
+        if unknown:
+            raise ValueError(
+                f"rope_parameters for {unknown}: the layer types are "
+                f"{' | '.join(_ATTENTION_KINDS)}")
+        if self.rope_parameters and self.head_dim % 2:
+            raise ValueError("rotary positions need an even head_dim")
         if self.mamba_num_heads % self.n_groups:
             raise ValueError("n_groups must divide mamba_num_heads")
         if self.num_attention_heads % self.num_key_value_heads:

@@ -80,10 +80,14 @@ def build_model(
         single = mesh is None or mesh.size == 1
         impl = ("flash" if single and jax.default_backend() == "tpu"
                 else "chunked")
+        window = p.hybrid_lm.sliding_window
         return HybridLM(
             cfg=p.hybrid_lm,
             attention=make_attention(impl, None, causal=True),
             dtype=dtype,
+            window_attention=(
+                make_attention(impl, None, causal=True, window=window)
+                if "W" in p.hybrid_lm.hybrid_override_pattern else None),
         )
     if p.seq_len > 0 and p.model_type != "sequence":
         raise ValueError(

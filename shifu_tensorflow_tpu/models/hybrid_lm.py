@@ -1,19 +1,27 @@
 """Hybrid decoder family (``ModelType: hybrid_lm``): a causal language
 model whose layers are each ONE mixer in a pre-norm residual,
 ``x <- x + mixer(RMSNorm(x))``, the mixer chosen per layer by a pattern
-string as the public ``nemotron_h`` configuration writes it:
+string as the public ``nemotron_h`` configuration writes it (a ``mellum``
+configuration's block, attention then experts, is two such layers:
+config/model_config.py ``HybridLMConfig``):
 
 - ``M``  Mamba-2 (``ssm.conv`` + ``ssm.scan``: ops/ssm_scan.py's chunked
   scan), gate before the grouped RMSNorm;
-- ``E``  routed experts (sigmoid gate over ALL ``n_routed_experts``, top-k
-  of score + correction bias, weights normalised over the k chosen and
-  scaled) + one shared expert, all ``W_down relu(W_up h)^2``.  The layer is
-  told which experts it holds (``experts_held`` = first id, count): it
-  routes over all of them, computes the held experts' part for the tokens
-  that chose them (ops/grouped.py: sort, grouped products, unsort) and
-  leaves out what the absent experts would add.  No token is dropped;
-- ``*``  causal grouped-query attention, no rotary embedding (the Mamba
-  layers carry position).
+- ``E``  routed experts: a score over ALL ``n_routed_experts`` (``sigmoid``
+  with top-k of score + correction bias, or ``softmax`` with top-k of the
+  score), weights normalised over the k chosen and scaled; the expert
+  ``W_down relu(W_up h)^2`` (``hidden_act: relu2``, beside one shared
+  expert of the same form) or gated, ``W_down(silu(W_gate h) * W_up h)``
+  (``silu``, no shared expert).  The layer is told which experts it holds
+  (``experts_held`` = first id, count): it routes over all of them,
+  computes the held experts' part for the tokens that chose them
+  (ops/grouped.py: sort, grouped products, unsort) and leaves out what
+  the absent experts would add.  No token is dropped;
+- ``*``  causal grouped-query attention; ``W`` the same inside
+  ``sliding_window`` (key ``j`` visible to query ``i`` iff ``j <= i`` and
+  ``i - j < sliding_window``).  Rotary positions where the layer's type
+  has ``rope_parameters`` (``default`` or ``yarn``: :func:`rope_tables`);
+  none otherwise (the Mamba layers carry position).
 
 Ingest compatibility (as models/sequence.py): a PSV row carries its
 ``S`` token ids in the float32 feature block; the model casts them on
@@ -23,11 +31,20 @@ beside ``__call__`` (logits) and the trainer's step builders take it
 through ``models/factory.py`` ``family_loss``.  Every layer and the head
 are rematerialised in the backward pass.
 
+Initialisation: normal, ``initializer_range`` for every matrix; the token
+embedding at ``embedding_initializer_range`` and every mixer's projection
+back onto the residual stream at ``output_initializer_range`` where the
+configuration states them (at one range for all, uniform attention makes
+every token of a row the running mean of its window by the second block,
+and a row's tokens then choose the same experts: PERF.md section 6).
+
 The phase names (``jax.named_scope``; obs/profile.py ``PHASE_SCOPES``):
 ``embed.gather``, ``ssm.proj`` (in/out projections, gate and grouped
 norm), ``ssm.conv``, ``ssm.scan``, ``moe.route``, ``moe.experts``,
-``moe.shared``, ``attn.proj`` (q, k, v, o), ``attn.core``, ``lm.head``.  The
-residual stream's own norms and adds carry no scope.
+``moe.shared``, ``attn.proj`` (q, k, v, o), ``attn.rope`` (the rotation of
+q and k), ``attn.core`` (a ``*`` layer's core), ``attn.window`` (a ``W``
+layer's), ``lm.head``.  The residual stream's own norms and adds carry no
+scope.
 """
 
 from __future__ import annotations
@@ -38,8 +55,12 @@ from typing import Any, Callable
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from shifu_tensorflow_tpu.config.model_config import HybridLMConfig
+from shifu_tensorflow_tpu.config.model_config import (
+    HybridLMConfig,
+    RopeParameters,
+)
 from shifu_tensorflow_tpu.ops import grouped, ssm_scan
 
 #: rows of a grouped-product tile.  A tile costs its rows' products or the
@@ -49,7 +70,9 @@ from shifu_tensorflow_tpu.ops import grouped, ssm_scan
 #: 1,024 rows are twice the products a weight read hides and pad half a
 #: tile in six.  The one-chip benchmark cell sends an expert 384-650 pairs,
 #: where this size multiplies more padding than rows; PERF.md section 6
-#: has both sizes' numbers there, for a perf_opt to choose by
+#: has both sizes' numbers there, for a perf_opt to choose by.  A
+#: configuration whose share sends an expert a load that ends on this
+#: tile's edge states its own (``expert_tile``)
 EXPERT_TILE = 1024
 
 
@@ -178,7 +201,7 @@ class MambaMixer(nn.Module):
             # grouped RMSNorm, the gate before the norm
             y = GroupNormScale(groups, c.layer_norm_epsilon, dt_,
                                name="norm")(y)
-            return Kernel(c.hidden_size, c.initializer_range, dt_,
+            return Kernel(c.hidden_size, c.output_std, dt_,
                           name="out_proj")(y)
 
 
@@ -201,29 +224,38 @@ class GroupNormScale(nn.Module):
 
 
 class ExpertWeights(nn.Module):
+    """``up``, ``down`` of the held experts, after ``gate`` where the
+    expert is gated."""
+
     held: int
     width: int
     std: float
+    down_std: float
+    gated: bool = False
 
     @nn.compact
     def __call__(self, hidden: int):
-        up = self.param("up", _normal(self.std),
-                        (self.held, hidden, self.width), jnp.float32)
-        down = self.param("down", _normal(self.std),
+        into = (self.held, hidden, self.width)
+        gate = ((self.param("gate", _normal(self.std), into, jnp.float32),)
+                if self.gated else ())
+        up = self.param("up", _normal(self.std), into, jnp.float32)
+        down = self.param("down", _normal(self.down_std),
                           (self.held, self.width, hidden), jnp.float32)
-        return up, down
+        return gate + (up, down)
 
 
 class SharedExpert(nn.Module):
     width: int
     std: float
+    down_std: float
     dtype: Any = jnp.float32
 
     @nn.compact
     def __call__(self, x):
         h = Kernel(self.width, self.std, self.dtype, name="up")(x)
         h = jnp.square(nn.relu(h))
-        return Kernel(x.shape[-1], self.std, self.dtype, name="down")(h)
+        return Kernel(x.shape[-1], self.down_std, self.dtype,
+                      name="down")(h)
 
 
 class MoEMixer(nn.Module):
@@ -244,43 +276,106 @@ class MoEMixer(nn.Module):
             # dtype: a rounded score flips choices between near-tied experts
             w_r = Weights((d, c.n_routed_experts), c.initializer_range,
                           name="router")()
-            scores = nn.sigmoid(jnp.dot(
-                flat.astype(jnp.float32), w_r,
-                precision=jax.lax.Precision.HIGHEST))
-            bias = self.param("e_score_correction_bias",
-                              nn.initializers.zeros, (c.n_routed_experts,),
-                              jnp.float32)
-            _, ids = jax.lax.top_k(scores + bias, c.num_experts_per_tok)
+            logits = jnp.dot(flat.astype(jnp.float32), w_r,
+                             precision=jax.lax.Precision.HIGHEST)
+            if c.scoring_func == "softmax":
+                scores = jax.nn.softmax(logits, axis=-1)
+                _, ids = jax.lax.top_k(scores, c.num_experts_per_tok)
+            else:
+                scores = nn.sigmoid(logits)
+                bias = self.param(
+                    "e_score_correction_bias", nn.initializers.zeros,
+                    (c.n_routed_experts,), jnp.float32)
+                _, ids = jax.lax.top_k(scores + bias, c.num_experts_per_tok)
             weights = jnp.take_along_axis(scores, ids, axis=-1)
             if c.norm_topk_prob:
                 weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
                                      + 1e-20)
             weights = weights * c.routed_scaling_factor
+            tile = c.expert_tile or EXPERT_TILE
             pair, tile_expert, n_tiles, counts = grouped.plan_tiles(
-                ids, first, held, EXPERT_TILE)
+                ids, first, held, tile)
             k = c.num_experts_per_tok
             token = jnp.where(pair < ids.size, pair // k, flat.shape[0])
             gate = jnp.where(
                 pair < ids.size,
                 jnp.take(weights.reshape(-1), pair, mode="clip"), 0.0)
-        up, down = ExpertWeights(held, c.moe_intermediate_size,
-                                 c.initializer_range, name="experts")(d)
+        gated = c.hidden_act == "silu"
+        matrices = ExpertWeights(held, c.moe_intermediate_size,
+                                 c.initializer_range, c.output_std, gated,
+                                 name="experts")(d)
         with jax.named_scope("moe.experts"):
-            routed = grouped.expert_mlp(
-                flat.astype(dt_), up.astype(dt_), down.astype(dt_), token,
-                gate, tile_expert, n_tiles, EXPERT_TILE)
-        with jax.named_scope("moe.shared"):
-            shared = SharedExpert(
-                c.moe_shared_expert_intermediate_size * c.n_shared_experts,
-                c.initializer_range, dt_, name="shared")(flat)
-        out = (routed.astype(dt_) + shared).reshape(bsz, s, d)
-        return out, jnp.stack([jnp.sum(counts), jnp.max(counts)])
+            walk = grouped.gated_expert_mlp if gated else grouped.expert_mlp
+            out = walk(flat.astype(dt_), *(w.astype(dt_) for w in matrices),
+                       token, gate, tile_expert, n_tiles,
+                       tile).astype(dt_)
+        if c.n_shared_experts:
+            with jax.named_scope("moe.shared"):
+                out = out + SharedExpert(
+                    c.moe_shared_expert_intermediate_size
+                    * c.n_shared_experts,
+                    c.initializer_range, c.output_std, dt_,
+                    name="shared")(flat)
+        return (out.reshape(bsz, s, d),
+                jnp.stack([jnp.sum(counts), jnp.max(counts)]))
+
+
+def rope_frequencies(rope: RopeParameters, head_dim: int):
+    """``(frequencies (head_dim / 2,) float64, scale of cos and sin)``.
+    ``default``: ``theta^(-m / (head_dim / 2))``, scale 1.  ``yarn``:
+    ``(1 - g_m) b_m / factor + g_m b_m`` with ``g_m = 1 - clip((m - low) /
+    (high - low), 0, 1)``, ``low`` / ``high`` the floor / ceiling of the
+    dimensions that turn ``beta_fast`` / ``beta_slow`` times over the
+    original context, and the scale ``attention_factor``."""
+    half = head_dim // 2
+    base = rope.rope_theta ** (-np.arange(half, dtype=np.float64) / half)
+    if rope.rope_type != "yarn":
+        return base, 1.0
+
+    def turns(r):  # the dimension that turns r times over the context
+        return half * math.log(rope.original_max_position_embeddings
+                               / (2 * math.pi * r)) / math.log(
+                                   rope.rope_theta)
+
+    low = max(math.floor(turns(rope.beta_fast)), 0)
+    high = min(math.ceil(turns(rope.beta_slow)), head_dim - 1)
+    ramp = np.clip((np.arange(half) - low) / max(high - low, 1e-3), 0, 1)
+    keep = 1.0 - ramp
+    scale = rope.attention_factor or 0.1 * math.log(rope.factor) + 1.0
+    return (1 - keep) * base / rope.factor + keep * base, scale
+
+
+def rope_tables(rope: RopeParameters, seq: int, head_dim: int):
+    """``(cos, sin)`` (S, head_dim / 2) float32, the scale folded in: the
+    angle is the float32 product of the position and the float32
+    frequency, as the public implementations compute it."""
+    freq, scale = rope_frequencies(rope, head_dim)
+    angle = (jnp.arange(seq, dtype=jnp.float32)[:, None]
+             * jnp.asarray(freq, jnp.float32)[None, :])
+    return jnp.cos(angle) * scale, jnp.sin(angle) * scale
+
+
+def apply_rope(u, cos, sin):
+    """``u cos + rotate_half(u) sin`` over the last axis of (B, S, H, D),
+    ``rotate_half(u) = (-u[D/2:], u[:D/2])``: dimension ``m`` pairs with
+    ``m + D/2``."""
+    half = u.shape[-1] // 2
+    u1, u2 = u[..., :half], u[..., half:]
+    cos = cos[None, :, None, :].astype(u.dtype)
+    sin = sin[None, :, None, :].astype(u.dtype)
+    return jnp.concatenate([u1 * cos - u2 * sin, u2 * cos + u1 * sin],
+                           axis=-1)
 
 
 class AttentionMixer(nn.Module):
+    """``kind`` ``*``: ``attention`` over every earlier key under
+    ``attn.core``; ``W``: ``attention`` (the caller's windowed one) under
+    ``attn.window``."""
+
     cfg: HybridLMConfig
     attention: Callable
     dtype: Any = jnp.float32
+    kind: str = "*"
 
     @nn.compact
     def __call__(self, x):
@@ -295,14 +390,20 @@ class AttentionMixer(nn.Module):
                 bsz, s, nkv, hd)
             v = Kernel(nkv * hd, std, dt_, name="v_proj")(x).reshape(
                 bsz, s, nkv, hd)
-        with jax.named_scope("attn.core"):
+        rope = c.rope_for(self.kind)
+        if rope is not None:
+            with jax.named_scope("attn.rope"):
+                cos, sin = rope_tables(rope, s, hd)
+                q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+        with jax.named_scope("attn.window" if self.kind == "W"
+                             else "attn.core"):
             # each KV head serves nq / nkv query heads: the repeat's
             # transpose sums their gradients
             k = jnp.repeat(k, nq // nkv, axis=2)
             v = jnp.repeat(v, nq // nkv, axis=2)
             y = self.attention(q, k, v)
         with jax.named_scope("attn.proj"):
-            return Kernel(c.hidden_size, std, dt_, name="o_proj")(
+            return Kernel(c.hidden_size, c.output_std, dt_, name="o_proj")(
                 y.reshape(bsz, s, nq * hd))
 
 
@@ -311,6 +412,7 @@ class Layer(nn.Module):
     cfg: HybridLMConfig
     attention: Callable
     dtype: Any = jnp.float32
+    window_attention: "Callable | None" = None
 
     @nn.compact
     def __call__(self, x):
@@ -321,7 +423,9 @@ class Layer(nn.Module):
         elif self.kind == "E":
             y, stats = MoEMixer(self.cfg, self.dtype, name="mixer")(h)
         else:
-            y = AttentionMixer(self.cfg, self.attention, self.dtype,
+            attention = (self.window_attention if self.kind == "W"
+                         else self.attention)
+            y = AttentionMixer(self.cfg, attention, self.dtype, self.kind,
                                name="mixer")(h)
         return x + y, stats
 
@@ -352,19 +456,22 @@ class LMHead(nn.Module):
 
 class HybridLM(nn.Module):
     """``__call__(x)``: logits (B, S, vocab held).  ``loss(x, w)``: what the
-    trainer differentiates (see :func:`batch_loss`)."""
+    trainer differentiates (see :func:`batch_loss`).  ``window_attention``
+    is the ``W`` layers' core (``attention`` inside ``sliding_window``)."""
 
     cfg: HybridLMConfig
     attention: Callable
     dtype: Any = jnp.float32
+    window_attention: "Callable | None" = None
 
     def setup(self):
         c = self.cfg
         self.embed = nn.Embed(c.vocab_size, c.hidden_size,
-                              embedding_init=_normal(c.initializer_range),
+                              embedding_init=_normal(c.embedding_std),
                               param_dtype=jnp.float32)
         layer = nn.remat(Layer)
-        self.layers = [layer(kind, c, self.attention, self.dtype)
+        self.layers = [layer(kind, c, self.attention, self.dtype,
+                             self.window_attention)
                        for kind in c.hybrid_override_pattern]
         self.final_norm = RMSNorm(c.layer_norm_epsilon, self.dtype)
         self.lm_head = nn.remat(LMHead)(c.vocab_size, c.hidden_size,

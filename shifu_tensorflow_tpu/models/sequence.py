@@ -135,6 +135,7 @@ def make_attention(
     seq_len: int = 0,
     num_heads: int = 0,
     causal: bool = False,
+    window: "int | None" = None,
 ) -> AttentionFn:
     """Resolve ``SeqAttention`` to a callable; 'auto' picks ring iff the
     mesh has a 'seq' axis of size > 1.  Shape constraints (seq axis must
@@ -142,8 +143,13 @@ def make_attention(
     validated HERE so misconfiguration is a config error naming the keys,
     not an opaque shard_map/all_to_all trace failure.  ``causal`` masks
     keys after the query (the single-device implementations; the decoder
-    family, models/hybrid_lm.py, passes it)."""
+    family, models/hybrid_lm.py, passes it); ``window`` (causal only)
+    also those ``window`` or more positions before it: a mask in ``full``
+    and ``chunked``, skipped blocks in ``flash``."""
     from shifu_tensorflow_tpu.parallel import ring
+
+    if window is not None and not causal:
+        raise ValueError("a window needs causal attention")
 
     seq_axis = mesh.shape.get(ring.SEQ_AXIS, 1) if mesh is not None else 1
     has_seq = seq_axis > 1
@@ -157,12 +163,13 @@ def make_attention(
             impl = "full"
     if impl == "full":
         if causal:
-            return partial(ring.full_attention, causal=True)
+            return partial(ring.full_attention, causal=True, window=window)
         return ring.full_attention
     if impl == "chunked":
         def attention(q, k, v):
             return ring.chunked_attention(
-                q, k, v, causal=causal, block_size=_chunked_block())
+                q, k, v, causal=causal, block_size=_chunked_block(),
+                window=window)
 
         return attention
     if impl == "flash":
@@ -171,7 +178,7 @@ def make_attention(
         def attention(q, k, v, _f=fa.flash_attention):
             if causal:
                 return _f(q, k, v, True, CAUSAL_FLASH_BLOCK,
-                          CAUSAL_FLASH_BLOCK)
+                          CAUSAL_FLASH_BLOCK, False, window)
             return _f(q, k, v)
 
         return attention

@@ -16,7 +16,18 @@ Design — the standard flash decomposition, Pallas-TPU idioms:
   and accumulates ``p @ v`` — the (S, S) matrix never exists anywhere;
 - the last K/V step normalizes and writes the output block;
 - causal + padding masks come from ``broadcasted_iota`` positions, so
-  arbitrary (non-multiple-of-block) S works via zero-padding.
+  arbitrary (non-multiple-of-block) S works via zero-padding;
+- a static ``window`` (causal only: key ``j`` is visible to query ``i``
+  iff ``j <= i`` and ``i - j < window``) narrows the innermost grid axis
+  to the blocks that intersect the band (:class:`_Band`): a query block
+  walks only the key blocks from the one that holds its first row's
+  oldest visible key to the one on the diagonal, a key block only the
+  query blocks from the diagonal to the one that holds its last key's
+  youngest query.  Blocks outside the band are neither fetched nor
+  multiplied; where a run is shorter than the axis (the first query
+  blocks, the last key blocks) the spare steps repeat a neighbouring
+  step's block index and do nothing.  Without a window the grid is the
+  whole square, masked above the diagonal, as before.
 
 The backward pass is a true Pallas FlashAttention-2 backward (new in
 r05; the forward now also emits per-row logsumexp): one kernel
@@ -47,26 +58,88 @@ def _round_up(n: int, m: int) -> int:
     return -(-n // m) * m
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
-                  l_ref, *, scale: float, causal: bool, s_real: int,
-                  block_q: int, block_k: int):
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+class _Band:
+    """Which blocks of the (query block, key block) square a causal
+    ``window`` leaves, in whole numbers a grid's index maps can compute.
 
-    @pl.when(ki == 0)
-    def _():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[:] = jnp.zeros_like(l_ref)
+    Query block ``qi`` (rows ``qi bq .. (qi+1) bq - 1``) sees the key
+    blocks ``first_k(qi) .. last_k(qi)``: from the block of its first
+    row's oldest visible key, ``qi bq - window + 1``, to the block of its
+    last row (the diagonal).  Key block ``ki`` is seen by the query blocks
+    ``first_q(ki) .. last_q(ki)``: from the block of its first key to the
+    block of its last key's youngest query, ``(ki+1) bk + window - 2``.
+    ``nk`` / ``nq`` are the longest such runs: the innermost grid axis."""
 
-    # (BQ, BK) score tile on the MXU; accumulate in f32 regardless of
-    # the input dtype so bf16 inputs keep full-precision statistics
-    scores = jax.lax.dot_general(
-        q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * scale
+    def __init__(self, window: int, sp: int, bq: int, bk: int):
+        self.window, self.bq, self.bk = window, bq, bk
+        self.q_blocks, self.k_blocks = sp // bq, sp // bk
+        self.nk = max(self.last_k(i) - self.first_k(i) + 1
+                      for i in range(self.q_blocks))
+        self.nq = max(self.last_q(i) - self.first_q(i) + 1
+                      for i in range(self.k_blocks))
 
+    # the same expressions serve Python ints (the sizes above) and the
+    # traced scalars of an index map or a kernel body
+    def first_k(self, qi):
+        return _at_least(qi * self.bq - self.window + 1, 0) // self.bk
+
+    def last_k(self, qi):
+        return ((qi + 1) * self.bq - 1) // self.bk
+
+    def first_q(self, ki):
+        return (ki * self.bk) // self.bq
+
+    def last_q(self, ki):
+        last = ((ki + 1) * self.bk + self.window - 2) // self.bq
+        return _at_most(last, self.q_blocks - 1)
+
+    def key_block(self, qi, step):
+        """(key block of a query block's ``step``-th grid step, whether
+        the step is inside its run): the run ends on the diagonal, so the
+        steps before a short run's start are the idle ones; they name the
+        run's first block, which the next step wants anyway."""
+        ki = self.last_k(qi) - (self.nk - 1) + step
+        first = self.first_k(qi)
+        return _at_least(ki, first), ki >= first
+
+    def query_block(self, ki, step):
+        """The same for a key block's run of query blocks, which starts
+        on the diagonal: the idle steps trail and repeat the last block."""
+        qi = self.first_q(ki) + step
+        last = self.last_q(ki)
+        return _at_most(qi, last), qi <= last
+
+
+def _at_least(x, lo):
+    return max(x, lo) if isinstance(x, int) else jnp.maximum(x, lo)
+
+
+def _at_most(x, hi):
+    return min(x, hi) if isinstance(x, int) else jnp.minimum(x, hi)
+
+
+def _key_step(band, qi, step):
+    """(key block, whether the step is live) of a (query block, step) grid
+    point; without a band the step IS the key block and every step live
+    (``None``: no condition to lower)."""
+    return (step, None) if band is None else band.key_block(qi, step)
+
+
+def _query_step(band, ki, step):
+    return (step, None) if band is None else band.query_block(ki, step)
+
+
+def _when(live, tile):
+    """Run a tile's body, under ``pl.when`` where the grid has idle steps."""
+    if live is None:
+        tile()
+    else:
+        pl.when(live)(tile)
+
+
+def _valid(qi, ki, block_q, block_k, s_real, causal, band):
+    """(BQ, BK) mask of the keys a tile's queries may see."""
+    window = band.window if band is not None else None
     q_pos = qi * block_q + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 0)
     k_pos = ki * block_k + jax.lax.broadcasted_iota(
@@ -74,24 +147,53 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
     valid = k_pos < s_real  # zero-padded keys must not attend
     if causal:
         valid = jnp.logical_and(valid, k_pos <= q_pos)
-    scores = jnp.where(valid, scores, -jnp.inf)
+    if window is not None:
+        valid = jnp.logical_and(valid, q_pos - k_pos < window)
+    return valid
 
-    m_prev = m_ref[:]
-    l_prev = l_ref[:]
-    m_blk = jnp.max(scores, axis=1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_blk)
-    # nothing seen yet where m_new is still -inf: keep correction at 0
-    corr = jnp.where(jnp.isneginf(m_new), 0.0, jnp.exp(m_prev - m_new))
-    p = jnp.exp(scores - m_new)
-    p = jnp.where(valid, p, 0.0)
-    l_ref[:] = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
-    acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
-        p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    m_ref[:] = m_new
 
-    @pl.when(ki == nk - 1)
+def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
+                  l_ref, *, scale: float, causal: bool, s_real: int,
+                  block_q: int, block_k: int, band: "_Band | None" = None):
+    qi = pl.program_id(1)
+    step = pl.program_id(2)
+    nk = pl.num_programs(2)
+    ki, live = _key_step(band, qi, step)
+
+    @pl.when(step == 0)
+    def _():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+        m_ref[:] = jnp.full_like(m_ref, -jnp.inf)
+        l_ref[:] = jnp.zeros_like(l_ref)
+
+    def tile():
+        # (BQ, BK) score tile on the MXU; accumulate in f32 regardless of
+        # the input dtype so bf16 inputs keep full-precision statistics
+        scores = jax.lax.dot_general(
+            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale
+        valid = _valid(qi, ki, block_q, block_k, s_real, causal, band)
+        scores = jnp.where(valid, scores, -jnp.inf)
+
+        m_prev = m_ref[:]
+        l_prev = l_ref[:]
+        m_blk = jnp.max(scores, axis=1, keepdims=True)
+        m_new = jnp.maximum(m_prev, m_blk)
+        # nothing seen yet where m_new is still -inf: keep correction at 0
+        corr = jnp.where(jnp.isneginf(m_new), 0.0, jnp.exp(m_prev - m_new))
+        p = jnp.exp(scores - m_new)
+        p = jnp.where(valid, p, 0.0)
+        l_ref[:] = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        m_ref[:] = m_new
+
+    _when(live, tile)
+
+    @pl.when(step == nk - 1)
     def _():
         l = jnp.where(l_ref[:] == 0.0, 1.0, l_ref[:])
         o_ref[0] = (acc_ref[:] / l).astype(o_ref.dtype)
@@ -127,8 +229,18 @@ def _unprep(xp, b, s, h, d, dp, sp):
     return xp.reshape(b, h, sp, dp).transpose(0, 2, 1, 3)[:, :s, :, :d]
 
 
+def _band_of(window, causal, sp, bq, bk) -> "_Band | None":
+    if window is None:
+        return None
+    if not causal or window <= 0:
+        raise ValueError(
+            f"window={window} needs causal attention and a window > 0")
+    return _Band(int(window), sp, bq, bk)
+
+
 def _flash_forward_with_stats(q, k, v, *, causal: bool, block_q: int,
-                              block_k: int, interpret: bool):
+                              block_k: int, interpret: bool,
+                              window: "int | None" = None):
     """Returns (out (B,S,H,D), lse (B*H, Sp, 1) padded-layout logsumexp)."""
     from shifu_tensorflow_tpu.obs import compile as obs_compile
 
@@ -137,7 +249,14 @@ def _flash_forward_with_stats(q, k, v, *, causal: bool, block_q: int,
     qp = _prep(q, b, s, h, d, dp, sp)
     kp = _prep(k, b, s, h, d, dp, sp)
     vp = _prep(v, b, s, h, d, dp, sp)
-    grid = (b * h, sp // bq, sp // bk)
+    band = _band_of(window, causal, sp, bq, bk)
+    if band is None:
+        grid = (b * h, sp // bq, sp // bk)
+        kv_block = lambda bh, qi, ki: (bh, ki, 0)  # noqa: E731
+    else:
+        grid = (b * h, sp // bq, band.nk)
+        kv_block = lambda bh, qi, t: (  # noqa: E731
+            bh, band.key_block(qi, t)[0], 0)
     # compile-attribution region (obs/compile.py): an EAGER call compiles
     # the kernel inside this frame and journals under the pallas name; a
     # call traced into an outer jitted step compiles later, inside that
@@ -145,12 +264,12 @@ def _flash_forward_with_stats(q, k, v, *, causal: bool, block_q: int,
     with obs_compile.attribute("pallas.flash_attention"):
         out, lse = pl.pallas_call(
             partial(_flash_kernel, scale=scale, causal=causal, s_real=s,
-                    block_q=bq, block_k=bk),
+                    block_q=bq, block_k=bk, band=band),
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, bq, dp), lambda bh, qi, ki: (bh, qi, 0)),
-                pl.BlockSpec((1, bk, dp), lambda bh, qi, ki: (bh, ki, 0)),
-                pl.BlockSpec((1, bk, dp), lambda bh, qi, ki: (bh, ki, 0)),
+                pl.BlockSpec((1, bk, dp), kv_block),
+                pl.BlockSpec((1, bk, dp), kv_block),
             ],
             out_specs=[
                 pl.BlockSpec((1, bq, dp), lambda bh, qi, ki: (bh, qi, 0)),
@@ -171,10 +290,10 @@ def _flash_forward_with_stats(q, k, v, *, causal: bool, block_q: int,
 
 
 def _flash_forward(q, k, v, *, causal: bool, block_q: int, block_k: int,
-                   interpret: bool):
+                   interpret: bool, window: "int | None" = None):
     out, _ = _flash_forward_with_stats(
         q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-        interpret=interpret)
+        interpret=interpret, window=window)
     return out
 
 
@@ -182,17 +301,6 @@ def _vmem(shape):
     from jax.experimental.pallas import tpu as pltpu
 
     return pltpu.VMEM(shape, jnp.float32)
-
-
-def _bwd_masks(qi, ki, block_q, block_k, s_real, causal):
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    k_pos = ki * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-    valid = k_pos < s_real
-    if causal:
-        valid = jnp.logical_and(valid, k_pos <= q_pos)
-    return valid
 
 
 def _bwd_p_ds(qf, kf, vf, dof, lse, dvec, valid, scale):
@@ -214,29 +322,34 @@ def _bwd_p_ds(qf, kf, vf, dof, lse, dvec, valid, scale):
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref,
                          dq_ref, acc_ref, *, scale: float, causal: bool,
-                         s_real: int, block_q: int, block_k: int):
-    ki = pl.program_id(2)
+                         s_real: int, block_q: int, block_k: int,
+                         band: "_Band | None" = None):
+    qi = pl.program_id(1)
+    step = pl.program_id(2)
     nk = pl.num_programs(2)
+    ki, live = _key_step(band, qi, step)
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    qf = q_ref[0].astype(jnp.float32)
-    kf = k_ref[0].astype(jnp.float32)
-    vf = v_ref[0].astype(jnp.float32)
-    dof = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0]   # (bq, 1)
-    dvec = d_ref[0]    # (bq, 1)
-    valid = _bwd_masks(pl.program_id(1), ki, block_q, block_k, s_real,
-                       causal)
-    _, ds = _bwd_p_ds(qf, kf, vf, dof, lse, dvec, valid, scale)
-    acc_ref[:] += jax.lax.dot_general(
-        ds, kf, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * scale
+    def tile():
+        qf = q_ref[0].astype(jnp.float32)
+        kf = k_ref[0].astype(jnp.float32)
+        vf = v_ref[0].astype(jnp.float32)
+        dof = do_ref[0].astype(jnp.float32)
+        lse = lse_ref[0]   # (bq, 1)
+        dvec = d_ref[0]    # (bq, 1)
+        valid = _valid(qi, ki, block_q, block_k, s_real, causal, band)
+        _, ds = _bwd_p_ds(qf, kf, vf, dof, lse, dvec, valid, scale)
+        acc_ref[:] += jax.lax.dot_general(
+            ds, kf, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale
 
-    @pl.when(ki == nk - 1)
+    _when(live, tile)
+
+    @pl.when(step == nk - 1)
     def _():
         dq_ref[0] = acc_ref[:].astype(dq_ref.dtype)
 
@@ -244,42 +357,47 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref,
 def _flash_bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, d_ref,
                           dk_ref, dv_ref, dk_acc, dv_acc, *, scale: float,
                           causal: bool, s_real: int, block_q: int,
-                          block_k: int):
-    qi = pl.program_id(2)
+                          block_k: int, band: "_Band | None" = None):
+    ki = pl.program_id(1)
+    step = pl.program_id(2)
     nq = pl.num_programs(2)
+    qi, live = _query_step(band, ki, step)
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    qf = q_ref[0].astype(jnp.float32)
-    kf = k_ref[0].astype(jnp.float32)
-    vf = v_ref[0].astype(jnp.float32)
-    dof = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0]
-    dvec = d_ref[0]
-    valid = _bwd_masks(qi, pl.program_id(1), block_q, block_k, s_real,
-                       causal)
-    p, ds = _bwd_p_ds(qf, kf, vf, dof, lse, dvec, valid, scale)
-    # dV += P^T @ dO ; dK += dS^T @ Q * scale  (both (bk, dp))
-    dv_acc[:] += jax.lax.dot_general(
-        p, dof, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    dk_acc[:] += jax.lax.dot_general(
-        ds, qf, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * scale
+    def tile():
+        qf = q_ref[0].astype(jnp.float32)
+        kf = k_ref[0].astype(jnp.float32)
+        vf = v_ref[0].astype(jnp.float32)
+        dof = do_ref[0].astype(jnp.float32)
+        lse = lse_ref[0]
+        dvec = d_ref[0]
+        valid = _valid(qi, ki, block_q, block_k, s_real, causal, band)
+        p, ds = _bwd_p_ds(qf, kf, vf, dof, lse, dvec, valid, scale)
+        # dV += P^T @ dO ; dK += dS^T @ Q * scale  (both (bk, dp))
+        dv_acc[:] += jax.lax.dot_general(
+            p, dof, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        dk_acc[:] += jax.lax.dot_general(
+            ds, qf, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale
 
-    @pl.when(qi == nq - 1)
+    _when(live, tile)
+
+    @pl.when(step == nq - 1)
     def _():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
 def _flash_backward(q, k, v, out, lse, g, *, causal: bool, block_q: int,
-                    block_k: int, interpret: bool):
+                    block_k: int, interpret: bool,
+                    window: "int | None" = None):
     """True Pallas flash backward: P is reconstructed per tile from the
     forward's logsumexp (no S×S matrix anywhere), dQ accumulates over key
     blocks, dK/dV over query blocks — the FlashAttention-2 decomposition.
@@ -294,15 +412,26 @@ def _flash_backward(q, k, v, out, lse, g, *, causal: bool, block_q: int,
     # D_i = sum_d dO_i * O_i — cheap elementwise+reduce, XLA does it well
     dvec = jnp.sum(dop.astype(jnp.float32) * outp.astype(jnp.float32),
                    axis=-1, keepdims=True)  # (BH, Sp, 1)
+    band = _band_of(window, causal, sp, bq, bk)
+    if band is None:
+        nk, nq = sp // bk, sp // bq
+        kv_block = lambda bh, qi, ki: (bh, ki, 0)  # noqa: E731
+        q_block = lambda bh, ki, qi: (bh, qi, 0)  # noqa: E731
+    else:
+        nk, nq = band.nk, band.nq
+        kv_block = lambda bh, qi, t: (  # noqa: E731
+            bh, band.key_block(qi, t)[0], 0)
+        q_block = lambda bh, ki, t: (  # noqa: E731
+            bh, band.query_block(ki, t)[0], 0)
 
     dq = pl.pallas_call(
         partial(_flash_bwd_dq_kernel, scale=scale, causal=causal,
-                s_real=s, block_q=bq, block_k=bk),
-        grid=(b * h, sp // bq, sp // bk),
+                s_real=s, block_q=bq, block_k=bk, band=band),
+        grid=(b * h, sp // bq, nk),
         in_specs=[
             pl.BlockSpec((1, bq, dp), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, bk, dp), lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, bk, dp), lambda bh, qi, ki: (bh, ki, 0)),
+            pl.BlockSpec((1, bk, dp), kv_block),
+            pl.BlockSpec((1, bk, dp), kv_block),
             pl.BlockSpec((1, bq, dp), lambda bh, qi, ki: (bh, qi, 0)),
             pl.BlockSpec((1, bq, 1), lambda bh, qi, ki: (bh, qi, 0)),
             pl.BlockSpec((1, bq, 1), lambda bh, qi, ki: (bh, qi, 0)),
@@ -315,15 +444,15 @@ def _flash_backward(q, k, v, out, lse, g, *, causal: bool, block_q: int,
 
     dk, dv = pl.pallas_call(
         partial(_flash_bwd_dkv_kernel, scale=scale, causal=causal,
-                s_real=s, block_q=bq, block_k=bk),
-        grid=(b * h, sp // bk, sp // bq),
+                s_real=s, block_q=bq, block_k=bk, band=band),
+        grid=(b * h, sp // bk, nq),
         in_specs=[
             pl.BlockSpec((1, bk, dp), lambda bh, ki, qi: (bh, ki, 0)),
             pl.BlockSpec((1, bk, dp), lambda bh, ki, qi: (bh, ki, 0)),
-            pl.BlockSpec((1, bq, dp), lambda bh, ki, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, bq, dp), lambda bh, ki, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, bq, 1), lambda bh, ki, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, bq, 1), lambda bh, ki, qi: (bh, qi, 0)),
+            pl.BlockSpec((1, bq, dp), q_block),
+            pl.BlockSpec((1, bq, dp), q_block),
+            pl.BlockSpec((1, bq, 1), q_block),
+            pl.BlockSpec((1, bq, 1), q_block),
         ],
         out_specs=[
             pl.BlockSpec((1, bk, dp), lambda bh, ki, qi: (bh, ki, 0)),
@@ -341,10 +470,13 @@ def _flash_backward(q, k, v, out, lse, g, *, causal: bool, block_q: int,
     return un(dq), un(dk), un(dv)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q, k, v, causal: bool = False, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = False):
-    """Fused flash attention, shapes (B, S, H, D).
+                    block_k: int = 128, interpret: bool = False,
+                    window: "int | None" = None):
+    """Fused flash attention, shapes (B, S, H, D).  ``window`` (causal
+    only) keeps the keys ``j`` with ``i - j < window`` of a query ``i``;
+    all three kernels then walk the band's blocks only.
 
     Forward: the Pallas kernel above.
     Backward: the Pallas FlashAttention-2 backward (_flash_backward) —
@@ -362,17 +494,18 @@ def flash_attention(q, k, v, causal: bool = False, block_q: int = 128,
     the jit caches (``jax.clear_caches()``) before the next call.
     """
     return _flash_forward(q, k, v, causal=causal, block_q=block_q,
-                          block_k=block_k, interpret=interpret)
+                          block_k=block_k, interpret=interpret,
+                          window=window)
 
 
-def _flash_fwd(q, k, v, causal, block_q, block_k, interpret):
+def _flash_fwd(q, k, v, causal, block_q, block_k, interpret, window):
     out, lse = _flash_forward_with_stats(
         q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-        interpret=interpret)
+        interpret=interpret, window=window)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, block_q, block_k, interpret, res, g):
+def _flash_bwd(causal, block_q, block_k, interpret, window, res, g):
     import os
 
     q, k, v, out, lse = res
@@ -387,13 +520,14 @@ def _flash_bwd(causal, block_q, block_k, interpret, res, g):
         block = max(512, block_q, block_k)
         _, vjp = jax.vjp(
             lambda q_, k_, v_: chunked_attention(
-                q_, k_, v_, causal=causal, block_size=block),
+                q_, k_, v_, causal=causal, block_size=block,
+                window=window),
             q, k, v,
         )
         return vjp(g)
     return _flash_backward(q, k, v, out, lse, g, causal=causal,
                            block_q=block_q, block_k=block_k,
-                           interpret=interpret)
+                           interpret=interpret, window=window)
 
 
 flash_attention.defvjp(_flash_fwd, _flash_bwd)

@@ -32,15 +32,29 @@ from jax.sharding import PartitionSpec as P
 SEQ_AXIS = "seq"
 
 
+def _check_window(causal: bool, window: "int | None") -> None:
+    if window is not None and (not causal or window <= 0):
+        raise ValueError(
+            f"window={window} needs causal attention and a window > 0")
+
+
 def full_attention(
-    q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool = False
+    q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool = False,
+    window: "int | None" = None,
 ) -> jax.Array:
-    """Reference single-device attention.  Shapes (B, S, H, D)."""
+    """Reference single-device attention.  Shapes (B, S, H, D).
+    ``window`` (causal only): key ``j`` is visible to query ``i`` iff
+    ``j <= i`` and ``i - j < window``."""
+    _check_window(causal, window)
     scale = q.shape[-1] ** -0.5
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
     if causal:
         sq, sk = scores.shape[-2], scores.shape[-1]
         mask = jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq)
+        if window is not None:
+            mask = jnp.logical_and(
+                mask, jnp.triu(jnp.ones((sq, sk), bool),
+                               k=sk - sq - window + 1))
         scores = jnp.where(mask, scores, -jnp.inf)
     weights = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", weights, v)
@@ -76,6 +90,7 @@ def chunked_attention(
     *,
     causal: bool = False,
     block_size: int = 512,
+    window: "int | None" = None,
 ) -> jax.Array:
     """Single-device flash-style attention: O(S·block) working memory,
     no S×S materialization, in EITHER direction.
@@ -91,15 +106,18 @@ def chunked_attention(
     through this path raises; use ``full_attention`` for that.)
     Shapes (B, S, H, D); K/V are zero-padded up to a block multiple
     with the padded keys masked out, so any sequence length works.
+    ``window`` (causal only) is :func:`full_attention`'s, as a mask: every
+    K/V block is still visited (the Pallas flash kernel skips them).
     """
+    _check_window(causal, window)
     s = k.shape[1]
     if s <= block_size:  # a single block IS full attention
-        return full_attention(q, k, v, causal=causal)
-    return _chunked(q, k, v, causal, min(block_size, s))
+        return full_attention(q, k, v, causal=causal, window=window)
+    return _chunked(q, k, v, causal, min(block_size, s), window)
 
 
 def _block_mask(blk_idx, sq: int, blk: int, s_real: int,
-                causal: bool, padded: bool):
+                causal: bool, padded: bool, window: "int | None" = None):
     """(1, 1, sq, blk) validity mask for one K/V block, or None."""
     if not (causal or padded):
         return None
@@ -111,6 +129,8 @@ def _block_mask(blk_idx, sq: int, blk: int, s_real: int,
         mask = jnp.logical_and(mask, k_pos < s_real)
     if causal:
         mask = jnp.logical_and(mask, k_pos <= q_pos)
+    if window is not None:
+        mask = jnp.logical_and(mask, q_pos - k_pos < window)
     return mask[None, None]
 
 
@@ -139,7 +159,7 @@ def _prep_blocks(q, k, v, blk: int):
             nblk, padded, sp, s)
 
 
-def _chunked_fwd_impl(q, k, v, causal: bool, blk: int):
+def _chunked_fwd_impl(q, k, v, causal: bool, blk: int, window=None):
     b, _, h, d = k.shape
     qf, ks, vs, scale, nblk, padded, sp, s = _prep_blocks(q, k, v, blk)
     sq = q.shape[1]
@@ -151,7 +171,7 @@ def _chunked_fwd_impl(q, k, v, causal: bool, blk: int):
     def step(carry, xs):
         acc, m, l = carry
         blk_idx, kb, vb = xs
-        mask = _block_mask(blk_idx, sq, blk, s, causal, padded)
+        mask = _block_mask(blk_idx, sq, blk, s, causal, padded, window)
         acc, m, l = _block_update(qf, kb, vb, acc, m, l,
                                   scale=scale, mask=mask)
         return (acc, m, l), None
@@ -168,18 +188,18 @@ def _chunked_fwd_impl(q, k, v, causal: bool, blk: int):
     return out.astype(q.dtype), lse
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _chunked(q, k, v, causal: bool, blk: int):
-    out, _ = _chunked_fwd_impl(q, k, v, causal, blk)
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _chunked(q, k, v, causal: bool, blk: int, window=None):
+    out, _ = _chunked_fwd_impl(q, k, v, causal, blk, window)
     return out
 
 
-def _chunked_fwd(q, k, v, causal: bool, blk: int):
-    out, lse = _chunked_fwd_impl(q, k, v, causal, blk)
+def _chunked_fwd(q, k, v, causal: bool, blk: int, window):
+    out, lse = _chunked_fwd_impl(q, k, v, causal, blk, window)
     return out, (q, k, v, out, lse)
 
 
-def _chunked_bwd(causal: bool, blk: int, res, g):
+def _chunked_bwd(causal: bool, blk: int, window, res, g):
     """Flash backward: recompute each block's weights from (q, k, lse).
 
     dS = p ∘ (g·vᵀ − D) with D = rowsum(g ∘ out); dq accumulates as the
@@ -198,7 +218,7 @@ def _chunked_bwd(causal: bool, blk: int, res, g):
         blk_idx, kb, vb = xs
         scores = jnp.einsum("bqhd,bkhd->bhqk", qf, kb) * scale
         p = jnp.exp(scores - lse[..., None])
-        mask = _block_mask(blk_idx, sq, blk, s, causal, padded)
+        mask = _block_mask(blk_idx, sq, blk, s, causal, padded, window)
         if mask is not None:
             p = jnp.where(mask, p, 0.0)
         dv_b = jnp.einsum("bhqk,bqhd->bkhd", p, gf)

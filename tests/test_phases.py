@@ -111,7 +111,8 @@ def test_compiled_step_carries_every_scope(path, scope):
 
 def _lm_step_text() -> str:
     """The compiled per-step program of a tiny ``hybrid_lm`` trainer (the
-    health guard's step, as the CLI builds it)."""
+    health guard's step, as the CLI builds it): one layer of every kind,
+    the window and the full attention each with its rotary."""
     if "lm" not in _STEP_TEXTS:
         from shifu_tensorflow_tpu.config.model_config import ModelConfig
         from shifu_tensorflow_tpu.train.trainer import HealthConfig, Trainer
@@ -119,7 +120,13 @@ def _lm_step_text() -> str:
         mc = ModelConfig.from_json({"train": {"params": {
             "ModelType": "hybrid_lm", "Optimizer": "adam",
             "LearningRate": 1e-3, "hidden_size": 32,
-            "hybrid_override_pattern": "ME*", "vocab_size": 64,
+            "hybrid_override_pattern": "MEW*", "vocab_size": 64,
+            "sliding_window": 4, "rope_parameters": {
+                "sliding_attention": {"rope_type": "default",
+                                      "rope_theta": 100.0},
+                "full_attention": {
+                    "rope_type": "yarn", "rope_theta": 100.0, "factor": 4,
+                    "original_max_position_embeddings": 8}},
             "mamba_num_heads": 2, "mamba_head_dim": 8, "n_groups": 1,
             "ssm_state_size": 8, "chunk_size": 8, "n_routed_experts": 4,
             "experts_held": [0, 2], "num_experts_per_tok": 2,

@@ -162,6 +162,61 @@ def test_grouped_experts_lower_at_the_lm_cells_shape(topo):
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
+# the sliding-window + gated-expert cell's kernels at its published widths
+# (benchmark/configs/mellum2_ep4.json): 2 rows x 8,192 tokens
+
+
+@pytest.mark.parametrize("window", [1024, None], ids=["window", "full"])
+def test_banded_flash_lowers_at_the_swa_cells_shape(topo, window):
+    """32 query heads over 4 KV heads of 128 at S 8,192 with 512-row
+    tiles: forward, dQ and dK/dV kernels, inside a 1,024 window (3 of 16
+    blocks a grid row) and over the whole square."""
+    from shifu_tensorflow_tpu.models.sequence import make_attention
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    attention = make_attention("flash", None, causal=True, window=window)
+    q = jax.ShapeDtypeStruct((2, 8192, 32, 128), jnp.float32,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((2, 8192, 4, 128), jnp.float32,
+                              sharding=one_chip)
+
+    def loss(q, k, v):
+        k, v = (jnp.repeat(x, 8, axis=2) for x in (k, v))
+        return jnp.sum(attention(q, k, v) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, kv, kv).compile()
+    assert _kernels(compiled) == 3
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 << 30
+
+
+def test_gated_experts_lower_at_the_swa_cells_shape(topo):
+    """16 held gated experts of 2304 x 896 over the 131,072 (token,
+    choice) pairs of a step, the cell's 1,280-row tiles: a ``while`` in both
+    directions, three weight gradients."""
+    from shifu_tensorflow_tpu.ops import grouped
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def on(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(h, w_gate, up, down, weights, ids):
+        pair, tile_expert, n_tiles, _ = grouped.plan_tiles(ids, 0, 16, 1280)
+        token = jnp.where(pair < ids.size, pair // 8, h.shape[0])
+        gate = jnp.where(pair < ids.size,
+                         jnp.take(weights.reshape(-1), pair, mode="clip"), 0.)
+        return jnp.sum(grouped.gated_expert_mlp(
+            h, w_gate, up, down, token, gate, tile_expert, n_tiles,
+            1280) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2, 3, 4))).lower(
+        on((16384, 2304)), on((16, 2304, 896)), on((16, 2304, 896)),
+        on((16, 896, 2304)), on((16384, 8)),
+        on((16384, 8), jnp.int32)).compile()
+    assert compiled.as_text().count(" while(") >= 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+
+
 # ----------------------------------------------------- the flagship step
 
 
