@@ -144,8 +144,10 @@ def make_attention(
     not an opaque shard_map/all_to_all trace failure.  ``causal`` masks
     keys after the query (the single-device implementations; the decoder
     family, models/hybrid_lm.py, passes it); ``window`` (causal only)
-    also those ``window`` or more positions before it: a mask in ``full``
-    and ``chunked``, skipped blocks in ``flash``."""
+    also those ``window`` or more positions before it.  Both are a mask
+    in ``full`` and ``chunked``; ``flash`` leaves the blocks that hold no
+    visible key out of its grid (above the diagonal, or outside the
+    window's band), and only where it is not causal visits them all."""
     from shifu_tensorflow_tpu.parallel import ring
 
     if window is not None and not causal:
@@ -240,8 +242,9 @@ def _chunked_min_seq() -> int:
 
 #: query and key rows of a flash-kernel tile on the causal path.  The
 #: kernel's own default, 128, makes a grid step per 128 x 128 scores: at
-#: S 4,096 that is 1,024 steps a head and pass, and the steps' overhead
-#: outweighs their products; 512 keeps a tile's scores at 1 MB of VMEM.
+#: S 4,096 that is 528 steps a head and pass over the causal triangle
+#: (1,024 over the square), and the steps' overhead outweighs their
+#: products; at 512 it is 36, and a tile's scores are 1 MB of VMEM.
 CAUSAL_FLASH_BLOCK = 512
 
 

@@ -14,7 +14,8 @@ Design — the standard flash decomposition, Pallas-TPU idioms:
 - each step computes a (BQ, BK) score tile on the MXU
   (``preferred_element_type=f32``), applies the online-softmax update,
   and accumulates ``p @ v`` — the (S, S) matrix never exists anywhere;
-- the last K/V step normalizes and writes the output block;
+- the last K/V step of a query block's run normalizes and writes the
+  output block;
 - causal + padding masks come from ``broadcasted_iota`` positions, so
   arbitrary (non-multiple-of-block) S works via zero-padding;
 - a static ``window`` (causal only: key ``j`` is visible to query ``i``
@@ -26,8 +27,20 @@ Design — the standard flash decomposition, Pallas-TPU idioms:
   youngest query.  Blocks outside the band are neither fetched nor
   multiplied; where a run is shorter than the axis (the first query
   blocks, the last key blocks) the spare steps repeat a neighbouring
-  step's block index and do nothing.  Without a window the grid is the
-  whole square, masked above the diagonal, as before.
+  step's block index and do nothing;
+- causal without a window, all three kernels walk the triangle and not
+  the square (:class:`_Fold`): a tile wholly above the diagonal, whose
+  every score would be masked, is neither fetched nor multiplied; the
+  tiles on the diagonal keep their mask.  So that no grid step is idle,
+  a grid row is TWO query blocks' runs, a short one and the long one
+  that complements it (key blocks likewise in the dK/dV kernel), the
+  statistics reset and the output block written where the run changes:
+  at S 8,192 and 512-row tiles 8 rows of 17 live steps a head, 136 of
+  the square's 256 (:func:`grid_steps` counts them).  An idle step is
+  not free (0.18 us: 5.5 ms a train step at that shape, measured), which
+  is why the triangle is folded and not walked as a band whose window is
+  the sequence;
+- not causal, the grid is the whole square and every tile is visited.
 
 The backward pass is a true Pallas FlashAttention-2 backward (new in
 r05; the forward now also emits per-row logsumexp): one kernel
@@ -109,6 +122,107 @@ class _Band:
         last = self.last_q(ki)
         return _at_most(qi, last), qi <= last
 
+    @property
+    def key_grid(self):
+        return self.q_blocks, self.nk
+
+    @property
+    def query_grid(self):
+        return self.k_blocks, self.nq
+
+    def key_tile(self, row, step):
+        """(query block, key block, live, first, last) of a grid point of
+        the forward and dQ kernels, where a grid row is one query block's
+        run: ``first`` and ``last`` say where a run starts (reset the
+        accumulators) and ends (write the output block)."""
+        ki, live = self.key_block(row, step)
+        return row, ki, live, step == 0, step == self.nk - 1
+
+    def query_tile(self, row, step):
+        """(key block, query block, live, first, last) in the dK/dV
+        kernel, where a grid row is one key block's run."""
+        qi, live = self.query_block(row, step)
+        return row, qi, live, step == 0, step == self.nq - 1
+
+
+class _Square:
+    """Every tile of the square (not causal): a grid row IS a block, a
+    step the block it walks, and every step live (``None``: no condition
+    to lower)."""
+
+    window = None
+
+    def __init__(self, sp: int, bq: int, bk: int):
+        self.key_grid = sp // bq, sp // bk
+        self.query_grid = sp // bk, sp // bq
+
+    def key_tile(self, row, step):
+        return row, step, None, step == 0, step == self.key_grid[1] - 1
+
+    def query_tile(self, row, step):
+        return row, step, None, step == 0, step == self.query_grid[1] - 1
+
+
+class _Fold:
+    """The causal triangle without a window, folded so that (nearly) no
+    grid step is idle.  Query block ``j`` walks ``last_k(j) + 1`` key
+    blocks and query block ``q_blocks - 1 - j`` the more the fewer ``j``
+    does, so the two share one grid row: ``j``'s run, then its partner's,
+    the accumulators reset and the output block written where the run
+    changes.  At equal blocks every row is ``q_blocks + 1`` live steps;
+    where the blocks differ, or the middle block of an odd count stands
+    alone, the spare steps trail, repeat the last block index and do
+    nothing.  The dK/dV kernel folds the key blocks the same way (key
+    block ``j`` is walked by the query blocks from ``first_q(j)`` on)."""
+
+    window = None
+
+    def __init__(self, sp: int, bq: int, bk: int):
+        runs = _Band(sp, sp, bq, bk)  # no window: it is the sequence
+        keys = _FoldedRuns(
+            runs.q_blocks, lambda qi: (runs.first_k(qi), runs.last_k(qi)))
+        queries = _FoldedRuns(
+            runs.k_blocks, lambda ki: (runs.first_q(ki), runs.last_q(ki)))
+        self.key_grid, self.key_tile = keys.grid, keys.tile
+        self.query_grid, self.query_tile = queries.grid, queries.tile
+
+
+class _FoldedRuns:
+    """``blocks`` runs, ``run_of(i)`` the (first, last) block that block
+    ``i`` walks, two to a grid row: ``i``'s, then ``blocks - 1 - i``'s."""
+
+    def __init__(self, blocks: int, run_of):
+        self.blocks, self.run_of = blocks, run_of
+        rows = -(-blocks // 2)
+        self.grid = rows, max(
+            sum(self._steps(i) for i in {row, blocks - 1 - row})
+            for row in range(rows))
+
+    def _steps(self, i):
+        start, end = self.run_of(i)
+        return end - start + 1
+
+    def tile(self, row, step):
+        """(block whose run it is, block walked, live, first, last) of a
+        grid point, in Python ints or in an index map's traced scalars."""
+        partner = self.blocks - 1 - row
+        n = self._steps(row)
+        second = (step >= n) & (partner != row)
+        block = _select(second, partner, row)
+        since = step - _select(second, n, 0)
+        start, end = self.run_of(block)
+        first = (step == 0) | (second & (since == 0))
+        last = (step == n - 1) | (
+            (step == self.grid[1] - 1) & (partner != row))
+        walked = start + since
+        return block, _at_most(walked, end), walked <= end, first, last
+
+
+def _select(cond, a, b):
+    if isinstance(cond, bool):
+        return a if cond else b
+    return jnp.where(cond, a, b)
+
 
 def _at_least(x, lo):
     return max(x, lo) if isinstance(x, int) else jnp.maximum(x, lo)
@@ -116,17 +230,6 @@ def _at_least(x, lo):
 
 def _at_most(x, hi):
     return min(x, hi) if isinstance(x, int) else jnp.minimum(x, hi)
-
-
-def _key_step(band, qi, step):
-    """(key block, whether the step is live) of a (query block, step) grid
-    point; without a band the step IS the key block and every step live
-    (``None``: no condition to lower)."""
-    return (step, None) if band is None else band.key_block(qi, step)
-
-
-def _query_step(band, ki, step):
-    return (step, None) if band is None else band.query_block(ki, step)
 
 
 def _when(live, tile):
@@ -137,9 +240,9 @@ def _when(live, tile):
         pl.when(live)(tile)
 
 
-def _valid(qi, ki, block_q, block_k, s_real, causal, band):
+def _valid(qi, ki, block_q, block_k, s_real, causal, walk):
     """(BQ, BK) mask of the keys a tile's queries may see."""
-    window = band.window if band is not None else None
+    window = walk.window
     q_pos = qi * block_q + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 0)
     k_pos = ki * block_k + jax.lax.broadcasted_iota(
@@ -154,13 +257,11 @@ def _valid(qi, ki, block_q, block_k, s_real, causal, band):
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
                   l_ref, *, scale: float, causal: bool, s_real: int,
-                  block_q: int, block_k: int, band: "_Band | None" = None):
-    qi = pl.program_id(1)
-    step = pl.program_id(2)
-    nk = pl.num_programs(2)
-    ki, live = _key_step(band, qi, step)
+                  block_q: int, block_k: int, walk):
+    qi, ki, live, first, last = walk.key_tile(
+        pl.program_id(1), pl.program_id(2))
 
-    @pl.when(step == 0)
+    @pl.when(first)
     def _():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, -jnp.inf)
@@ -173,7 +274,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
             q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale
-        valid = _valid(qi, ki, block_q, block_k, s_real, causal, band)
+        valid = _valid(qi, ki, block_q, block_k, s_real, causal, walk)
         scores = jnp.where(valid, scores, -jnp.inf)
 
         m_prev = m_ref[:]
@@ -193,7 +294,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
 
     _when(live, tile)
 
-    @pl.when(step == nk - 1)
+    @pl.when(last)
     def _():
         l = jnp.where(l_ref[:] == 0.0, 1.0, l_ref[:])
         o_ref[0] = (acc_ref[:] / l).astype(o_ref.dtype)
@@ -205,18 +306,20 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
         lse_ref[0] = lse
 
 
-def _pad_geom(q, block_q: int, block_k: int):
+def _padded(s: int, block_q: int, block_k: int):
+    """(padded S, query block, key block).  S pads to a common multiple of
+    BOTH blocks: rounding to only the larger one truncates the grid for
+    the smaller (sp // block floors), silently dropping trailing query
+    rows or key blocks."""
     import math
 
-    b, s, h, d = q.shape
-    dp = _round_up(d, 128)
-    # pad S to a common multiple of BOTH blocks: rounding to only the
-    # larger one truncates the grid for the smaller (sp // block floors),
-    # silently dropping trailing query rows or key blocks
     sp = _round_up(s, math.lcm(block_q, block_k))
-    bq = min(block_q, sp)
-    bk = min(block_k, sp)
-    return b, s, h, d, dp, sp, bq, bk
+    return sp, min(block_q, sp), min(block_k, sp)
+
+
+def _pad_geom(q, block_q: int, block_k: int):
+    b, s, h, d = q.shape
+    return b, s, h, d, _round_up(d, 128), *_padded(s, block_q, block_k)
 
 
 def _prep(x, b, s, h, d, dp, sp):
@@ -229,13 +332,41 @@ def _unprep(xp, b, s, h, d, dp, sp):
     return xp.reshape(b, h, sp, dp).transpose(0, 2, 1, 3)[:, :s, :, :d]
 
 
-def _band_of(window, causal, sp, bq, bk) -> "_Band | None":
+def _walk_of(window, causal, sp, bq, bk) -> "_Band | _Fold | _Square":
+    """Which tiles of the square the kernels visit: a window's band, the
+    causal triangle, or all of them."""
     if window is None:
-        return None
+        return (_Fold if causal else _Square)(sp, bq, bk)
     if not causal or window <= 0:
         raise ValueError(
             f"window={window} needs causal attention and a window > 0")
     return _Band(int(window), sp, bq, bk)
+
+
+def grid_steps(seq_len: int, block_q: int, block_k: int, *, causal: bool,
+               window: "int | None" = None) -> dict:
+    """``(live, idle)`` grid steps a batch·head of each of the three
+    kernels: a live step fetches and multiplies one tile, an idle one
+    repeats its neighbour's block indices and does nothing.  A function of
+    the static shapes alone, as the walk is."""
+    walk = _walk_of(window, causal, *_padded(seq_len, block_q, block_k))
+
+    def count(tile, grid):
+        rows, steps = grid
+        live = sum(tile(row, t)[2] is not False
+                   for row in range(rows) for t in range(steps))
+        return live, rows * steps - live
+
+    keys = count(walk.key_tile, walk.key_grid)
+    return {"forward": keys, "dq": keys,
+            "dkv": count(walk.query_tile, walk.query_grid)}
+
+
+def _index_maps(tile):
+    """Block index maps of a walk's grid: (of the block whose run a grid
+    row is, of the block a step of it walks)."""
+    return (lambda bh, row, t: (bh, tile(row, t)[0], 0),
+            lambda bh, row, t: (bh, tile(row, t)[1], 0))
 
 
 def _flash_forward_with_stats(q, k, v, *, causal: bool, block_q: int,
@@ -249,14 +380,8 @@ def _flash_forward_with_stats(q, k, v, *, causal: bool, block_q: int,
     qp = _prep(q, b, s, h, d, dp, sp)
     kp = _prep(k, b, s, h, d, dp, sp)
     vp = _prep(v, b, s, h, d, dp, sp)
-    band = _band_of(window, causal, sp, bq, bk)
-    if band is None:
-        grid = (b * h, sp // bq, sp // bk)
-        kv_block = lambda bh, qi, ki: (bh, ki, 0)  # noqa: E731
-    else:
-        grid = (b * h, sp // bq, band.nk)
-        kv_block = lambda bh, qi, t: (  # noqa: E731
-            bh, band.key_block(qi, t)[0], 0)
+    walk = _walk_of(window, causal, sp, bq, bk)
+    q_block, kv_block = _index_maps(walk.key_tile)
     # compile-attribution region (obs/compile.py): an EAGER call compiles
     # the kernel inside this frame and journals under the pallas name; a
     # call traced into an outer jitted step compiles later, inside that
@@ -264,16 +389,16 @@ def _flash_forward_with_stats(q, k, v, *, causal: bool, block_q: int,
     with obs_compile.attribute("pallas.flash_attention"):
         out, lse = pl.pallas_call(
             partial(_flash_kernel, scale=scale, causal=causal, s_real=s,
-                    block_q=bq, block_k=bk, band=band),
-            grid=grid,
+                    block_q=bq, block_k=bk, walk=walk),
+            grid=(b * h, *walk.key_grid),
             in_specs=[
-                pl.BlockSpec((1, bq, dp), lambda bh, qi, ki: (bh, qi, 0)),
+                pl.BlockSpec((1, bq, dp), q_block),
                 pl.BlockSpec((1, bk, dp), kv_block),
                 pl.BlockSpec((1, bk, dp), kv_block),
             ],
             out_specs=[
-                pl.BlockSpec((1, bq, dp), lambda bh, qi, ki: (bh, qi, 0)),
-                pl.BlockSpec((1, bq, 1), lambda bh, qi, ki: (bh, qi, 0)),
+                pl.BlockSpec((1, bq, dp), q_block),
+                pl.BlockSpec((1, bq, 1), q_block),
             ],
             out_shape=[
                 jax.ShapeDtypeStruct((b * h, sp, dp), q.dtype),
@@ -323,13 +448,11 @@ def _bwd_p_ds(qf, kf, vf, dof, lse, dvec, valid, scale):
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref,
                          dq_ref, acc_ref, *, scale: float, causal: bool,
                          s_real: int, block_q: int, block_k: int,
-                         band: "_Band | None" = None):
-    qi = pl.program_id(1)
-    step = pl.program_id(2)
-    nk = pl.num_programs(2)
-    ki, live = _key_step(band, qi, step)
+                         walk):
+    qi, ki, live, first, last = walk.key_tile(
+        pl.program_id(1), pl.program_id(2))
 
-    @pl.when(step == 0)
+    @pl.when(first)
     def _():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
@@ -340,7 +463,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref,
         dof = do_ref[0].astype(jnp.float32)
         lse = lse_ref[0]   # (bq, 1)
         dvec = d_ref[0]    # (bq, 1)
-        valid = _valid(qi, ki, block_q, block_k, s_real, causal, band)
+        valid = _valid(qi, ki, block_q, block_k, s_real, causal, walk)
         _, ds = _bwd_p_ds(qf, kf, vf, dof, lse, dvec, valid, scale)
         acc_ref[:] += jax.lax.dot_general(
             ds, kf, (((1,), (0,)), ((), ())),
@@ -349,7 +472,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref,
 
     _when(live, tile)
 
-    @pl.when(step == nk - 1)
+    @pl.when(last)
     def _():
         dq_ref[0] = acc_ref[:].astype(dq_ref.dtype)
 
@@ -357,13 +480,11 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref,
 def _flash_bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, d_ref,
                           dk_ref, dv_ref, dk_acc, dv_acc, *, scale: float,
                           causal: bool, s_real: int, block_q: int,
-                          block_k: int, band: "_Band | None" = None):
-    ki = pl.program_id(1)
-    step = pl.program_id(2)
-    nq = pl.num_programs(2)
-    qi, live = _query_step(band, ki, step)
+                          block_k: int, walk):
+    ki, qi, live, first, last = walk.query_tile(
+        pl.program_id(1), pl.program_id(2))
 
-    @pl.when(step == 0)
+    @pl.when(first)
     def _():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
@@ -375,7 +496,7 @@ def _flash_bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, d_ref,
         dof = do_ref[0].astype(jnp.float32)
         lse = lse_ref[0]
         dvec = d_ref[0]
-        valid = _valid(qi, ki, block_q, block_k, s_real, causal, band)
+        valid = _valid(qi, ki, block_q, block_k, s_real, causal, walk)
         p, ds = _bwd_p_ds(qf, kf, vf, dof, lse, dvec, valid, scale)
         # dV += P^T @ dO ; dK += dS^T @ Q * scale  (both (bk, dp))
         dv_acc[:] += jax.lax.dot_general(
@@ -389,7 +510,7 @@ def _flash_bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, d_ref,
 
     _when(live, tile)
 
-    @pl.when(step == nq - 1)
+    @pl.when(last)
     def _():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
@@ -412,31 +533,23 @@ def _flash_backward(q, k, v, out, lse, g, *, causal: bool, block_q: int,
     # D_i = sum_d dO_i * O_i — cheap elementwise+reduce, XLA does it well
     dvec = jnp.sum(dop.astype(jnp.float32) * outp.astype(jnp.float32),
                    axis=-1, keepdims=True)  # (BH, Sp, 1)
-    band = _band_of(window, causal, sp, bq, bk)
-    if band is None:
-        nk, nq = sp // bk, sp // bq
-        kv_block = lambda bh, qi, ki: (bh, ki, 0)  # noqa: E731
-        q_block = lambda bh, ki, qi: (bh, qi, 0)  # noqa: E731
-    else:
-        nk, nq = band.nk, band.nq
-        kv_block = lambda bh, qi, t: (  # noqa: E731
-            bh, band.key_block(qi, t)[0], 0)
-        q_block = lambda bh, ki, t: (  # noqa: E731
-            bh, band.query_block(ki, t)[0], 0)
+    walk = _walk_of(window, causal, sp, bq, bk)
+    q_row, kv_step = _index_maps(walk.key_tile)
+    kv_row, q_step = _index_maps(walk.query_tile)
 
     dq = pl.pallas_call(
         partial(_flash_bwd_dq_kernel, scale=scale, causal=causal,
-                s_real=s, block_q=bq, block_k=bk, band=band),
-        grid=(b * h, sp // bq, nk),
+                s_real=s, block_q=bq, block_k=bk, walk=walk),
+        grid=(b * h, *walk.key_grid),
         in_specs=[
-            pl.BlockSpec((1, bq, dp), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, bk, dp), kv_block),
-            pl.BlockSpec((1, bk, dp), kv_block),
-            pl.BlockSpec((1, bq, dp), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, bq, 1), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, bq, 1), lambda bh, qi, ki: (bh, qi, 0)),
+            pl.BlockSpec((1, bq, dp), q_row),
+            pl.BlockSpec((1, bk, dp), kv_step),
+            pl.BlockSpec((1, bk, dp), kv_step),
+            pl.BlockSpec((1, bq, dp), q_row),
+            pl.BlockSpec((1, bq, 1), q_row),
+            pl.BlockSpec((1, bq, 1), q_row),
         ],
-        out_specs=pl.BlockSpec((1, bq, dp), lambda bh, qi, ki: (bh, qi, 0)),
+        out_specs=pl.BlockSpec((1, bq, dp), q_row),
         out_shape=jax.ShapeDtypeStruct((b * h, sp, dp), q.dtype),
         scratch_shapes=[_vmem((bq, dp))],
         interpret=interpret,
@@ -444,19 +557,19 @@ def _flash_backward(q, k, v, out, lse, g, *, causal: bool, block_q: int,
 
     dk, dv = pl.pallas_call(
         partial(_flash_bwd_dkv_kernel, scale=scale, causal=causal,
-                s_real=s, block_q=bq, block_k=bk, band=band),
-        grid=(b * h, sp // bk, nq),
+                s_real=s, block_q=bq, block_k=bk, walk=walk),
+        grid=(b * h, *walk.query_grid),
         in_specs=[
-            pl.BlockSpec((1, bk, dp), lambda bh, ki, qi: (bh, ki, 0)),
-            pl.BlockSpec((1, bk, dp), lambda bh, ki, qi: (bh, ki, 0)),
-            pl.BlockSpec((1, bq, dp), q_block),
-            pl.BlockSpec((1, bq, dp), q_block),
-            pl.BlockSpec((1, bq, 1), q_block),
-            pl.BlockSpec((1, bq, 1), q_block),
+            pl.BlockSpec((1, bk, dp), kv_row),
+            pl.BlockSpec((1, bk, dp), kv_row),
+            pl.BlockSpec((1, bq, dp), q_step),
+            pl.BlockSpec((1, bq, dp), q_step),
+            pl.BlockSpec((1, bq, 1), q_step),
+            pl.BlockSpec((1, bq, 1), q_step),
         ],
         out_specs=[
-            pl.BlockSpec((1, bk, dp), lambda bh, ki, qi: (bh, ki, 0)),
-            pl.BlockSpec((1, bk, dp), lambda bh, ki, qi: (bh, ki, 0)),
+            pl.BlockSpec((1, bk, dp), kv_row),
+            pl.BlockSpec((1, bk, dp), kv_row),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b * h, sp, dp), k.dtype),
@@ -474,9 +587,10 @@ def _flash_backward(q, k, v, out, lse, g, *, causal: bool, block_q: int,
 def flash_attention(q, k, v, causal: bool = False, block_q: int = 128,
                     block_k: int = 128, interpret: bool = False,
                     window: "int | None" = None):
-    """Fused flash attention, shapes (B, S, H, D).  ``window`` (causal
-    only) keeps the keys ``j`` with ``i - j < window`` of a query ``i``;
-    all three kernels then walk the band's blocks only.
+    """Fused flash attention, shapes (B, S, H, D).  ``causal`` keeps the
+    keys ``j <= i`` of a query ``i``, ``window`` (causal only) of those
+    the ones with ``i - j < window``; all three kernels walk only the
+    blocks that hold a visible key: the triangle, or the window's band.
 
     Forward: the Pallas kernel above.
     Backward: the Pallas FlashAttention-2 backward (_flash_backward) —

@@ -74,12 +74,22 @@ def test_chunked_grads_match_full(causal, s):
                                    rtol=2e-4, atol=2e-4)
 
 
+# tiles smaller than S, so that the causal walk meets tiles wholly above
+# the diagonal (not visited), on it and wholly below it; unequal
+# blocks both ways; 200 and 320 are S that no block here divides (padded
+# keys on the last diagonal tile), and 320 / 128 and 200 / 40 are odd
+# counts of blocks (the middle block of the fold stands alone)
+BLOCKS = [(128, 128), (32, 32), (32, 64), (64, 32), (40, 40), (48, 16)]
+_block_ids = [f"{bq}x{bk}" for bq, bk in BLOCKS]
+
+
 @pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("blocks", BLOCKS, ids=_block_ids)
 @pytest.mark.parametrize("s", [64, 96, 320])  # 96/320: pad the blocks
-def test_flash_matches_full(causal, s):
+def test_flash_matches_full(causal, s, blocks):
     q, k, v = _qkv(s=s)
     want = full_attention(q, k, v, causal=causal)
-    got = flash_attention(q, k, v, causal)
+    got = flash_attention(q, k, v, causal, *blocks)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
 
@@ -186,11 +196,13 @@ def test_sequence_model_trains_with_chunked_attention():
 
 
 @pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("blocks", BLOCKS, ids=_block_ids)
 @pytest.mark.parametrize("s", [128, 96, 200])  # 96/200: padded S
-def test_pallas_flash_backward_matches_full(causal, s):
+def test_pallas_flash_backward_matches_full(causal, s, blocks):
     """The r05 Pallas FlashAttention-2 backward (dQ over key blocks,
     dK/dV over query blocks, P from saved logsumexp): gradients must
-    match full attention including zero-padded tails."""
+    match full attention including zero-padded tails, whichever tiles the
+    causal walk leaves out."""
     q, k, v = _qkv(s=s, seed=3)
     want = jax.grad(
         lambda q, k, v: jnp.sum(
@@ -198,12 +210,32 @@ def test_pallas_flash_backward_matches_full(causal, s):
     )(q, k, v)
     got = jax.grad(
         lambda q, k, v: jnp.sum(
-            flash_attention(q, k, v, causal) ** 2), (0, 1, 2)
+            flash_attention(q, k, v, causal, *blocks) ** 2), (0, 1, 2)
     )(q, k, v)
     for w, g in zip(want, got):
         assert not np.isnan(np.asarray(g)).any()
         np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                    rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("blocks", [(32, 32), (16, 64), (64, 16)],
+                         ids=["32x32", "16x64", "64x16"])
+def test_causal_first_row_sees_only_itself_and_stays_finite(blocks):
+    """Row 0's one visible key is its own: its output is ``v[0]`` whatever
+    the scores, and no gradient picks up a NaN from the tiles left out or
+    from a block's rows that have seen nothing yet."""
+    q, k, v = _qkv(s=100, seed=11)
+    q = q * 30.0  # scores far apart: a stray -inf - -inf would show
+    out = flash_attention(q, k, v, True, *blocks)
+    np.testing.assert_allclose(np.asarray(out[:, 0]), np.asarray(v[:, 0]),
+                               rtol=1e-6, atol=1e-6)
+    grads = jax.grad(
+        lambda q, k, v: jnp.sum(flash_attention(q, k, v, True, *blocks)),
+        (0, 1, 2))(q, k, v)
+    for g in grads:
+        assert np.isfinite(np.asarray(g)).all()
+    # softmax over one key is constant in q and k: no gradient reaches them
+    np.testing.assert_allclose(np.asarray(grads[0][:, 0]), 0.0, atol=1e-6)
 
 
 def test_pallas_flash_backward_ab_matches_chunked_fallback(monkeypatch):

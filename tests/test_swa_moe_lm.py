@@ -312,6 +312,62 @@ def test_the_band_leaves_the_blocks_outside_it_out_of_the_grid():
     assert _Band(8192, 8192, 512, 512).nk == 16
 
 
+@pytest.mark.parametrize("seq_len,window,live,idle", [
+    (8192, None, 136, 0),   # the Mellum cell's full layer: 256 in the square
+    (4096, None, 36, 0),    # the Nemotron cell's `*` layer: 64 in the square
+    (8192, 1024, 45, 3),    # the Mellum cell's window layers, as before
+], ids=["mellum-full", "nemotron-full", "mellum-window"])
+def test_the_causal_kernels_walk_the_triangle_at_the_cells_shapes(
+        seq_len, window, live, idle):
+    """Grid steps a head of each of the three kernels at the cells' 512-row
+    tiles: the tiles that hold a visible key and no others, folded so that
+    the full layers' grids have no idle step."""
+    from shifu_tensorflow_tpu.models.sequence import CAUSAL_FLASH_BLOCK
+    from shifu_tensorflow_tpu.ops.pallas.flash_attention import grid_steps
+
+    tile = CAUSAL_FLASH_BLOCK
+    steps = grid_steps(seq_len, tile, tile, causal=True, window=window)
+    assert steps == {k: (live, idle) for k in ("forward", "dq", "dkv")}
+    blocks = seq_len // tile
+    assert grid_steps(seq_len, tile, tile, causal=False) == {
+        k: (blocks * blocks, 0) for k in ("forward", "dq", "dkv")}
+
+
+@pytest.mark.parametrize("sp,bq,bk", [
+    (8192, 512, 512), (256, 32, 64), (256, 64, 32), (192, 64, 96),
+    (192, 96, 64), (384, 128, 128), (200, 40, 40), (240, 48, 16)])
+def test_the_fold_visits_each_tile_of_the_triangle_once(sp, bq, bk):
+    """Every (query block, key block) tile with a visible key is a live
+    step of exactly one grid point, between the step that resets its
+    block's statistics and the step that writes its block; idle steps
+    repeat the block indices of the step before them (no new fetch)."""
+    from shifu_tensorflow_tpu.ops.pallas.flash_attention import _Fold
+
+    fold = _Fold(sp, bq, bk)
+    want = {(qi, ki) for qi in range(sp // bq) for ki in range(sp // bk)
+            if ki * bk <= (qi + 1) * bq - 1}
+    for grid, tile, swap in ((fold.key_grid, fold.key_tile, False),
+                             (fold.query_grid, fold.query_tile, True)):
+        seen, rows, steps = [], *grid
+        for row in range(rows):
+            open_run, prev = None, None
+            for t in range(steps):
+                block, walked, live, first, last = tile(row, t)
+                if first:
+                    assert open_run is None
+                    open_run = block
+                if live:  # never a tile outside its block's open run
+                    assert block == open_run
+                    seen.append((walked, block) if swap else (block, walked))
+                else:
+                    assert (block, walked) == prev
+                if last:
+                    open_run = None
+                prev = (block, walked)
+            assert open_run is None
+        assert sorted(seen) == sorted(want)  # each once, none missing
+
+
 @pytest.mark.parametrize("window", [16, 20, 1, 100])
 @pytest.mark.parametrize("block", [16, 512], ids=["scan", "one-block"])
 def test_chunked_and_full_attention_take_the_window_as_a_mask(window, block):

@@ -95,21 +95,26 @@ def test_flash_attention_lowers_for_v5e(topo, grad, seq_len, head_dim,
 # (benchmark/configs/nemotron3_nano_ep16.json): 2 rows x 4,096 tokens
 
 
-def test_causal_grouped_query_flash_lowers_at_the_lm_cells_shape(topo):
-    """32 query heads over 2 KV heads of 128 at S 4,096, through
+@pytest.mark.parametrize("seq_len,kv_heads", [(4096, 2), (8192, 4)],
+                         ids=["nemotron-s4k", "mellum-s8k"])
+def test_causal_grouped_query_flash_lowers_at_the_lm_cells_shape(
+        topo, seq_len, kv_heads):
+    """32 query heads of 128 over 2 KV heads at S 4,096 and over 4 at
+    S 8,192 (the Mellum cell's full layer), through
     ``make_attention("flash", causal=True)`` with its 512-row tiles:
-    forward, dQ and dK/dV kernels, the KV heads repeated outside."""
+    forward, dQ and dK/dV kernels over the folded triangle, the KV heads
+    repeated outside."""
     from shifu_tensorflow_tpu.models.sequence import make_attention
 
     one_chip = SingleDeviceSharding(topo.devices[0])
     attention = make_attention("flash", None, causal=True)
-    q = jax.ShapeDtypeStruct((2, 4096, 32, 128), jnp.float32,
+    q = jax.ShapeDtypeStruct((2, seq_len, 32, 128), jnp.float32,
                              sharding=one_chip)
-    kv = jax.ShapeDtypeStruct((2, 4096, 2, 128), jnp.float32,
+    kv = jax.ShapeDtypeStruct((2, seq_len, kv_heads, 128), jnp.float32,
                               sharding=one_chip)
 
     def loss(q, k, v):
-        k, v = (jnp.repeat(x, 16, axis=2) for x in (k, v))
+        k, v = (jnp.repeat(x, 32 // kv_heads, axis=2) for x in (k, v))
         return jnp.sum(attention(q, k, v) ** 2)
 
     compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, kv, kv).compile()
