@@ -1,0 +1,22 @@
+"""``partial_rope_ms`` — layer: models models/ ops/.  Unit ``ms``, source
+``device_trace``; should move ``train_rows_per_s``.
+
+Device ms a step inside ``attn.rope``, forward + backward summed (the
+backward's recomputed forward included): the rotation of q and k on every
+attention layer, the whole head on the sliding layers and the first half
+of it (YaRN) on the full ones, the other half copied through.  From
+``obs.profile.phases`` on the run's own capture, handed on by the plane;
+``None`` on a reading without the phase or of another configuration's
+kind.
+"""
+
+LAYER = "models models/ ops/"
+UNIT = "ms"
+SOURCE = "device_trace"
+MOVES = "train_rows_per_s"
+
+from benchmark.mixed_lm_readings import mixed_phase_ms
+
+
+def read(r):
+    return mixed_phase_ms(r, "attn.rope")

@@ -1,0 +1,32 @@
+"""``small_experts_roofline`` — layer: kernels ops/ssm_scan.py ops/grouped.py Pallas flash attention.  Unit ``%``, source
+``device_trace``; should move ``train_rows_per_s``.
+
+The least time the chip could take for the held small gated experts'
+products of a step — max(FLOPs / peak, bytes / peak) of
+``benchmark/shapes_mixed_lm.py`` ``experts_flops`` / ``experts_bytes`` a
+layer (three matrices, for the pairs the step's ``moe_held_pairs`` counter
+says landed on the held experts, not the uniform share; the held weights
+read twice and their gradient written once), times the sparse blocks —
+over ``small_experts_ms``.  At a few hundred pairs an expert the weights'
+bytes bind, not the products.
+"""
+
+LAYER = "kernels ops/ssm_scan.py ops/grouped.py Pallas flash attention"
+UNIT = "%"
+SOURCE = "device_trace"
+MOVES = "train_rows_per_s"
+
+from benchmark import shapes_mixed_lm
+from benchmark.mixed_lm_readings import held_pairs_a_layer, mixed_shapes
+from benchmark.swa_lm_readings import roofline_pct
+
+
+def read(r):
+    pairs, shapes = held_pairs_a_layer(r), mixed_shapes(r)
+    if pairs is None or shapes is None:
+        return None
+    cfg = shapes[0]
+    return roofline_pct(
+        r, "moe.experts", shapes_mixed_lm.blocks_of(cfg, "sparse"),
+        shapes_mixed_lm.experts_flops(cfg, pairs),
+        shapes_mixed_lm.experts_bytes(cfg, pairs))

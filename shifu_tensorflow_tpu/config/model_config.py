@@ -56,6 +56,7 @@ def require_servable(model_type: str, plane: str) -> None:
 
 #: ``layer_types`` / ``mlp_layer_types`` entries -> pattern characters
 _ATTENTION_KINDS = {"full_attention": "*", "sliding_attention": "W"}
+_MLP_KINDS = {"sparse": "E", "dense": "D"}
 _PATTERN_LAYER_TYPES = {v: k for k, v in _ATTENTION_KINDS.items()}
 
 
@@ -65,7 +66,10 @@ class RopeParameters:
     (``theta^(-m / (head_dim / 2))``) or ``yarn`` (those frequencies
     blended with their ``1 / factor`` interpolation between the
     ``beta_fast`` and ``beta_slow`` correction dimensions, cos and sin
-    scaled by ``attention_factor``; 0 = ``0.1 ln(factor) + 1``)."""
+    scaled by ``attention_factor``; 0 = ``0.1 ln(factor) + 1``).
+    ``partial_rotary_factor``: the share of a head's dimensions, from the
+    first, that turn (the frequencies are then those of a head of that
+    many dimensions); the rest pass through unchanged."""
 
     rope_type: str = "default"
     rope_theta: float = 10000.0
@@ -74,6 +78,10 @@ class RopeParameters:
     beta_fast: float = 32.0
     beta_slow: float = 1.0
     attention_factor: float = 0.0
+    partial_rotary_factor: float = 1.0
+
+    def rotary_dim(self, head_dim: int) -> int:
+        return int(head_dim * self.partial_rotary_factor)
 
     @classmethod
     def from_json(cls, obj: Mapping[str, Any]) -> "RopeParameters":
@@ -97,6 +105,10 @@ class RopeParameters:
             raise ValueError(
                 "rope_type=yarn needs factor > 1 and "
                 "original_max_position_embeddings")
+        if not 0.0 < rope.partial_rotary_factor <= 1.0:
+            raise ValueError(
+                f"partial_rotary_factor={rope.partial_rotary_factor} is "
+                "not a share of the head in (0, 1]")
         return rope
 
 
@@ -104,8 +116,8 @@ class RopeParameters:
 class HybridLMConfig:
     """``ModelType: hybrid_lm`` — the keys of a public ``config.json``
     under their own names (``train.params`` carries them beside
-    ``ModelType``), plus the share this chip holds.  Two public shapes are
-    read:
+    ``ModelType``), plus the share this chip holds.  Three public shapes
+    are read:
 
     - ``nemotron_h``: ``hybrid_override_pattern``, one mixer a layer (``M``
       Mamba-2, ``E`` experts, ``*`` causal attention, ``W`` causal
@@ -118,7 +130,19 @@ class HybridLMConfig:
       (``default`` | ``yarn``), ``hidden_act: silu`` (the gated expert
       ``W_down(silu(W_gate h) * W_up h)``), ``scoring_func: softmax``,
       ``n_shared_experts: 0``, ``num_experts`` for the router's width and
-      ``rms_norm_eps`` for the norms'.
+      ``rms_norm_eps`` for the norms';
+    - ``laguna``: the ``mellum`` keys, and ``mlp_layer_types`` with
+      ``dense`` (``D``: the gated feed-forward ``W_down(silu(W_gate h) *
+      W_up h)`` of width ``intermediate_size``, no router),
+      ``num_attention_heads_per_layer`` (one number a layer type: the
+      query heads of a ``*`` and of a ``W`` layer differ, over the same
+      ``num_key_value_heads``), ``partial_rotary_factor`` inside a
+      ``rope_parameters`` entry, sigmoid scores scaled by
+      ``moe_routed_scaling_factor`` over gated experts beside a gated
+      shared expert of ``shared_expert_intermediate_size`` (with
+      ``hidden_act: silu`` the shared expert takes the experts' form).
+      ``gating: true`` is read as that gated feed-forward and adds
+      nothing further (a gate on attention's output is not implemented).
 
     ``n_routed_experts`` is the router's width (all the experts there
     are); ``experts_held`` = (first id, count) the experts whose weights
@@ -153,6 +177,7 @@ class HybridLMConfig:
     time_step_min: float = 0.001
     time_step_max: float = 0.1
     time_step_floor: float = 1e-4
+    intermediate_size: int = 0  # a ``D`` layer's width
     # experts
     n_routed_experts: int = 8
     num_experts_per_tok: int = 2
@@ -167,6 +192,9 @@ class HybridLMConfig:
     scoring_func: str = "sigmoid"  # sigmoid (+ correction bias) | softmax
     # attention
     num_attention_heads: int = 4
+    #: ((layer type, query heads), ...) from ``num_attention_heads_per_layer``;
+    #: a type without one has ``num_attention_heads``
+    attention_heads_by_type: tuple = ()
     num_key_value_heads: int = 1
     head_dim: int = 16
     sliding_window: int = 0  # keys a ``W`` layer's query sees, itself one
@@ -175,7 +203,10 @@ class HybridLMConfig:
 
     #: public spellings of the same number
     ALIASES = (("num_experts", "n_routed_experts"),
-               ("rms_norm_eps", "layer_norm_epsilon"))
+               ("rms_norm_eps", "layer_norm_epsilon"),
+               ("shared_expert_intermediate_size",
+                "moe_shared_expert_intermediate_size"),
+               ("moe_routed_scaling_factor", "routed_scaling_factor"))
 
     @property
     def embedding_std(self) -> float:
@@ -188,6 +219,50 @@ class HybridLMConfig:
     def rope_for(self, kind: str) -> "RopeParameters | None":
         """The rotary parametrisation of a ``*`` or ``W`` layer."""
         return dict(self.rope_parameters).get(_PATTERN_LAYER_TYPES[kind])
+
+    def heads_for(self, kind: str) -> int:
+        """The query heads of a ``*`` or ``W`` layer."""
+        return dict(self.attention_heads_by_type).get(
+            _PATTERN_LAYER_TYPES[kind], self.num_attention_heads)
+
+    @staticmethod
+    def heads_by_type(params: Mapping[str, Any]) -> tuple:
+        """``num_attention_heads_per_layer`` beside ``layer_types`` as one
+        number a layer type."""
+        heads = [int(n) for n in params["num_attention_heads_per_layer"]]
+        kinds = [str(k) for k in params.get("layer_types", ())]
+        if len(heads) != len(kinds):
+            raise ValueError(
+                f"num_attention_heads_per_layer has {len(heads)} entries, "
+                f"layer_types {len(kinds)}")
+        by_type: dict[str, int] = {}
+        for kind, n in zip(kinds, heads):
+            if by_type.setdefault(kind, n) != n:
+                raise ValueError(
+                    f"num_attention_heads_per_layer gives {kind} layers "
+                    f"{by_type[kind]} and {n} heads: one number a layer "
+                    "type is implemented")
+        return tuple(sorted(by_type.items()))
+
+    @staticmethod
+    def rope_by_type(params: Mapping[str, Any]) -> tuple:
+        """``rope_parameters`` as ((layer type, RopeParameters), ...).  A
+        number among its entries (``original_max_position_embeddings``)
+        and the config's own ``partial_rotary_factor`` stand for the
+        entries that state none."""
+        entries = dict(params["rope_parameters"])
+        shared = {k: entries.pop(k) for k in list(entries)
+                  if not isinstance(entries[k], Mapping)}
+        unknown = sorted(set(shared) - {"original_max_position_embeddings"})
+        if unknown:
+            raise ValueError(
+                f"rope_parameters {unknown} are neither a layer type's "
+                "entry nor original_max_position_embeddings")
+        if "partial_rotary_factor" in params:
+            shared["partial_rotary_factor"] = params["partial_rotary_factor"]
+        return tuple(sorted(
+            (str(kind), RopeParameters.from_json({**shared, **r}))
+            for kind, r in entries.items()))
 
     @staticmethod
     def pattern_of(params: Mapping[str, Any]) -> str:
@@ -204,12 +279,13 @@ class HybridLMConfig:
             raise ValueError(
                 f"layer_types {bad} are not implemented "
                 f"({' | '.join(_ATTENTION_KINDS)})")
-        if set(mlps) - {"sparse"}:
+        bad = sorted(set(mlps) - set(_MLP_KINDS))
+        if bad:
             raise ValueError(
-                f"mlp_layer_types {sorted(set(mlps) - {'sparse'})} are not "
-                "implemented (sparse: the family has no dense gated "
-                "feed-forward)")
-        return "".join(_ATTENTION_KINDS[k] + "E" for k in kinds)
+                f"mlp_layer_types {bad} are not implemented "
+                f"({' | '.join(_MLP_KINDS)})")
+        return "".join(_ATTENTION_KINDS[k] + _MLP_KINDS[m]
+                       for k, m in zip(kinds, mlps))
 
     @classmethod
     def from_json(cls, params: Mapping[str, Any]) -> "HybridLMConfig":
@@ -233,6 +309,10 @@ class HybridLMConfig:
                     f"+ mlp_layer_types give {pattern!r}")
             params["hybrid_override_pattern"] = pattern
             blocks = len(params["layer_types"])
+        if "num_attention_heads_per_layer" in params:
+            params["attention_heads_by_type"] = cls.heads_by_type(params)
+        if "rope_parameters" in params:
+            params["rope_parameters"] = cls.rope_by_type(params)
         known = {f.name: f for f in dataclasses.fields(cls)}
         missing = [k for k in ("hidden_size", "hybrid_override_pattern",
                                "vocab_size") if k not in params]
@@ -246,10 +326,8 @@ class HybridLMConfig:
             v = params[name]
             if name == "experts_held":
                 kw[name] = (int(v[0]), int(v[1]))
-            elif name == "rope_parameters":
-                kw[name] = tuple(sorted(
-                    (str(kind), RopeParameters.from_json(r))
-                    for kind, r in v.items()))
+            elif f.type == "tuple":
+                kw[name] = tuple(v)
             elif f.type in ("int",):
                 kw[name] = int(v or 0)
             elif f.type in ("float",):
@@ -268,12 +346,12 @@ class HybridLMConfig:
     def validate(self, params: Mapping[str, Any] = (),
                  blocks: "int | None" = None) -> None:
         pattern = self.hybrid_override_pattern
-        bad = set(pattern) - set("MEW*")
+        bad = set(pattern) - set("MEDW*")
         if bad or not pattern:
             raise ValueError(
                 "hybrid_override_pattern is a string of M (Mamba-2), E "
-                "(experts), * (attention) and W (attention inside "
-                f"sliding_window); got {sorted(bad)}")
+                "(experts), D (dense gated feed-forward), * (attention) "
+                f"and W (attention inside sliding_window); got {sorted(bad)}")
         layers = params.get("num_hidden_layers") if params else None
         expect = len(pattern) if blocks is None else blocks
         if layers is not None and int(layers) != expect:
@@ -286,7 +364,8 @@ class HybridLMConfig:
                 raise ValueError(
                     f"{key}={params[key]}: group-limited routing is not "
                     "implemented (the family routes over all experts)")
-        for key in ("attention_bias", "mlp_bias"):
+        for key in ("attention_bias", "mlp_bias",
+                    "moe_apply_router_weight_on_input"):
             if params and _parse_bool(params.get(key, False)):
                 raise ValueError(f"{key}=true is not implemented")
         first, count = self.experts_held
@@ -312,12 +391,21 @@ class HybridLMConfig:
             raise ValueError(
                 f"scoring_func={self.scoring_func!r} is not implemented "
                 "(sigmoid | softmax)")
-        if self.n_shared_experts < 0 or (
-                self.n_shared_experts and self.hidden_act != "relu2"):
+        if self.n_shared_experts < 0:
             raise ValueError(
-                f"n_shared_experts={self.n_shared_experts} with hidden_act="
-                f"{self.hidden_act!r} is not implemented (the shared "
-                "expert is relu2; a gated model states n_shared_experts 0)")
+                f"n_shared_experts={self.n_shared_experts} is negative")
+        if "D" in pattern and (self.intermediate_size <= 0
+                               or self.hidden_act != "silu"):
+            raise ValueError(
+                f"a D (dense) layer needs intermediate_size > 0 and "
+                f"hidden_act silu, the gated feed-forward; got "
+                f"intermediate_size={self.intermediate_size}, hidden_act="
+                f"{self.hidden_act!r}")
+        if (params and _parse_bool(params.get("gating", False))
+                and self.hidden_act != "silu"):
+            raise ValueError(
+                f"gating=true with hidden_act={self.hidden_act!r}: gating "
+                "is read as the gated (silu) feed-forward and nothing else")
         if "W" in pattern and self.sliding_window <= 0:
             raise ValueError(
                 "a W (sliding_attention) layer needs sliding_window > 0")
@@ -327,13 +415,20 @@ class HybridLMConfig:
             raise ValueError(
                 f"rope_parameters for {unknown}: the layer types are "
                 f"{' | '.join(_ATTENTION_KINDS)}")
-        if self.rope_parameters and self.head_dim % 2:
-            raise ValueError("rotary positions need an even head_dim")
+        for kind, rope in self.rope_parameters:
+            turning = rope.rotary_dim(self.head_dim)
+            if turning % 2 or not turning:
+                raise ValueError(
+                    f"rotary positions need an even number of dimensions: "
+                    f"{kind} turns {turning} of head_dim={self.head_dim}")
         if self.mamba_num_heads % self.n_groups:
             raise ValueError("n_groups must divide mamba_num_heads")
-        if self.num_attention_heads % self.num_key_value_heads:
-            raise ValueError(
-                "num_key_value_heads must divide num_attention_heads")
+        for heads in (self.num_attention_heads,
+                      *dict(self.attention_heads_by_type).values()):
+            if heads <= 0 or heads % self.num_key_value_heads:
+                raise ValueError(
+                    f"num_key_value_heads={self.num_key_value_heads} must "
+                    f"divide a layer's query heads ({heads})")
 
 
 @dataclass(frozen=True)

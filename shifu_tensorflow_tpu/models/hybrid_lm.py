@@ -2,8 +2,8 @@
 model whose layers are each ONE mixer in a pre-norm residual,
 ``x <- x + mixer(RMSNorm(x))``, the mixer chosen per layer by a pattern
 string as the public ``nemotron_h`` configuration writes it (a ``mellum``
-configuration's block, attention then experts, is two such layers:
-config/model_config.py ``HybridLMConfig``):
+or ``laguna`` configuration's block, attention then feed-forward, is two
+such layers: config/model_config.py ``HybridLMConfig``):
 
 - ``M``  Mamba-2 (``ssm.conv`` + ``ssm.scan``: ops/ssm_scan.py's chunked
   scan), gate before the grouped RMSNorm;
@@ -12,16 +12,22 @@ config/model_config.py ``HybridLMConfig``):
   score), weights normalised over the k chosen and scaled; the expert
   ``W_down relu(W_up h)^2`` (``hidden_act: relu2``, beside one shared
   expert of the same form) or gated, ``W_down(silu(W_gate h) * W_up h)``
-  (``silu``, no shared expert).  The layer is told which experts it holds
-  (``experts_held`` = first id, count): it routes over all of them,
-  computes the held experts' part for the tokens that chose them
+  (``silu``; a shared expert, where ``n_shared_experts`` asks for one, is
+  gated too, unscaled, for every token).  The layer is told which experts
+  it holds (``experts_held`` = first id, count): it routes over all of
+  them, computes the held experts' part for the tokens that chose them
   (ops/grouped.py: sort, grouped products, unsort) and leaves out what
   the absent experts would add.  No token is dropped;
+- ``D``  the dense gated feed-forward ``W_down(silu(W_gate h) * W_up h)``
+  of width ``intermediate_size``: every token, no router;
 - ``*``  causal grouped-query attention; ``W`` the same inside
   ``sliding_window`` (key ``j`` visible to query ``i`` iff ``j <= i`` and
-  ``i - j < sliding_window``).  Rotary positions where the layer's type
-  has ``rope_parameters`` (``default`` or ``yarn``: :func:`rope_tables`);
-  none otherwise (the Mamba layers carry position).
+  ``i - j < sliding_window``).  The query heads are the layer type's
+  (``HybridLMConfig.heads_for``) over one ``num_key_value_heads``.  Rotary
+  positions where the layer's type has ``rope_parameters`` (``default``
+  or ``yarn``: :func:`rope_tables`) over the first
+  ``partial_rotary_factor`` of a head's dimensions; none otherwise (the
+  Mamba layers carry position).
 
 Ingest compatibility (as models/sequence.py): a PSV row carries its
 ``S`` token ids in the float32 feature block; the model casts them on
@@ -41,10 +47,10 @@ and a row's tokens then choose the same experts: PERF.md section 6).
 The phase names (``jax.named_scope``; obs/profile.py ``PHASE_SCOPES``):
 ``embed.gather``, ``ssm.proj`` (in/out projections, gate and grouped
 norm), ``ssm.conv``, ``ssm.scan``, ``moe.route``, ``moe.experts``,
-``moe.shared``, ``attn.proj`` (q, k, v, o), ``attn.rope`` (the rotation of
-q and k), ``attn.core`` (a ``*`` layer's core), ``attn.window`` (a ``W``
-layer's), ``lm.head``.  The residual stream's own norms and adds carry no
-scope.
+``moe.shared``, ``mlp.dense`` (a ``D`` layer), ``attn.proj`` (q, k, v, o),
+``attn.rope`` (the rotation of q and k), ``attn.core`` (a ``*`` layer's
+core), ``attn.window`` (a ``W`` layer's), ``lm.head``.  The residual
+stream's own norms and adds carry no scope.
 """
 
 from __future__ import annotations
@@ -244,16 +250,25 @@ class ExpertWeights(nn.Module):
         return gate + (up, down)
 
 
-class SharedExpert(nn.Module):
+class FeedForward(nn.Module):
+    """What every token goes through: ``W_down relu(W_up h)^2``, or gated,
+    ``W_down(silu(W_gate h) * W_up h)``.  A shared expert beside the
+    routed ones, and the ``D`` layer's mixer."""
+
     width: int
     std: float
     down_std: float
     dtype: Any = jnp.float32
+    gated: bool = False
 
     @nn.compact
     def __call__(self, x):
         h = Kernel(self.width, self.std, self.dtype, name="up")(x)
-        h = jnp.square(nn.relu(h))
+        if self.gated:
+            h = nn.silu(Kernel(self.width, self.std, self.dtype,
+                               name="gate")(x)) * h
+        else:
+            h = jnp.square(nn.relu(h))
         return Kernel(x.shape[-1], self.down_std, self.dtype,
                       name="down")(h)
 
@@ -311,17 +326,19 @@ class MoEMixer(nn.Module):
                        tile).astype(dt_)
         if c.n_shared_experts:
             with jax.named_scope("moe.shared"):
-                out = out + SharedExpert(
+                out = out + FeedForward(
                     c.moe_shared_expert_intermediate_size
                     * c.n_shared_experts,
-                    c.initializer_range, c.output_std, dt_,
+                    c.initializer_range, c.output_std, dt_, gated,
                     name="shared")(flat)
         return (out.reshape(bsz, s, d),
                 jnp.stack([jnp.sum(counts), jnp.max(counts)]))
 
 
 def rope_frequencies(rope: RopeParameters, head_dim: int):
-    """``(frequencies (head_dim / 2,) float64, scale of cos and sin)``.
+    """``(frequencies (head_dim / 2,) float64, scale of cos and sin)`` of a
+    head whose ``head_dim`` dimensions all turn (under a
+    ``partial_rotary_factor`` the caller passes the dimensions that do).
     ``default``: ``theta^(-m / (head_dim / 2))``, scale 1.  ``yarn``:
     ``(1 - g_m) b_m / factor + g_m b_m`` with ``g_m = 1 - clip((m - low) /
     (high - low), 0, 1)``, ``low`` / ``high`` the floor / ceiling of the
@@ -346,25 +363,29 @@ def rope_frequencies(rope: RopeParameters, head_dim: int):
 
 
 def rope_tables(rope: RopeParameters, seq: int, head_dim: int):
-    """``(cos, sin)`` (S, head_dim / 2) float32, the scale folded in: the
-    angle is the float32 product of the position and the float32
+    """``(cos, sin)`` (S, R / 2) float32 for the ``R =
+    rope.rotary_dim(head_dim)`` dimensions that turn, the scale folded in:
+    the angle is the float32 product of the position and the float32
     frequency, as the public implementations compute it."""
-    freq, scale = rope_frequencies(rope, head_dim)
+    freq, scale = rope_frequencies(rope, rope.rotary_dim(head_dim))
     angle = (jnp.arange(seq, dtype=jnp.float32)[:, None]
              * jnp.asarray(freq, jnp.float32)[None, :])
     return jnp.cos(angle) * scale, jnp.sin(angle) * scale
 
 
 def apply_rope(u, cos, sin):
-    """``u cos + rotate_half(u) sin`` over the last axis of (B, S, H, D),
-    ``rotate_half(u) = (-u[D/2:], u[:D/2])``: dimension ``m`` pairs with
-    ``m + D/2``."""
-    half = u.shape[-1] // 2
-    u1, u2 = u[..., :half], u[..., half:]
+    """``u cos + rotate_half(u) sin`` over the first ``R = 2 x`` (the
+    tables' width) of the last axis of (B, S, H, D), ``rotate_half(u) =
+    (-u[R/2:R], u[:R/2])``: dimension ``m`` pairs with ``m + R/2``.  The
+    dimensions from ``R`` on pass through, unchanged and unscaled."""
+    half = cos.shape[-1]
+    u1, u2 = u[..., :half], u[..., half:2 * half]
     cos = cos[None, :, None, :].astype(u.dtype)
     sin = sin[None, :, None, :].astype(u.dtype)
-    return jnp.concatenate([u1 * cos - u2 * sin, u2 * cos + u1 * sin],
-                           axis=-1)
+    parts = [u1 * cos - u2 * sin, u2 * cos + u1 * sin]
+    if 2 * half < u.shape[-1]:
+        parts.append(u[..., 2 * half:])
+    return jnp.concatenate(parts, axis=-1)
 
 
 class AttentionMixer(nn.Module):
@@ -380,7 +401,8 @@ class AttentionMixer(nn.Module):
     @nn.compact
     def __call__(self, x):
         c, dt_ = self.cfg, self.dtype
-        nq, nkv, hd = c.num_attention_heads, c.num_key_value_heads, c.head_dim
+        nq, nkv, hd = (c.heads_for(self.kind), c.num_key_value_heads,
+                       c.head_dim)
         bsz, s, _ = x.shape
         std = c.initializer_range
         with jax.named_scope("attn.proj"):
@@ -422,6 +444,12 @@ class Layer(nn.Module):
             y = MambaMixer(self.cfg, self.dtype, name="mixer")(h)
         elif self.kind == "E":
             y, stats = MoEMixer(self.cfg, self.dtype, name="mixer")(h)
+        elif self.kind == "D":
+            c = self.cfg
+            with jax.named_scope("mlp.dense"):
+                y = FeedForward(c.intermediate_size, c.initializer_range,
+                                c.output_std, self.dtype, True,
+                                name="mixer")(h)
         else:
             attention = (self.window_attention if self.kind == "W"
                          else self.attention)

@@ -188,9 +188,9 @@ def _capture(out_dir: str, seconds: float) -> None:
 TABULAR_SCOPES = ("embed.hash", "embed.gather", "wide.cross", "deep.mlp",
                   "loss", "optimizer.update")
 HYBRID_LM_SCOPES = ("embed.gather", "ssm.proj", "ssm.conv", "ssm.scan",
-                    "moe.route", "moe.experts", "moe.shared", "attn.proj",
-                    "attn.rope", "attn.core", "attn.window", "lm.head",
-                    "optimizer.update")
+                    "moe.route", "moe.experts", "moe.shared", "mlp.dense",
+                    "attn.proj", "attn.rope", "attn.core", "attn.window",
+                    "lm.head", "optimizer.update")
 PHASE_SCOPES = TABULAR_SCOPES + tuple(
     s for s in HYBRID_LM_SCOPES if s not in TABULAR_SCOPES)
 #: the per-step program's module name (``jax.jit`` of ``train_step``);

@@ -111,7 +111,8 @@ def test_compiled_step_carries_every_scope(path, scope):
 
 def _lm_step_text() -> str:
     """The compiled per-step program of a tiny ``hybrid_lm`` trainer (the
-    health guard's step, as the CLI builds it): one layer of every kind,
+    health guard's step, as the CLI builds it): one layer of every kind, the
+    dense gated feed-forward among them (its experts are then gated too),
     the window and the full attention each with its rotary."""
     if "lm" not in _STEP_TEXTS:
         from shifu_tensorflow_tpu.config.model_config import ModelConfig
@@ -120,7 +121,8 @@ def _lm_step_text() -> str:
         mc = ModelConfig.from_json({"train": {"params": {
             "ModelType": "hybrid_lm", "Optimizer": "adam",
             "LearningRate": 1e-3, "hidden_size": 32,
-            "hybrid_override_pattern": "MEW*", "vocab_size": 64,
+            "hybrid_override_pattern": "MEW*D", "vocab_size": 64,
+            "intermediate_size": 48, "hidden_act": "silu",
             "sliding_window": 4, "rope_parameters": {
                 "sliding_attention": {"rope_type": "default",
                                       "rope_theta": 100.0},

@@ -135,13 +135,14 @@ def test_the_shipped_file_parses_and_the_other_decoders_tree_is_unchanged():
 @pytest.mark.parametrize("bad,match", [
     ({"layer_types": ["sliding_attention", "linear_attention"]},
      "layer_types"),
-    ({"mlp_layer_types_": ["dense", "sparse"]}, "mlp_layer_types"),
+    ({"mlp_layer_types_": ["conv", "sparse"]}, "mlp_layer_types"),
+    ({"mlp_layer_types_": ["dense", "sparse"]}, "intermediate_size"),
     ({"hybrid_override_pattern": "WE*EM"}, "hybrid_override_pattern"),
     ({"num_hidden_layers_": 4}, "num_hidden_layers"),
     ({"sliding_window": 0}, "sliding_window"),
     ({"hidden_act": "gelu"}, "hidden_act"),
     ({"scoring_func": "topk"}, "scoring_func"),
-    ({"n_shared_experts": 1}, "n_shared_experts"),
+    ({"n_shared_experts": -1}, "n_shared_experts"),
     ({"attention_bias": True}, "attention_bias"),
     ({"n_routed_experts": 16}, "num_experts"),
     ({"expert_tile": 100}, "expert_tile"),

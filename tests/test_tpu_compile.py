@@ -222,6 +222,62 @@ def test_gated_experts_lower_at_the_swa_cells_shape(topo):
     assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
 
 
+# the mixed-head cell's kernels at its published widths
+# (benchmark/configs/laguna_xs2_ep8.json): 1 row x 8,192 tokens
+
+
+@pytest.mark.parametrize("heads,window", [(64, 512), (48, None)],
+                         ids=["window-64-heads", "full-48-heads"])
+def test_flash_lowers_at_the_mixed_head_cells_shapes(topo, heads, window):
+    """64 query heads over 8 KV heads of 128 inside a 512 window (one
+    512-row tile: a query block walks 2 key blocks), and 48 over the same 8
+    on the folded triangle, at S 8,192: forward, dQ and dK/dV kernels."""
+    from shifu_tensorflow_tpu.models.sequence import make_attention
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    attention = make_attention("flash", None, causal=True, window=window)
+    q = jax.ShapeDtypeStruct((1, 8192, heads, 128), jnp.float32,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 8192, 8, 128), jnp.float32,
+                              sharding=one_chip)
+
+    def loss(q, k, v):
+        k, v = (jnp.repeat(x, heads // 8, axis=2) for x in (k, v))
+        return jnp.sum(attention(q, k, v) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, kv, kv).compile()
+    assert _kernels(compiled) == 3
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 << 30
+
+
+def test_small_gated_experts_lower_at_the_mixed_head_cells_shape(topo):
+    """32 held gated experts of 2048 x 512 of a router's 256 over the
+    65,536 (token, choice) pairs of a step, the cell's 384-row tiles: a
+    ``while`` in both directions, three weight gradients."""
+    from shifu_tensorflow_tpu.ops import grouped
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def on(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(h, w_gate, up, down, weights, ids):
+        pair, tile_expert, n_tiles, _ = grouped.plan_tiles(ids, 0, 32, 384)
+        token = jnp.where(pair < ids.size, pair // 8, h.shape[0])
+        gate = jnp.where(pair < ids.size,
+                         jnp.take(weights.reshape(-1), pair, mode="clip"), 0.)
+        return jnp.sum(grouped.gated_expert_mlp(
+            h, w_gate, up, down, token, gate, tile_expert, n_tiles,
+            384) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2, 3, 4))).lower(
+        on((8192, 2048)), on((32, 2048, 512)), on((32, 2048, 512)),
+        on((32, 512, 2048)), on((8192, 8)),
+        on((8192, 8), jnp.int32)).compile()
+    assert compiled.as_text().count(" while(") >= 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+
+
 # ----------------------------------------------------- the flagship step
 
 
