@@ -27,7 +27,13 @@ such layers: config/model_config.py ``HybridLMConfig``):
   positions where the layer's type has ``rope_parameters`` (``default``
   or ``yarn``: :func:`rope_tables`) over the first
   ``partial_rotary_factor`` of a head's dimensions; none otherwise (the
-  Mamba layers carry position).
+  Mamba layers carry position).  The rotation (:func:`rotate`) is
+  :func:`apply_rope`'s expression, or, where the static shapes say so
+  (ops/pallas/rope.py ``lanes_pay``: a head of whole 128-lane registers,
+  float32) and the program is lowered for the TPU, one kernel pass a
+  tensor that reads q or k as its projection leaves it and writes it
+  head-major, where the flash kernels read it; which one a compiled
+  step holds is in its op names (``rope_lanes``).
 
 Ingest compatibility (as models/sequence.py): a PSV row carries its
 ``S`` token ids in the float32 feature block; the model casts them on
@@ -68,6 +74,7 @@ from shifu_tensorflow_tpu.config.model_config import (
     RopeParameters,
 )
 from shifu_tensorflow_tpu.ops import grouped, ssm_scan
+from shifu_tensorflow_tpu.ops.pallas import rope as rope_kernel
 
 #: rows of a grouped-product tile.  A tile costs its rows' products or the
 #: read of its expert's weights, whichever is longer (2688 x 1856 float32
@@ -388,6 +395,18 @@ def apply_rope(u, cos, sin):
     return jnp.concatenate(parts, axis=-1)
 
 
+def rotate(u, cos, sin):
+    """:func:`apply_rope` by the path the static shapes pick: where
+    ``rope_kernel.lanes_pay`` (a head of whole 128-lane registers,
+    float32) a program lowered for the TPU gets the kernel, whose one pass
+    leaves the result as the flash kernels read it; every other program,
+    and every other head or dtype, the expression."""
+    if not rope_kernel.lanes_pay(u.shape[-1], 2 * cos.shape[-1], u.dtype):
+        return apply_rope(u, cos, sin)
+    return jax.lax.platform_dependent(
+        u, cos, sin, tpu=rope_kernel.rope_lanes, default=apply_rope)
+
+
 class AttentionMixer(nn.Module):
     """``kind`` ``*``: ``attention`` over every earlier key under
     ``attn.core``; ``W``: ``attention`` (the caller's windowed one) under
@@ -416,7 +435,7 @@ class AttentionMixer(nn.Module):
         if rope is not None:
             with jax.named_scope("attn.rope"):
                 cos, sin = rope_tables(rope, s, hd)
-                q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+                q, k = rotate(q, cos, sin), rotate(k, cos, sin)
         with jax.named_scope("attn.window" if self.kind == "W"
                              else "attn.core"):
             # each KV head serves nq / nkv query heads: the repeat's

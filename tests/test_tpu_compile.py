@@ -278,6 +278,139 @@ def test_small_gated_experts_lower_at_the_mixed_head_cells_shape(topo):
     assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
 
 
+# the rotary kernel (ops/pallas/rope.py) at the shapes the two cells with
+# rotary positions run, alone and in front of the flash kernels
+
+#: (rows, query heads, KV heads, dimensions that turn, window)
+ROTARY_SHAPES = [
+    pytest.param(2, 32, 4, 128, 1024, id="mellum-window-2x32-whole"),
+    pytest.param(1, 64, 8, 128, 512, id="laguna-window-64-whole"),
+    pytest.param(1, 48, 8, 64, None, id="laguna-full-48-half"),
+]
+
+
+def _rotary_shapes(one_chip, rows, heads, kv_heads, turning):
+    def on(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    return (on(rows, 8192, heads, 128), on(rows, 8192, kv_heads, 128),
+            on(8192, turning // 2))
+
+
+@pytest.mark.parametrize("rows,heads,kv_heads,turning,window", ROTARY_SHAPES)
+def test_rotary_kernel_lowers_at_the_cells_shapes(topo, rows, heads,
+                                                  kv_heads, turning, window):
+    """The rotation of q and of k and their transposes through Mosaic: two
+    kernels a tensor, the forward's named ``rope_lanes`` and the
+    backward's ``rope_lanes_t``."""
+    from shifu_tensorflow_tpu.ops.pallas import rope
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    q, k, table = _rotary_shapes(one_chip, rows, heads, kv_heads, turning)
+    assert rope.lanes_pay(128, turning, jnp.float32)
+
+    def loss(q, k, cos, sin):
+        return sum(jnp.sum(rope.rope_lanes(u, cos, sin) ** 2)
+                   for u in (q, k))
+
+    text = jax.jit(jax.grad(loss, (0, 1))).lower(
+        q, k, table, table).compile().as_text()
+    assert sorted(_rotary_calls(text).values()) == (
+        ["rope_lanes"] * 2 + ["rope_lanes_t"] * 2)
+
+
+def _rotary_calls(text) -> dict:
+    """``instruction -> kernel`` of the rotary kernel's calls: the name a
+    ``pallas_call`` was given ends its op's path in the metadata."""
+    return {m.group(1): m.group(2) for m in re.finditer(
+        r"%([\w.\-]+) = \S+ custom-call\(.*op_name=\"[^\"]*"
+        r"\b(rope_lanes(?:_t)?)\)*/pallas_call\"", text)}
+
+
+def _instructions(text) -> dict:
+    """``name -> (opcode, operand names, result type)`` of a compiled
+    module's text."""
+    found = {}
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (\(.*?\)|\S+) "
+                     r"([\w\-]+)\((.*?)\)(?:, |$)", line)
+        if m:
+            found[m.group(1)] = (m.group(3),
+                                 re.findall(r"%([\w.\-]+)", m.group(4)),
+                                 m.group(2))
+    return found
+
+
+def _through_bitcasts(instructions, name, to_users: bool) -> list[str]:
+    """The opcodes the array meets first, bitcasts and tuple reads seen
+    through: of its readers, or of the instruction that made it."""
+    def step(n):
+        if to_users:
+            return [u for u, (_, ops, _) in instructions.items()
+                    if n in ops]
+        return instructions[n][1][:1]
+
+    met, walk = [], step(name)
+    while walk:
+        n = walk.pop()
+        op = instructions[n][0]
+        if op in ("bitcast", "get-tuple-element"):
+            walk.extend(step(n))
+        else:
+            met.append(f"{op}:{n}" if op == "custom-call" else op)
+    return met
+
+
+@pytest.mark.parametrize("rows,heads,kv_heads,turning,window", ROTARY_SHAPES)
+def test_the_flash_kernels_read_the_rotated_heads_where_they_lie(
+        topo, rows, heads, kv_heads, turning, window):
+    """rope -> repeat -> banded / folded flash, as ``AttentionMixer``
+    orders them, compiled together: nothing stands between q's rotation
+    and the flash kernels (their ``(B, S, H, D) -> (B·H, S, D)`` is the
+    inverse of the transposition the rotary kernel hands its output back
+    through, and XLA folds the pair), nor between the dQ kernel and the
+    transposed rotation; k's rotation is read by the repeat's broadcast
+    and its transpose reads the repeat's sum, with no copy on the way."""
+    from shifu_tensorflow_tpu.models import hybrid_lm
+    from shifu_tensorflow_tpu.models.sequence import make_attention
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    attention = make_attention("flash", None, causal=True, window=window)
+    q, kv, table = _rotary_shapes(one_chip, rows, heads, kv_heads, turning)
+
+    def attended(q, k, v, cos, sin):
+        q, k = hybrid_lm.rotate(q, cos, sin), hybrid_lm.rotate(k, cos, sin)
+        k, v = (jnp.repeat(x, heads // kv_heads, axis=2) for x in (k, v))
+        return attention(q, k, v)
+
+    def loss(q, k, v, cos, sin):
+        return jnp.sum(attended(q, k, v, cos, sin) ** 2)
+
+    def flash_calls(met):
+        return [m for m in met if m.startswith("custom-call:")
+                and m.split(":")[1] not in calls]
+
+    for fn, grad in ((attended, False), (jax.grad(loss, (0, 1, 2)), True)):
+        text = jax.jit(fn).lower(q, kv, kv, table, table).compile().as_text()
+        entry = _instructions(text[text.rindex("ENTRY"):])
+        calls = _rotary_calls(text)
+        assert sorted(calls.values()) == ["rope_lanes"] * 2 + (
+            ["rope_lanes_t"] * 2 if grad else [])
+        for n, kernel in calls.items():
+            forward = kernel == "rope_lanes"
+            met = _through_bitcasts(entry, n, to_users=forward)
+            of_q = entry[n][2].startswith(
+                f"f32[{rows},{heads},8192,128]" if forward
+                else f"f32[{rows},8192,{heads * 128}]")
+            if of_q:
+                # the forward kernel and, with the gradient, dQ and dK/dV
+                # read q; the dQ kernel alone writes its cotangent
+                assert met == flash_calls(met), met
+                assert len(met) == (3 if grad and forward else 1)
+            else:
+                assert met == (["broadcast"] if forward else ["reduce"]), met
+
+
 # ----------------------------------------------------- the flagship step
 
 
