@@ -39,7 +39,8 @@ cleared), ``mem`` the device-memory accountant's bucket split and
 high-water marks, and ``profile`` lists journaled ``jax.profiler``
 captures, (``--request``) asks the running fleet for one, or
 (``--phases <dump dir>``) splits a capture's train step by the program's
-own phase names.  Every
+own phase names and, "between epochs", the device's wait from one epoch's
+last step to the next one's first by the host span open over it.  Every
 reading subcommand takes ``--json`` for machine-readable output —
 scripts and the autoscaling supervisor must not screen-scrape the
 human renderer.
@@ -220,7 +221,8 @@ def build_parser() -> argparse.ArgumentParser:
     prof.add_argument("--phases", metavar="DUMP_DIR",
                       help="reduce the newest capture under DUMP_DIR: the "
                            "train step's device time by the program's "
-                           "named scopes, and the host spans it holds")
+                           "named scopes, the host spans it holds, and "
+                           "the device's idle between epochs by span")
     prof.add_argument("--request", action="store_true",
                       help="write a capture trigger beside the journal; "
                            "the fleet's next obs tick starts the window")
@@ -2129,7 +2131,32 @@ def _cmd_profile_phases(args) -> int:
         print(f"  {'host span':<22} {'count':>7} {'total s':>10}")
         for name, h in out["host_spans"].items():
             print(f"  {name:<22} {h['count']:>7} {h['total_s']:>10.4f}")
+    if out["boundaries"]:
+        _print_boundaries(out["boundaries"])
     return 0
+
+
+def _print_boundaries(b: dict) -> None:
+    """The "between epochs" block of ``profile --phases``
+    (``obs.profile.boundaries``)."""
+    n = b["boundaries"]
+    print(f"  between epochs: {n} {'boundary' if n == 1 else 'boundaries'}, "
+          f"{b['devices']} device(s); gap {b['gap_ms']['median']:.3f} ms "
+          f"(largest {b['gap_ms']['max']:.3f}), of it the device idle "
+          f"{b['idle_ms']['median']:.3f} ms (largest "
+          f"{b['idle_ms']['max']:.3f}; all of them {b['idle_ms']['sum']:.3f})")
+    print(f"  {'idle under':<22} {'median ms':>10} {'largest':>10}")
+    for name, ms in b["idle_split_ms"].items():
+        print(f"  {name:<22} {ms['median']:>10.3f} {ms['max']:>10.3f}")
+    for name, ms in b["first_ms"].items():
+        steady = b["steady_ms"].get(name)
+        print(f"  an epoch's first {name}: {ms['median']:.3f} ms (largest "
+              f"{ms['max']:.3f})" + ("" if steady is None else
+                                     f"; the others' median {steady:.3f}"))
+    edges = b["edges_ms"]
+    print(f"  the window's edges: idle {edges['open']['idle']:.3f} ms before "
+          f"the first step, {edges['close']['idle']:.3f} after the last; "
+          f"inside and between steps {b['in_steps_idle_ms']:.3f}")
 
 
 # ---- top ----

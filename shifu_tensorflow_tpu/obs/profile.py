@@ -29,7 +29,9 @@ stdlib-only at import; jax loads inside the capture thread.
 train step's device time split by the program's own ``jax.named_scope``
 names (``embed.gather.fwd``, ``embed.gather.bwd``, ``optimizer.update``
 ...), which survive a recompile that renumbers XLA's ``fusion.N``, and
-the program's host spans (``obs/trace.py``) that the capture holds.
+the program's host spans (``obs/trace.py``) that the capture holds; and,
+with :func:`boundaries`, what the device waited for between one epoch's
+last step and the next one's first, by the host span open over the wait.
 """
 
 from __future__ import annotations
@@ -49,7 +51,7 @@ from shifu_tensorflow_tpu.utils import logs
 log = logs.get("obs")
 
 __all__ = ["configure", "unconfigure", "trigger_path", "request", "poll",
-           "PHASE_SCOPES", "phase_of", "phases"]
+           "PHASE_SCOPES", "phase_of", "phases", "boundaries"]
 
 _lock = threading.Lock()
 _trigger: str | None = None     # trigger file this process polls
@@ -357,9 +359,11 @@ def phases(dump_dir: str, step: str = STEP_PROGRAM) -> dict:
     path = find_xplane(dump_dir)
     if path is None:
         return {}
-    out = reduce_phases(load_capture(path, step))
+    capture = load_capture(path, step)
+    out = reduce_phases(capture)
     if out:
         out["step"], out["xplane"] = step, path
+        out["boundaries"] = boundaries(capture)
     return out
 
 
@@ -452,3 +456,229 @@ def reduce_phases(capture: dict) -> dict:
             [(n, ms) for n, ms in _medians_ms(loose).items() if ms > 0][:12]),
         "host_spans": dict(sorted(host.items())),
     }
+
+
+# ---- reading a dump: between epochs ----
+
+#: the value fetch an epoch ends with (train/trainer.py): it ends after the
+#: device's last step of the epoch and before the next epoch's first (its
+#: START does not: the host runs ahead of the device, by up to the epoch)
+BLOCK_SPAN = "step.block"
+NO_SPAN = "(no span)"
+#: the put thread's spans whose first event of an epoch (the stream's first
+#: batch, its placement) is on the boundary's path and the others are not
+FIRST_OF_EPOCH = ("step.host.produce", "step.infeed.put")
+
+
+def _merged(intervals) -> list[tuple[int, int]]:
+    """Sorted disjoint ``(start, end)`` covering the same instants."""
+    out: list[list[int]] = []
+    for start, end in sorted(intervals):
+        if out and start <= out[-1][1]:
+            out[-1][1] = max(out[-1][1], end)
+        else:
+            out.append([start, end])
+    return [(start, end) for start, end in out]
+
+
+def _inside(lo: int, hi: int, pieces: list, ends: list) -> list:
+    """The parts of the sorted disjoint ``pieces`` (``(start, end, ...)``;
+    ``ends`` their ends) that lie in ``[lo, hi)``, cut to it."""
+    out = []
+    k = bisect.bisect_right(ends, lo)
+    while k < len(pieces) and pieces[k][0] < hi:
+        piece = pieces[k]
+        out.append((max(piece[0], lo), min(piece[1], hi), *piece[2:]))
+        k += 1
+    return out
+
+
+def _idle(lo: int, hi: int, busy: list, busy_ends: list) -> list:
+    """``[lo, hi)`` less the device's busy intervals."""
+    out, cur = [], lo
+    for start, end in _inside(lo, hi, busy, busy_ends):
+        if start > cur:
+            out.append((cur, start))
+        cur = end
+    if cur < hi:
+        out.append((cur, hi))
+    return out
+
+
+def _shortest_open(host: list) -> list[tuple[int, int, str]]:
+    """Disjoint ``(start, end, name)`` pieces of the host spans' union,
+    each named after the shortest span open during it.  The spans of one
+    thread nest (they are context managers), so on a thread the shortest
+    is the innermost: ``epoch.drain`` keeps what ``step.block`` leaves,
+    ``epoch.fill`` what the first ``step.infeed.wait`` leaves.  Across
+    threads it names the narrower wait: the put thread's
+    ``step.host.produce`` and ``step.infeed.put`` inside the consumer's
+    wait for them."""
+    edges = []
+    for i, (_, start, dur) in enumerate(host):
+        edges.append((start, 1, i))
+        edges.append((start + dur, 0, i))
+    edges.sort()
+    out: list[tuple[int, int, str]] = []
+    open_: set[int] = set()
+    prev = 0
+    for t, opening, i in edges:
+        if open_ and t > prev:
+            name = host[min(open_, key=lambda k: host[k][2])][0]
+            if out and out[-1][2] == name and out[-1][1] == prev:
+                out[-1] = (out[-1][0], t, name)
+            else:
+                out.append((prev, t, name))
+        (open_.add if opening else open_.discard)(i)
+        prev = t
+    return out
+
+
+def _spread(values: list) -> dict[str, float]:
+    """Median and largest of nanoseconds, in ms."""
+    return {"median": statistics.median(values) / 1e6,
+            "max": max(values) / 1e6}
+
+
+def _device_boundaries(dev: dict, block_ends: list, segments: list,
+                       window: tuple[int, int]) -> dict | None:
+    """One device's boundaries (``{"gap", "idle", "split", "block_end",
+    "until"}`` each, in ns), the window's two edges and the idle left
+    inside and between the steps; ``None`` where the step program ran less
+    than twice."""
+    steps = sorted(dev["steps"])
+    if len(steps) < 2:
+        return None
+    busy = _merged((start, start + dur) for _, _, start, dur in dev["ops"]
+                   if dur > 0)
+    busy_ends = [end for _, end in busy]
+    seg_ends = [end for _, end, _ in segments]
+
+    def idle_ns(lo, hi):
+        return sum(e - s for s, e in _idle(lo, hi, busy, busy_ends))
+
+    found = []
+    for (start, dur), (until, _) in zip(steps, steps[1:]):
+        lo = start + dur
+        k = bisect.bisect_left(block_ends, lo)
+        if k == len(block_ends) or block_ends[k] > until:
+            continue
+        split: dict[str, int] = {}
+        idle = 0
+        for s, e in _idle(lo, until, busy, busy_ends):
+            idle += e - s
+            named = 0
+            for a, b, name in _inside(s, e, segments, seg_ends):
+                split[name] = split.get(name, 0) + b - a
+                named += b - a
+            if e - s > named:
+                split[NO_SPAN] = split.get(NO_SPAN, 0) + e - s - named
+        found.append({"gap": until - lo, "idle": idle, "split": split,
+                      "block_end": block_ends[k], "until": until})
+    first, last = steps[0][0], steps[-1][0] + steps[-1][1]
+    lo, hi = min(window[0], first), max(window[1], last)
+    return {
+        "found": found,
+        "open": {"gap": first - lo, "idle": idle_ns(lo, first)},
+        "close": {"gap": hi - last, "idle": idle_ns(last, hi)},
+        "in_steps": idle_ns(first, last) - sum(b["idle"] for b in found),
+    }
+
+
+def boundaries(capture: dict) -> dict:
+    """What the device waited for between epochs, from the same plain
+    lists :func:`reduce_phases` reads.  An epoch boundary is the gap
+    between two consecutive executions of the step program on a device
+    that holds the END of a ``step.block`` span.  For each: the gap, the
+    device's idle time inside it (the gap less the union of the op line,
+    as the benchmark's ``device_idle_pct`` counts it) and that idle shared
+    out by the host span open over it (:func:`_shortest_open`; what no
+    span covers is ``(no span)``).  Reported in ms: the median and the
+    largest over a device's boundaries, then the median over devices;
+    ``each`` lists the lowest device's boundaries one by one.  Beside
+    them the epoch's FIRST ``step.host.produce`` and ``step.infeed.put``
+    (the first to start after the boundary's ``step.block``) against the
+    median of the others: a starved first batch shows there.  The window's
+    own two edges (its start to the first step, the last step to its end)
+    and the idle inside and between steps are kept apart, so the idle of
+    the window is ``idle_ms.sum`` + both edges + ``in_steps_idle_ms``.
+    The window is a host span that holds every step (the benchmark's
+    ``bench.window``), which then names no idle; without one, the first
+    event's start to the last one's end.  ``{}`` for a capture with no
+    boundary."""
+    steps = [(start, start + dur) for d in capture["devices"].values()
+             for start, dur in d["steps"]]
+    if not steps:
+        return {}
+    first, last = min(s for s, _ in steps), max(e for _, e in steps)
+    def holds_the_steps(h) -> bool:
+        return h[1] <= first and h[1] + h[2] >= last
+
+    around = [h for h in capture["host"] if holds_the_steps(h)]
+    host = [h for h in capture["host"]
+            if h[2] > 0 and not holds_the_steps(h)]
+    window = ((max(h[1] for h in around),
+               min(h[1] + h[2] for h in around)) if around else
+              (min([first] + [h[1] for h in host]),
+               max([last] + [h[1] + h[2] for h in host])))
+    block_ends = sorted(h[1] + h[2] for h in host if h[0] == BLOCK_SPAN)
+    segments = _shortest_open(host)
+    per_device = [b for b in (
+        _device_boundaries(capture["devices"][dev], block_ends, segments,
+                           window)
+        for dev in sorted(capture["devices"], key=int)) if b and b["found"]]
+    if not per_device:
+        return {}
+
+    def over_devices(read) -> dict[str, float]:
+        """``{"median", "max"}``: over a device's boundaries, then the
+        median over devices."""
+        spreads = [_spread([read(b) for b in d["found"]])
+                   for d in per_device]
+        return {k: statistics.median(s[k] for s in spreads)
+                for k in ("median", "max")}
+
+    def device_median_ms(read) -> float:
+        return statistics.median(read(d) for d in per_device) / 1e6
+
+    names = {n for d in per_device for b in d["found"] for n in b["split"]}
+    split = {n: over_devices(lambda b, n=n: b["split"].get(n, 0))
+             for n in names}
+    lowest = per_device[0]["found"]
+    out = {
+        "boundaries": min(len(d["found"]) for d in per_device),
+        "devices": len(per_device),
+        "gap_ms": over_devices(lambda b: b["gap"]),
+        "idle_ms": dict(
+            over_devices(lambda b: b["idle"]),
+            sum=device_median_ms(
+                lambda d: sum(b["idle"] for b in d["found"]))),
+        "idle_split_ms": dict(sorted(
+            split.items(), key=lambda kv: -kv[1]["median"])),
+        "each": [{"gap_ms": b["gap"] / 1e6, "idle_ms": b["idle"] / 1e6,
+                  "split_ms": {n: ns / 1e6 for n, ns in sorted(
+                      b["split"].items(), key=lambda kv: -kv[1])}}
+                 for b in lowest],
+        "edges_ms": {
+            edge: {k: device_median_ms(lambda d, k=k: d[edge][k])
+                   for k in ("gap", "idle")} for edge in ("open", "close")},
+        "in_steps_idle_ms": device_median_ms(lambda d: d["in_steps"]),
+        "first_ms": {}, "steady_ms": {},
+    }
+    for name in FIRST_OF_EPOCH:
+        events = sorted((h[1], h[2]) for h in host if h[0] == name)
+        starts = [start for start, _ in events]
+        firsts = {0} if events and events[0][0] < first else set()
+        of_epochs = []
+        for b in lowest:
+            k = bisect.bisect_left(starts, b["block_end"])
+            if k < len(events) and starts[k] < b["until"]:
+                firsts.add(k)
+                of_epochs.append(events[k][1])
+        others = [dur for k, (_, dur) in enumerate(events)
+                  if k not in firsts]
+        if of_epochs:
+            out["first_ms"][name] = _spread(of_epochs)
+        if others:
+            out["steady_ms"][name] = statistics.median(others) / 1e6
+    return out

@@ -4,25 +4,49 @@ tf.data (arxiv 2101.12127) showed input-pipeline stall time is the
 dominant *invisible* training bottleneck; TF-Replicator (arxiv
 1902.00465) showed per-replica timing through a common instrumentation
 layer is what makes distributed-SGD regressions diagnosable.  This
-module is that layer for the per-step loop: every epoch can report a
-breakdown of
+module is that layer for the per-step loop.  The spans the trainer
+opens (train/trainer.py, data/dataset.py), and the thread each runs on:
 
-- ``step.host``      — producing the next host batch (parse/stack/filter),
-- ``step.infeed``    — device placement (host-side gather/pad + transfer),
-- ``step.dispatch``  — enqueueing the jitted step,
-- ``step.block``     — fetching results (the only true completion wait
-  on this backend — see utils/profiling.true_sync),
+- ``step.host``         — producing the next host batch (parse / stack /
+  filter) where the path forbids the put thread: consumer thread.  Under
+  the pipelined infeed it is ``step.host.produce``, on the put thread,
+  overlapping dispatch;
+- ``step.infeed``       — device placement (host-side gather/pad +
+  transfer) inline: consumer thread.  Pipelined it splits into
+  ``step.infeed.put`` (the placement, put thread) and
+  ``step.infeed.wait`` (the consumer's wait for the next placed batch);
+- ``step.dispatch``     — enqueueing the jitted step: consumer thread;
+- ``step.block``        — fetching results (the only true completion wait
+  on this backend — see utils/profiling.true_sync): consumer thread, once
+  an epoch (once a step on the host-embedding path);
+- ``epoch.fill``        — from the moment the epoch loop starts building
+  its feed (the put thread's start, the stream's first batch, its
+  placement) to the first unit in the consumer's hands: consumer thread;
+  it holds the epoch's first ``step.infeed.wait`` (inline: the first
+  ``step.host`` + ``step.infeed``);
+- ``epoch.drain``       — from the exit of the loop over the feed through
+  the feed's close (the join of the put thread) and ``step.block``, which
+  it holds, to the epoch's mean: consumer thread;
+- ``epoch.turn``        — what the epoch loop does outside ``train_epoch``
+  (journal, autotuner, the stream's rebuild, callbacks, checkpoint):
+  consumer thread, several an epoch.
 
-plus named spans around checkpoint save/restore (train/checkpoint.py),
-retry backoff sleeps (utils/retry.py), and coordinator RPCs
+The four disjoint ``step.`` phases (host, infeed, dispatch, block) are the
+epoch's wall-clock budget (:func:`budget_fields`); the three ``epoch.``
+spans between them cover the consumer thread from one epoch's last
+dispatch to the next one's first, and ``obs profile --phases`` shares the
+device's wait there out among all of them (obs/profile.py ``boundaries``).
+There are also named spans around checkpoint save/restore
+(train/checkpoint.py), retry backoff sleeps (utils/retry.py), and coordinator RPCs
 (coordinator/coordinator.py).  Spans carry the worker index so SPMD
 replicas can be compared side by side.
 
 Cost discipline: a disabled site is ONE module-global load + ``is None``
 check; an enabled site is two ``perf_counter`` calls and a dict update
-under a lock (~1µs).  The trainer's per-step phases are all in one
-thread, so contention is nil; the lock exists for the cross-thread
-spans (retry sleeps on a checkpoint writer thread, RPC heartbeats).
+under a lock (~1µs).  The trainer's per-step phases run on two threads
+at most (consumer and put thread), so contention is slight; the lock is
+for them and for the cross-thread spans (retry sleeps on a checkpoint
+writer thread, RPC heartbeats).
 ``sample_every=N`` measures every Nth event per span name — steady-state
 ratios stay unbiased while the (already tiny) cost divides by N.
 

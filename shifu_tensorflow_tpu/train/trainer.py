@@ -20,6 +20,8 @@ Epoch-level behavior parity:
 
 from __future__ import annotations
 
+import contextlib
+import itertools
 import threading
 import time
 from dataclasses import dataclass, field
@@ -1429,53 +1431,71 @@ class Trainer:
         def via(unit: Unit) -> EpochPath:
             return path.tail if unit.tail else path
 
-        feed = self._infeed(
-            path.units(batches), lambda unit: via(unit).put(unit),
-            tracer, threaded=path.threaded, depth=path.depth)
-        try:
-            for unit in feed:
-                with obs_trace.maybe_span(tracer, "step.dispatch"):
-                    self.state, aux = via(unit).step(self.state, unit.data)
-                if path.after_step is not None:
-                    path.after_step(aux, unit)
-                losses.append(aux["loss"])
-                if with_norm:
-                    gnorms.append(aux["grad_norm"])
+        # the epoch's boundary on the consumer thread, beside the loop's
+        # "epoch.turn": "epoch.fill" from the feed's build (the put
+        # thread's start, the stream's first batch and its placement) to
+        # the first unit in hand, so it contains the epoch's first
+        # "step.infeed.wait" (unthreaded: the first "step.host" +
+        # "step.infeed"); "epoch.drain" from the loop's exit, raised or
+        # not, through the feed's close (the put thread's join) and
+        # "step.block" to the epoch's mean.  Twice an epoch, not a step.
+        feed = None
+        with contextlib.ExitStack() as drain:
+            try:
+                with obs_trace.maybe_span(tracer, "epoch.fill"):
+                    feed = self._infeed(
+                        path.units(batches),
+                        lambda unit: via(unit).put(unit),
+                        tracer, threaded=path.threaded, depth=path.depth)
+                    units = iter(feed)
+                    first = next(units, None)
+                for unit in (() if first is None
+                             else itertools.chain((first,), units)):
+                    with obs_trace.maybe_span(tracer, "step.dispatch"):
+                        self.state, aux = via(unit).step(self.state,
+                                                         unit.data)
+                    if path.after_step is not None:
+                        path.after_step(aux, unit)
+                    losses.append(aux["loss"])
+                    if with_norm:
+                        gnorms.append(aux["grad_norm"])
+                    if with_counters:
+                        counters.append(aux["counters"])
+                    weights.append(unit.batches)
+                    if guard is not None:
+                        guard.tick()
+                    if self.step_timer is not None:
+                        self.step_timer.step(aux["loss"], rows=unit.rows)
+            finally:
+                drain.enter_context(
+                    obs_trace.maybe_span(tracer, "epoch.drain"))
+                close_stream(feed)
+            if not losses:
+                return float("nan"), 0
+            with obs_trace.maybe_span(tracer, "step.block"):
+                # one loss a unit, or (scan) one a batch of it
+                vals = np.asarray(jax.device_get(losses)).reshape(-1)
+                gvals = (np.asarray(jax.device_get(gnorms))
+                         if with_norm else None)
                 if with_counters:
-                    counters.append(aux["counters"])
-                weights.append(unit.batches)
-                if guard is not None:
-                    guard.tick()
-                if self.step_timer is not None:
-                    self.step_timer.step(aux["loss"], rows=unit.rows)
-        finally:
-            close_stream(feed)
-        if not losses:
-            return float("nan"), 0
-        with obs_trace.maybe_span(tracer, "step.block"):
-            # one loss a unit, or (scan) one a batch of it
-            vals = np.asarray(jax.device_get(losses)).reshape(-1)
-            gvals = (np.asarray(jax.device_get(gnorms))
-                     if with_norm else None)
-            if with_counters:
-                fetched = jax.device_get(counters)
-                self.epoch_counters = {
-                    k: np.asarray([c[k] for c in fetched])
-                    for k in fetched[0]}
-        if guard is not None:
-            guard.note_losses(vals, gvals, mode=path.loss_mode)
-        # all-padding units report NaN by contract (apply_update);
-        # exclude them from the epoch mean instead of biasing it
-        real = ~np.isnan(vals)
-        if not real.any():
-            mean = float("nan")
-        elif path.weighted:
-            mean = float(np.average(
-                vals[real].astype(np.float64),
-                weights=np.asarray(weights, np.float64)[real]))
-        else:
-            mean = float(np.mean(vals[real]))
-        return mean, sum(weights)
+                    fetched = jax.device_get(counters)
+                    self.epoch_counters = {
+                        k: np.asarray([c[k] for c in fetched])
+                        for k in fetched[0]}
+            if guard is not None:
+                guard.note_losses(vals, gvals, mode=path.loss_mode)
+            # all-padding units report NaN by contract (apply_update);
+            # exclude them from the epoch mean instead of biasing it
+            real = ~np.isnan(vals)
+            if not real.any():
+                mean = float("nan")
+            elif path.weighted:
+                mean = float(np.average(
+                    vals[real].astype(np.float64),
+                    weights=np.asarray(weights, np.float64)[real]))
+            else:
+                mean = float(np.mean(vals[real]))
+            return mean, sum(weights)
 
     def _stacked_chunks(self, batches: Iterable[Batch],
                         K: int) -> Iterator[Unit]:
@@ -2168,10 +2188,15 @@ class Trainer:
                     self.state, train_dev,
                     jax.random.fold_in(base_key, epoch)
                 )
-            with obs_trace.maybe_span(self.tracer, "step.block"):
-                vals = np.asarray(jax.device_get(aux["loss"]))
-            real = vals[~np.isnan(vals)]
-            train_loss = float(np.mean(real)) if real.size else float("nan")
+            # the tensors were placed once, before the first epoch: this
+            # path has no feed to fill, so of the two boundary spans of
+            # _run_epoch it opens "epoch.drain" alone
+            with obs_trace.maybe_span(self.tracer, "epoch.drain"):
+                with obs_trace.maybe_span(self.tracer, "step.block"):
+                    vals = np.asarray(jax.device_get(aux["loss"]))
+                real = vals[~np.isnan(vals)]
+                train_loss = (float(np.mean(real)) if real.size
+                              else float("nan"))
             train_time = time.time() - t0
 
             ev = self._no_validation()

@@ -85,7 +85,8 @@ def test_span_names(path):
     """Threaded paths record the pipelined infeed's pair and the
     overlapped production; unthreaded ones (host-embedding: its
     zero-staleness contract; SAGN) ``step.infeed`` and ``step.host``.
-    One ``step.dispatch`` a unit, from the loop's one site."""
+    One ``step.dispatch`` a unit, one ``epoch.fill`` and one
+    ``epoch.drain`` an epoch, each from the loop's one site."""
     trainer = _trainer(path)
     trainer.tracer = Tracer()
     loss, n = trainer.train_epoch(_batches())
@@ -93,8 +94,11 @@ def test_span_names(path):
     spans = trainer.tracer.summary()
     infeed = ({"step.host.produce", "step.infeed.wait", "step.infeed.put"}
               if path in THREADED else {"step.host", "step.infeed"})
-    assert set(spans) == infeed | {"step.dispatch", "step.block"}
+    assert set(spans) == infeed | {"step.dispatch", "step.block",
+                                   "epoch.fill", "epoch.drain"}
     assert spans["step.dispatch"]["count"] == PATHS[path][2]
+    # the epoch's two boundary spans, once on every path of the one loop
+    assert spans["epoch.fill"]["count"] == spans["epoch.drain"]["count"] == 1
     # the epoch's value fetch, and host-embedding's gradient fetch a step
     assert spans["step.block"]["count"] == (6 if path == "host_emb" else 1)
 

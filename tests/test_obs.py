@@ -622,14 +622,20 @@ def test_trainer_untraced_emits_nothing_and_has_no_tracer(tmp_path):
 
 # ---- the spans on the profiler's clock ----
 
-def _captured_spans(dump_dir) -> list[str]:
-    """Names of the program's spans on a capture's host planes."""
+def _captured_events(dump_dir) -> list[list]:
+    """``[name, start_ns, dur_ns]`` of the program's spans on a capture's
+    host planes, by start."""
     from shifu_tensorflow_tpu.obs import profile as profile_mod
 
     path = profile_mod.find_xplane(str(dump_dir))
     assert path, f"no capture under {dump_dir}"
-    return [name for name, _, _ in
-            profile_mod.load_capture(path, profile_mod.STEP_PROGRAM)["host"]]
+    return sorted(profile_mod.load_capture(
+        path, profile_mod.STEP_PROGRAM)["host"], key=lambda e: e[1])
+
+
+def _captured_spans(dump_dir) -> list[str]:
+    """Names of the program's spans on a capture's host planes."""
+    return [name for name, _, _ in _captured_events(dump_dir)]
 
 
 def _drive_span(t):
@@ -749,6 +755,190 @@ def test_epoch_turn_is_spanned_journaled_and_annotated(tmp_path, stream):
     # the next breakdown (the lag every auxiliary span has)
     assert breakdowns[1]["spans"]["epoch.turn"]["count"] >= 1
     assert "epoch.turn" in t.summary()
+
+
+# ---- the epoch's boundary: epoch.fill and epoch.drain ----
+
+def _tiny_trainer(**kw):
+    from shifu_tensorflow_tpu.config.model_config import ModelConfig
+    from shifu_tensorflow_tpu.train import make_trainer
+
+    mc = ModelConfig.from_json(
+        {"train": {"params": {"NumHiddenLayers": 1, "NumHiddenNodes": [4],
+                              "ActivationFunc": ["relu"],
+                              "LearningRate": 0.1}}}
+    )
+    return make_trainer(mc, 2, feature_columns=(1, 2), **kw)
+
+
+def _holds(outer, inner) -> bool:
+    return (outer[1] <= inner[1]
+            and inner[1] + inner[2] <= outer[1] + outer[2])
+
+
+def _fit(trainer, dataset, how, epochs=2):
+    if how == "fit":
+        trainer.fit(dataset, epochs=epochs, batch_size=32)
+    else:
+        if how == "fit_stream_unthreaded":
+            trainer.infeed_pipelined = False
+        trainer.fit_stream(
+            lambda epoch: dataset.train_batches(32, epoch=epoch),
+            epochs=epochs)
+
+
+@pytest.mark.parametrize("how", ["fit", "fit_stream",
+                                 "fit_stream_unthreaded"])
+def test_epoch_fill_and_drain_once_an_epoch_in_sums_journal_and_capture(
+        tmp_path, how):
+    """The two spans that, with `epoch.turn`, cover the consumer thread
+    from one epoch's last dispatch to the next one's first: in the
+    tracer's sums, under the breakdown's `spans` (not `step.` names) and
+    on the profiler's clock, where `epoch.fill` holds the epoch's first
+    wait for a batch and `epoch.drain` the value fetch."""
+    import jax
+
+    t = trace_mod.install(Tracer())
+    journal_mod.install(Journal(str(tmp_path / "j.jsonl"), plane="train"))
+    dataset, _ = _tiny_dataset(tmp_path)
+    trainer = _tiny_trainer()
+    with jax.profiler.trace(str(tmp_path / "dump")):
+        _fit(trainer, dataset, how)
+    breakdowns = [e for e in read_events(str(tmp_path / "j.jsonl"))
+                  if e["event"] == "step_breakdown"]
+    assert len(breakdowns) == 2
+    for b in breakdowns:
+        assert b["spans"]["epoch.fill"]["count"] == 1
+        assert b["spans"]["epoch.drain"]["count"] == 1
+        assert b["spans"]["epoch.drain"]["total_s"] >= b["block_s"]
+    events = _captured_events(tmp_path / "dump")
+    fills, drains, blocks = ([e for e in events if e[0] == n] for n in (
+        "epoch.fill", "epoch.drain", "step.block"))
+    assert len(fills) == len(drains) == len(blocks) == 2
+    first = ("step.infeed" if how == "fit_stream_unthreaded"
+             else "step.infeed.wait")
+    for fill, drain, block in zip(fills, drains, blocks):
+        inside = [e for e in events if e[0] == first and _holds(fill, e)]
+        # the fill ends with the first unit in hand: it holds the first
+        # wait (unthreaded: the placements that fill the look-ahead) and
+        # no later one, and every dispatch comes after it
+        assert inside and inside[0] is next(
+            e for e in events if e[0] == first and e[1] >= fill[1])
+        assert all(e[1] >= fill[1] + fill[2] or e[1] < fill[1]
+                   for e in events if e[0] == "step.dispatch")
+        assert _holds(drain, block)
+        assert fill[1] + fill[2] <= drain[1]
+    if how != "fit_stream_unthreaded":
+        # only the first wait of an epoch is the fill's
+        waits = [e for e in events if e[0] == "step.infeed.wait"]
+        assert sum(any(_holds(f, w) for f in fills) for w in waits) == 2
+        assert len(waits) > 2
+    # the journal drained both epochs' sums: what is left is the last turn
+    assert not {"epoch.fill", "epoch.drain"} & set(t.summary())
+
+
+def test_epoch_drain_in_the_device_resident_fit(tmp_path):
+    """One dispatch an epoch over tensors placed once: no feed to fill;
+    the fetch and the mean are the drain."""
+    t = trace_mod.install(Tracer())
+    dataset, _ = _tiny_dataset(tmp_path)
+    _tiny_trainer().fit_device_resident(dataset, epochs=3, batch_size=32)
+    summary = t.summary()
+    assert summary["epoch.drain"]["count"] == 3
+    assert summary["step.block"]["count"] == 3
+    assert summary["epoch.drain"]["total_s"] >= summary["step.block"][
+        "total_s"]
+    assert "epoch.fill" not in summary
+
+
+@pytest.mark.parametrize("empty", [False, True])
+def test_an_epoch_that_raises_or_is_empty_closes_both_spans(tmp_path,
+                                                            empty):
+    """A stream that raises mid-loop: `epoch.fill` closed when the first
+    unit came, `epoch.drain` opens on the way out, joins the put thread
+    inside it and closes with the exception in flight.  An empty stream:
+    both close, and nothing is fetched."""
+    import jax
+
+    t = trace_mod.install(Tracer())
+    dataset, _ = _tiny_dataset(tmp_path)
+    trainer = _tiny_trainer()
+
+    def batches():
+        if empty:
+            return
+        for k, b in enumerate(dataset.train_batches(32, epoch=0)):
+            if k == 2:
+                raise RuntimeError("the stream broke")
+            yield b
+
+    with jax.profiler.trace(str(tmp_path / "dump")):
+        if empty:
+            loss, n = trainer.train_epoch(batches())
+            assert n == 0 and np.isnan(loss)
+        else:
+            with pytest.raises(RuntimeError, match="the stream broke"):
+                trainer.train_epoch(batches())
+    summary = t.summary()
+    assert summary["epoch.fill"]["count"] == 1
+    assert summary["epoch.drain"]["count"] == 1
+    assert "step.block" not in summary  # nothing fetched on either way out
+    assert not [th for th in threading.enumerate()
+                if th.name == "stpu-infeed-put"]
+    events = _captured_events(tmp_path / "dump")
+    fill, drain = (next(e for e in events if e[0] == n)
+                   for n in ("epoch.fill", "epoch.drain"))
+    assert fill[1] + fill[2] <= drain[1]
+    # the put thread's last event ends before the drain does: it was
+    # joined inside the span
+    puts = [e for e in events if e[0] in ("step.infeed.put",
+                                          "step.host.produce")]
+    assert all(e[1] + e[2] <= drain[1] + drain[2] for e in puts)
+    assert len([e for e in events if e[0] == "step.dispatch"]) == (
+        0 if empty else 2)
+
+
+def test_without_a_tracer_the_epoch_loop_opens_no_span(tmp_path,
+                                                       monkeypatch):
+    """No tracer installed: each site is `maybe_span`'s `is None` check
+    and the shared null context, on every path of the loop."""
+    opened = []
+    real = trace_mod.maybe_span
+
+    def spy(tracer, name):
+        cm = real(tracer, name)
+        opened.append((name, cm))
+        return cm
+
+    monkeypatch.setattr(trace_mod, "maybe_span", spy)
+    dataset, _ = _tiny_dataset(tmp_path)
+    trainer = _tiny_trainer()
+    assert trainer.tracer is None
+    trainer.fit(dataset, epochs=2, batch_size=32)
+    names = [n for n, _ in opened]
+    assert names.count("epoch.fill") == names.count("epoch.drain") == 2
+    assert all(cm is trace_mod._NULL_CM for _, cm in opened)
+
+
+def test_the_harness_tracer_names_both_and_annotates_each_once(tmp_path):
+    """`benchmark/tracing.py`'s subclass, as it stands: the trainer's new
+    spans go through `Tracer.span`, so it names them and puts each event
+    in the capture once (not once by the base class and once by it)."""
+    import jax
+
+    from benchmark.tracing import AnnotatingTracer
+
+    t = trace_mod.install(AnnotatingTracer())
+    dataset, _ = _tiny_dataset(tmp_path)
+    trainer = _tiny_trainer()
+    with jax.profiler.trace(str(tmp_path / "dump")):
+        _fit(trainer, dataset, "fit_stream")
+    assert {"epoch.fill", "epoch.drain", "epoch.turn"} <= t.names
+    names = [e[0] for e in _captured_events(tmp_path / "dump")]
+    for name in ("epoch.fill", "epoch.drain"):
+        assert names.count(name) == 2
+        assert t.cumulative()[name]["count"] == 2
+    assert names.count("step.block") == 2
 
 
 # ---- CLI ----

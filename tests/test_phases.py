@@ -35,6 +35,11 @@ from shifu_tensorflow_tpu.obs.profile import (
 
 FIXTURE = os.path.join(os.path.dirname(__file__), "fixtures",
                        "wdl_criteo_stream_4steps.phases.json.gz")
+#: three epochs of the Nemotron cell on one TPU v5e (PR 34; its ``origin``
+#: key says which run and how the op line was merged)
+BOUNDARY_FIXTURE = os.path.join(
+    os.path.dirname(__file__), "fixtures",
+    "nemotron3_nano_ep16_stream_s4k_3epochs.boundaries.json.gz")
 
 
 # ---- the scopes in the program ----
@@ -386,7 +391,12 @@ def _hlo_metadata_plane(program: str, op_names: dict) -> bytes:
                   + _field(4, _field(1, 1) + _field(2, meta)))
 
 
-def _write_dump(tmp_path, *, program="jit_train_step(123)") -> str:
+def _write_dump(tmp_path, *, program="jit_train_step(123)",
+                between=False) -> str:
+    """Two steps, [0, 100) and [1000, 1100).  ``between`` puts an epoch's
+    boundary in the gap: a fetch that ends at 120, a fill of [200, 900)
+    with the put thread's first placement [300, 800) on a line of its
+    own, the next dispatch from 900."""
     ops = ["%fusion.2 = f32[8,4]{1,0} fusion(%p0), kind=kLoop",
            "%cond.4 = (f32[8]) conditional(%p1, %t, %f)",
            "%fusion.7 = f32[8] fusion(%p2), kind=kLoop",
@@ -401,10 +411,15 @@ def _write_dump(tmp_path, *, program="jit_train_step(123)") -> str:
         lines=[("XLA Modules", [(7, 0, 100), (7, 1000, 100)]),
                ("XLA Ops", events)],
         event_names=ops + [program])
+    main, put_thread = [(1, 0, 40), (2, 5, 10), (3, 50, 7)], []
+    if between:
+        main += [(4, 90, 30), (5, 200, 700), (1, 900, 150)]
+        put_thread = [(6, 300, 500)]
     host = _plane("/host:CPU",
-                  lines=[("main/1", [(1, 0, 40), (2, 5, 10), (3, 50, 7)])],
+                  lines=[("main/1", main), ("stpu-infeed-put/2", put_thread)],
                   event_names=["step.dispatch", "epoch.turn",
-                               "PjitFunction(train_step)"])
+                               "PjitFunction(train_step)", "step.block",
+                               "epoch.fill", "step.infeed.put"])
     names = {"fusion.2": "jit(train_step)/jvp(M)/embed.gather/gather",
              "cond.4": "jit(train_step)/optimizer.update/cond",
              "fusion.7": "jit(train_step)/optimizer.update/cond/"
@@ -467,6 +482,36 @@ def test_cli_profile_phases_prints_the_split(tmp_path):
     assert json.loads(out)["phases_ms"]["collective"] == pytest.approx(1e-5)
 
 
+def test_phases_and_the_cli_report_the_boundary_of_a_dump(tmp_path):
+    """The same dump with an epoch's boundary in the gap between its two
+    steps: 900 ns of idle, shared out by the span open over it."""
+    assert profile_mod.phases(_write_dump(tmp_path / "a"))["boundaries"] == {}
+    dump = _write_dump(tmp_path / "b", between=True)
+    found = profile_mod.phases(dump)["boundaries"]
+    assert found["boundaries"] == 1 and found["devices"] == 1
+    assert found["gap_ms"]["median"] == found["idle_ms"]["median"] == (
+        pytest.approx(900 / 1e6))
+    assert {k: round(v["median"] * 1e6)
+            for k, v in found["idle_split_ms"].items()} == {
+        "step.infeed.put": 500, "epoch.fill": 200, "step.dispatch": 100,
+        "(no span)": 80, "step.block": 20}
+    assert found["first_ms"] == {"step.infeed.put": {
+        "median": pytest.approx(500 / 1e6), "max": pytest.approx(500 / 1e6)}}
+    rc, out, _ = _cli("profile", "--phases", dump)
+    assert rc == 0
+    assert "between epochs: 1 boundary" in out
+    assert re.search(r"step\.infeed\.put\s+0\.001\s+0\.001", out)  # 500 ns
+    assert "an epoch's first step.infeed.put" in out
+    assert "the window's edges" in out
+    rc, out, _ = _cli("profile", "--phases", dump, "--json")
+    assert rc == 0
+    assert json.loads(out)["boundaries"]["idle_ms"]["sum"] == pytest.approx(
+        900 / 1e6)
+    # a dump with no boundary prints no such block
+    rc, out, _ = _cli("profile", "--phases", _write_dump(tmp_path / "c"))
+    assert rc == 0 and "between epochs" not in out
+
+
 @pytest.mark.parametrize("as_json", [False, True])
 def test_cli_profile_phases_exits_1_without_a_step_program(tmp_path, as_json):
     dump = _write_dump(tmp_path, program="jit_eval_step(5)")
@@ -481,3 +526,177 @@ def test_cli_profile_phases_exits_1_without_a_step_program(tmp_path, as_json):
 def test_cli_profile_without_journal_or_phases_is_a_usage_error():
     rc, _, err = _cli("profile")
     assert rc == 2 and "--journal" in err
+
+
+# ---- between epochs ----
+
+def _three_epochs(second_device=False) -> dict:
+    """Three epochs of two 100 ns steps on one device inside a
+    ``bench.window`` of [40, 1000): boundary 1 is the gap [300, 400) with a
+    10 ns program of another kind inside it, boundary 2 the gap [600, 760);
+    the third epoch's fetch has no step after it.  The put thread's spans
+    lie inside the consumer's wait for them."""
+    steps = [[100, 100], [200, 100], [400, 100], [500, 100], [760, 100],
+             [860, 100]]
+    ops = [["fusion.1", "", s, d] for s, d in steps if s != 500]
+    ops += [["fusion.1", "", 500, 40], ["fusion.1", "", 550, 50],
+            ["convert.3", "", 330, 10]]
+    host = [
+        ["bench.window", 40, 960],
+        # the first epoch's fill, before the first step: the window's edge
+        ["epoch.fill", 45, 30], ["step.host.produce", 50, 10],
+        ["step.infeed.put", 61, 9],
+        ["step.host.produce", 110, 4], ["step.infeed.put", 115, 2],
+        # boundary 1
+        ["epoch.drain", 290, 45], ["step.block", 295, 35],
+        ["epoch.turn", 336, 14],
+        ["epoch.fill", 352, 44], ["step.infeed.wait", 354, 41],
+        ["step.host.produce", 356, 14], ["step.infeed.put", 371, 21],
+        ["step.dispatch", 396, 6],
+        ["step.host.produce", 410, 4], ["step.infeed.put", 415, 3],
+        # boundary 2
+        ["epoch.drain", 598, 42], ["step.block", 601, 29],
+        ["epoch.turn", 640, 20],
+        ["epoch.fill", 660, 90], ["step.infeed.wait", 662, 87],
+        ["step.host.produce", 664, 16], ["step.infeed.put", 681, 64],
+        ["step.dispatch", 750, 20],
+        ["step.host.produce", 770, 4], ["step.infeed.put", 775, 2],
+        # the last epoch's fetch: no step follows it
+        ["epoch.drain", 950, 45], ["step.block", 955, 35],
+    ]
+    devices = {"0": {"steps": steps, "ops": ops}}
+    if second_device:  # the same steps, nothing else in boundary 1
+        devices["1"] = {"steps": steps,
+                        "ops": [o for o in ops if o[0] != "convert.3"]}
+    return {"devices": devices, "host": host}
+
+
+def test_boundaries_split_a_gaps_idle_to_the_nanosecond():
+    out = profile_mod.boundaries(_three_epochs())
+    assert out["boundaries"] == 2 and out["devices"] == 1
+    first, second = out["each"]
+    ns = 1e-6  # the report is in ms
+    assert first["gap_ms"] == pytest.approx(100 * ns)
+    assert first["idle_ms"] == pytest.approx(90 * ns)  # less convert.3
+    assert {k: round(v / ns) for k, v in first["split_ms"].items()} == {
+        "step.block": 30, "step.infeed.put": 21, "step.host.produce": 14,
+        "epoch.turn": 10, "step.infeed.wait": 6, "step.dispatch": 4,
+        "epoch.fill": 3, "(no span)": 2}
+    assert second["gap_ms"] == second["idle_ms"] == pytest.approx(160 * ns)
+    assert {k: round(v / ns) for k, v in second["split_ms"].items()} == {
+        "step.infeed.put": 64, "step.block": 29, "epoch.turn": 20,
+        "step.host.produce": 16, "epoch.drain": 11, "step.dispatch": 10,
+        "step.infeed.wait": 7, "epoch.fill": 3}
+    for b in out["each"]:
+        assert sum(b["split_ms"].values()) == pytest.approx(b["idle_ms"])
+    assert out["gap_ms"] == {"median": pytest.approx(130 * ns),
+                             "max": pytest.approx(160 * ns)}
+    assert out["idle_ms"] == {"median": pytest.approx(125 * ns),
+                              "max": pytest.approx(160 * ns),
+                              "sum": pytest.approx(250 * ns)}
+    assert list(out["idle_split_ms"])[0] == "step.infeed.put"
+    assert out["idle_split_ms"]["step.infeed.put"] == {
+        "median": pytest.approx(42.5 * ns), "max": pytest.approx(64 * ns)}
+    assert out["idle_split_ms"]["epoch.drain"]["median"] == pytest.approx(
+        5.5 * ns)
+
+
+def test_boundaries_keep_the_windows_edges_and_the_steps_holes_apart():
+    """``bench.window`` holds every step: it is the window, not a span a
+    gap is named after.  The idle of the whole window is the boundaries'
+    plus the two edges' plus what is inside and between the steps."""
+    out = profile_mod.boundaries(_three_epochs())
+    ns = 1e-6
+    assert "bench.window" not in out["idle_split_ms"]
+    assert out["edges_ms"] == {
+        "open": {"gap": pytest.approx(60 * ns), "idle": pytest.approx(60 * ns)},
+        "close": {"gap": pytest.approx(40 * ns),
+                  "idle": pytest.approx(40 * ns)}}
+    assert out["in_steps_idle_ms"] == pytest.approx(10 * ns)  # [540, 550)
+    busy = 5 * 100 + 90 + 10
+    assert (out["idle_ms"]["sum"] + out["edges_ms"]["open"]["idle"]
+            + out["edges_ms"]["close"]["idle"] + out["in_steps_idle_ms"]
+            ) == pytest.approx((960 - busy) * ns)
+    # without a span around the steps the window is the events' own extent
+    bare = _three_epochs()
+    bare["host"] = [h for h in bare["host"] if h[0] != "bench.window"]
+    out = profile_mod.boundaries(bare)
+    assert out["edges_ms"]["open"]["gap"] == pytest.approx(55 * ns)
+    assert out["edges_ms"]["close"]["gap"] == pytest.approx(35 * ns)
+
+
+def test_boundaries_tell_an_epochs_first_batch_from_the_others():
+    out = profile_mod.boundaries(_three_epochs())
+    ns = 1e-6
+    assert out["first_ms"] == {
+        "step.host.produce": {"median": pytest.approx(15 * ns),
+                              "max": pytest.approx(16 * ns)},
+        "step.infeed.put": {"median": pytest.approx(42.5 * ns),
+                            "max": pytest.approx(64 * ns)}}
+    # the window's own first batch (before the first step) is neither
+    assert out["steady_ms"] == {"step.host.produce": pytest.approx(4 * ns),
+                                "step.infeed.put": pytest.approx(2 * ns)}
+
+
+def test_boundaries_are_per_device_then_the_median_over_devices():
+    out = profile_mod.boundaries(_three_epochs(second_device=True))
+    ns = 1e-6
+    assert out["devices"] == 2 and out["boundaries"] == 2
+    # device 0 idles 90 and 160, device 1 100 and 160
+    assert out["idle_ms"]["median"] == pytest.approx((125 + 130) / 2 * ns)
+    assert out["idle_ms"]["sum"] == pytest.approx((250 + 260) / 2 * ns)
+    assert out["each"][0]["idle_ms"] == pytest.approx(90 * ns)
+
+
+def test_a_capture_of_one_epoch_has_no_boundary():
+    one = _three_epochs()
+    one["devices"]["0"]["steps"] = one["devices"]["0"]["steps"][:2]
+    one["devices"]["0"]["ops"] = [o for o in one["devices"]["0"]["ops"]
+                                  if o[2] < 300]
+    assert profile_mod.boundaries(one) == {}
+    # nor has one whose gaps hold no fetch's end, or no step at all
+    no_block = _three_epochs()
+    no_block["host"] = [h for h in no_block["host"] if h[0] != "step.block"]
+    assert profile_mod.boundaries(no_block) == {}
+    assert profile_mod.boundaries({"devices": {}, "host": []}) == {}
+    assert profile_mod.boundaries({"devices": {"0": {"steps": [], "ops": []}},
+                                   "host": [["step.block", 0, 9]]}) == {}
+
+
+def test_recorded_boundaries_of_three_epochs_on_the_chip():
+    """What the chip recorded (a traced run under ``--obs``): two
+    boundaries, each one's idle shared out whole, ``(no span)`` a hundredth
+    of it, and the two populations ``infeed_put_ms`` averages told apart:
+    an epoch's first placement 14 and 128 ms, the others 1.4."""
+    with gzip.open(BOUNDARY_FIXTURE, "rt") as f:
+        recorded = json.load(f)
+    assert "seed 3400000102" in recorded["origin"]
+    out = profile_mod.boundaries(recorded)
+    assert out["boundaries"] == 2 and out["devices"] == 1
+    assert [round(b["idle_ms"], 3) for b in out["each"]] == [39.646, 162.648]
+    for b in out["each"]:
+        assert b["gap_ms"] == b["idle_ms"]  # nothing else ran in the gap
+        assert sum(b["split_ms"].values()) == pytest.approx(b["idle_ms"])
+        assert b["split_ms"]["(no span)"] < 0.02 * b["idle_ms"]
+        # the drain outside the fetch and the fill outside the wait are
+        # the consumer thread's own work: under a millisecond
+        assert b["split_ms"]["epoch.drain"] < 0.5
+        assert b["split_ms"]["epoch.fill"] < 1.0
+    assert list(out["idle_split_ms"])[:3] == [
+        "step.infeed.put", "step.host.produce", "step.block"]
+    assert out["idle_ms"]["sum"] == pytest.approx(202.293825)
+    first, steady = out["first_ms"], out["steady_ms"]
+    assert first["step.infeed.put"]["max"] == pytest.approx(128.354575)
+    assert first["step.infeed.put"]["median"] > 50 * steady["step.infeed.put"]
+    assert first["step.host.produce"]["median"] > 100 * steady[
+        "step.host.produce"]
+    # the window: 48 steps, two boundaries, two edges, the launch gaps
+    window = next(h for h in recorded["host"] if h[0] == "bench.window")
+    steps = recorded["devices"]["0"]["steps"]
+    assert len(steps) == 48
+    assert (sum(d for _, d in steps) / 1e6 + out["idle_ms"]["sum"]
+            + out["edges_ms"]["open"]["idle"]
+            + out["edges_ms"]["close"]["idle"] + out["in_steps_idle_ms"]
+            ) == pytest.approx(window[2] / 1e6)
+    # the same lists reduce as a capture's do
+    assert reduce_phases(recorded)["host_spans"]["epoch.fill"]["count"] == 3
