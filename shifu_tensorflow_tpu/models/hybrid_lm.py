@@ -5,8 +5,13 @@ string as the public ``nemotron_h`` configuration writes it (a ``mellum``
 or ``laguna`` configuration's block, attention then feed-forward, is two
 such layers: config/model_config.py ``HybridLMConfig``):
 
-- ``M``  Mamba-2 (``ssm.conv`` + ``ssm.scan``: ops/ssm_scan.py's chunked
-  scan), gate before the grouped RMSNorm;
+- ``M``  Mamba-2 (``ssm.conv`` + ``ssm.scan``: the chunked scan by the
+  path :func:`chunked_scan` picks: ops/ssm_scan.py's expression, or,
+  where the static shapes say so (ops/pallas/ssd_scan.py ``ssd_pays``)
+  and the program is lowered for the TPU, one kernel a direction that
+  keeps a chunk's mask and the running state in VMEM; which one a
+  compiled step holds is in its op names, ``ssd_scan_fwd`` and
+  ``ssd_scan_bwd``), gate before the grouped RMSNorm;
 - ``E``  routed experts: a score over ALL ``n_routed_experts`` (``sigmoid``
   with top-k of score + correction bias, or ``softmax`` with top-k of the
   score), weights normalised over the k chosen and scaled; the expert
@@ -75,6 +80,7 @@ from shifu_tensorflow_tpu.config.model_config import (
 )
 from shifu_tensorflow_tpu.ops import grouped, ssm_scan
 from shifu_tensorflow_tpu.ops.pallas import rope as rope_kernel
+from shifu_tensorflow_tpu.ops.pallas import ssd_scan as ssd_kernel
 
 #: rows of a grouped-product tile.  A tile costs its rows' products or the
 #: read of its expert's weights, whichever is longer (2688 x 1856 float32
@@ -168,7 +174,28 @@ class ConvParams(nn.Module):
         return kernel, bias
 
 
+def chunked_scan(x, dt, a, b, c, chunk: int):
+    """``ssm_scan.ssm_scan_chunked`` by the path the static shapes pick:
+    where ``ssd_kernel.ssd_pays`` (float32, a chunk and a state of whole
+    128-lane registers, a group's heads filling whole registers, a
+    sequence of whole chunks) a program lowered for the TPU gets the
+    kernels, which keep a chunk's mask and the running state in VMEM;
+    every other program, and every other shape or dtype, the
+    expression."""
+    heads, groups = x.shape[2], b.shape[2]
+    if not ssd_kernel.ssd_pays(chunk, x.shape[3], heads // groups,
+                               b.shape[3], x.dtype, x.shape[1]):
+        return ssm_scan.ssm_scan_chunked(x, dt, a, b, c, chunk)
+    return jax.lax.platform_dependent(
+        x, dt, a, b, c,
+        tpu=lambda *v: ssd_kernel.ssd_scan(*v, chunk),
+        default=lambda *v: ssm_scan.ssm_scan_chunked(*v, chunk))
+
+
 class MambaMixer(nn.Module):
+    """One ``M`` layer's mixer; its scan is :func:`chunked_scan`'s (the
+    expression or the kernels, by static shapes and platform)."""
+
     cfg: HybridLMConfig
     dtype: Any = jnp.float32
 
@@ -206,8 +233,7 @@ class MambaMixer(nn.Module):
         d_skip = self.param("D", nn.initializers.ones, (heads,), jnp.float32)
         with jax.named_scope("ssm.scan"):
             dt = nn.softplus(dt + dt_bias.astype(dt_))
-            y = ssm_scan.ssm_scan_chunked(
-                xs, dt, -jnp.exp(a_log), b, cc, c.chunk_size)
+            y = chunked_scan(xs, dt, -jnp.exp(a_log), b, cc, c.chunk_size)
             y = y.astype(dt_) + d_skip.astype(dt_)[:, None] * xs
         with jax.named_scope("ssm.proj"):
             y = y.reshape(bsz, s, inner) * nn.silu(z)

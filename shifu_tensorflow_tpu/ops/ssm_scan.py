@@ -1,5 +1,15 @@
 """Chunked state-space scan (the Mamba-2 "SSD" form) in plain ``jax.numpy``.
 
+Who runs it: every program but one.  ``models/hybrid_lm.py``
+``chunked_scan`` hands a program lowered for the TPU, at float32 and
+shapes of whole 128-lane registers (``ops/pallas/ssd_scan.py``
+``ssd_pays``: the Nemotron cell's), to the kernels of that module, which
+do this arithmetic with the chunk kept in VMEM; every program on the CPU,
+``--dtype bfloat16``, a head of 8, a state of 16, a chunk of 7 … 64 and a
+sequence the chunk does not divide run the expression below.  It is also
+the kernels' oracle: ``tests/test_ssd_kernel.py`` holds their ``y`` and
+five gradients to it.
+
 The recurrence, per head with state ``H`` (p, n)::
 
     H_t = exp(dt_t * a) * H_{t-1} + dt_t * x_t (x) B_t        y_t = H_t C_t
@@ -19,10 +29,11 @@ run one step at a time is S sequential element-wise passes over the state
 - the entering state's share of the output is one more product,
   ``exp(A_l) C_l H_in``.
 
-The backward pass is JAX's transpose of exactly these products (no
-custom rule): the decay exponents are masked to ``-inf`` *before* the
-``exp``, so a masked entry is 0 with gradient 0 and no ``inf * 0`` can
-arise; the chunk recurrence transposes into the reverse scan over chunks.
+The backward pass of THIS path is JAX's transpose of exactly these
+products (no custom rule; the kernels bring their own): the decay
+exponents are masked to ``-inf`` *before* the ``exp``, so a masked entry
+is 0 with gradient 0 and no ``inf * 0`` can arise; the chunk recurrence
+transposes into the reverse scan over chunks.
 A sequence that the chunk does not divide is padded with ``dt = 0`` steps
 (decay 1, no input: the state passes through) and the rows are dropped.
 

@@ -6,12 +6,31 @@ tested without TPU hardware.
 """
 
 import os
+import signal
 import sys
 
 # keep XLA/CPU math deterministic-ish and quiet in tests
 os.environ.setdefault("TF_CPP_MIN_LOG_LEVEL", "2")
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+# XLA:CPU builds the tests' programs without LLVM's optimisation passes, in
+# this process and in every child that inherits XLA_FLAGS (the flag rides
+# it beside the device count; the fleet and rehearsal tests hand their
+# children an XLA_FLAGS of their own, and those keep the default: the kill
+# drills of tests/test_spmd.py time their kill by a worker's compile).
+# Most of a run's CPU time is compiling small programs that then run once:
+# at level 0 the whole of tests/ takes 3,550 CPU-seconds for 4,230 and
+# counts the same passes, which keeps the run inside the driver's time
+# limit at the pace of three cores, the pace its runs have gone at.  What
+# the tests compare is arithmetic, not code generation; the compiler the
+# programs ship on is the TPU's, and tests/test_tpu_compile.py asks that
+# one (the scan's compile for the v5e is the same text with the flag and
+# without).
+if "xla_backend_optimization_level" not in os.environ.get("XLA_FLAGS", ""):
+    os.environ["XLA_FLAGS"] = (
+        os.environ.get("XLA_FLAGS", "")
+        + " --xla_backend_optimization_level=0").strip()
 
 # force CPU: tests run on the virtual 8-device CPU mesh, whatever the host
 # has.  (Plugins like jaxtyping may import jax before this conftest runs,
@@ -32,6 +51,43 @@ jax.config.update("jax_enable_compilation_cache", False)
 
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
+
+
+#: seconds one test may take, set-up and tear-down included.  The slowest
+#: takes 100 s on eight idle cores and 170 s on three; a test still running
+#: after this is waiting on something that will not come (a child blocked
+#: on a full pipe, a peer that died), and under the driver's one time limit
+#: for the whole run it would take every test behind it down with it.
+TEST_TIME_LIMIT_S = 600
+
+
+class OutOfTime(BaseException):
+    """Not an ``Exception``: a test's own ``except Exception`` must not
+    swallow it."""
+
+
+def _out_of_time(signum, frame):
+    raise OutOfTime(f"still running after {TEST_TIME_LIMIT_S} s")
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_protocol(item, nextitem):
+    """Fail a test that outlives ``TEST_TIME_LIMIT_S`` where it stands and
+    go on with the next: an alarm raises in the main thread, which reaches
+    a test stuck in a blocking read, a ``wait``, a ``sleep`` or a lock (the
+    ways a test waits for a child or a thread; its fixtures then tear
+    down).  The process is not ended: under xdist's ``loadfile`` a worker
+    that dies hands its file, stuck test first, to the next worker."""
+    try:
+        before = signal.signal(signal.SIGALRM, _out_of_time)
+    except ValueError:  # not the main thread: no alarm to set
+        return (yield)
+    signal.setitimer(signal.ITIMER_REAL, TEST_TIME_LIMIT_S)
+    try:
+        return (yield)
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, before)
 
 
 def pytest_configure(config):

@@ -414,11 +414,14 @@ def test_the_flash_kernels_read_the_rotated_heads_where_they_lie(
 # ----------------------------------------------------- the flagship step
 
 
-def _step_and_shapes(mc, columns, mesh=None, with_grad_norm=False):
+def _step_and_shapes(mc, columns, mesh=None, with_grad_norm=False,
+                     model=None):
     """The trainer's own step body and the abstract TrainState / batch it
     takes at ``mc.batch_size`` rows — built the way ``Trainer.__init__``
-    builds them, minus everything that needs a device."""
-    from shifu_tensorflow_tpu.models.factory import build_model
+    builds them, minus everything that needs a device.  ``model`` where
+    ``build_model`` would pick by ``jax.default_backend()``, which is the
+    CPU here."""
+    from shifu_tensorflow_tpu.models.factory import build_model, family_loss
     from shifu_tensorflow_tpu.train.optimizers import make_optimizer
     from shifu_tensorflow_tpu.train.trainer import (
         TrainState,
@@ -426,7 +429,9 @@ def _step_and_shapes(mc, columns, mesh=None, with_grad_norm=False):
     )
 
     sharded = mesh is not None and mesh.shape.get("model", 1) > 1
-    model = build_model(mc, columns, shard_embeddings=sharded, mesh=mesh)
+    if model is None:
+        model = build_model(mc, columns, shard_embeddings=sharded,
+                            mesh=mesh)
     tx = make_optimizer(mc.params)
 
     def init():
@@ -441,7 +446,8 @@ def _step_and_shapes(mc, columns, mesh=None, with_grad_norm=False):
              "y": jax.ShapeDtypeStruct((rows, 1), jnp.float32),
              "w": jax.ShapeDtypeStruct((rows, 1), jnp.float32)}
     body = make_train_step_body(model.apply, "mse", mc.params.l2_reg,
-                                with_grad_norm=with_grad_norm)
+                                with_grad_norm=with_grad_norm,
+                                batch_loss=family_loss(model))
     return body, jax.eval_shape(init), batch
 
 
