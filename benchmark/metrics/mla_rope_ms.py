@@ -1,0 +1,22 @@
+"""``mla_rope_ms`` — layer: models models/ ops/.  Unit ``ms``, source
+``device_trace``; should move ``train_rows_per_s``.
+
+Device ms a step inside ``attn.rope``, forward + backward summed (the
+backward's recomputed forward included): the rotation of the 64 rotary
+dimensions of every query head and of the one rotary key, on the main
+blocks.  From
+``obs.profile.phases`` on the run's own capture, handed on by the plane;
+``None`` on a reading without the phase or of another configuration's
+kind.
+"""
+
+LAYER = "models models/ ops/"
+UNIT = "ms"
+SOURCE = "device_trace"
+MOVES = "train_rows_per_s"
+
+from benchmark.mla_lm_readings import mla_phase_ms
+
+
+def read(r):
+    return mla_phase_ms(r, "attn.rope")

@@ -57,7 +57,12 @@ def require_servable(model_type: str, plane: str) -> None:
 #: ``layer_types`` / ``mlp_layer_types`` entries -> pattern characters
 _ATTENTION_KINDS = {"full_attention": "*", "sliding_attention": "W"}
 _MLP_KINDS = {"sparse": "E", "dense": "D"}
-_PATTERN_LAYER_TYPES = {v: k for k, v in _ATTENTION_KINDS.items()}
+#: a pattern's attention characters -> the layer type whose heads and rotary
+#: they take; ``L`` (latent attention) is no ``layer_types`` entry: its
+#: family has no such list (``HybridLMConfig.latent_family``)
+LATENT = "latent_attention"
+_PATTERN_LAYER_TYPES = {**{v: k for k, v in _ATTENTION_KINDS.items()},
+                        "L": LATENT}
 
 
 @dataclass(frozen=True)
@@ -142,7 +147,23 @@ class HybridLMConfig:
       shared expert of ``shared_expert_intermediate_size`` (with
       ``hidden_act: silu`` the shared expert takes the experts' form).
       ``gating: true`` is read as that gated feed-forward and adds
-      nothing further (a gate on attention's output is not implemented).
+      nothing further (a gate on attention's output is not implemented);
+    - ``glm4_moe_lite`` (the DeepSeek-V3 block): no list of layers at all.
+      ``kv_lora_rank`` marks the family (:meth:`latent_family`): every
+      block's attention is latent (``L``: ``q_lora_rank`` / ``kv_lora_rank``
+      low-rank paths with an RMSNorm on each latent, ``qk_nope_head_dim`` +
+      ``qk_rope_head_dim`` a query and key head of which the second part
+      turns and, on the key, is one for all heads, ``v_head_dim`` a value
+      head), the first ``first_k_dense_replace`` of ``num_hidden_layers``
+      blocks' feed-forward dense (``D``) and the others' sparse (``E``),
+      ``topk_method: noaux_tc`` (sigmoid scores, top-k of score +
+      correction bias), the shared expert ``n_shared_experts`` x
+      ``moe_intermediate_size`` wide, rotary from ``rope_theta`` with
+      ``rope_scaling: null``, and ``num_nextn_predict_layers`` multi-token
+      prediction modules (0 or 1: one more block of the last block's kinds
+      over ``[RMSNorm(h_i) ; RMSNorm(Emb(t_{i+1}))] W_m``, its own final
+      norm, the shared head; the step's loss is the next-token loss +
+      ``mtp_loss_weight`` x the module's, which is no public key).
 
     ``n_routed_experts`` is the router's width (all the experts there
     are); ``experts_held`` = (first id, count) the experts whose weights
@@ -200,6 +221,15 @@ class HybridLMConfig:
     sliding_window: int = 0  # keys a ``W`` layer's query sees, itself one
     #: ((layer type, RopeParameters), ...); a type without one: no rotary
     rope_parameters: tuple = ()
+    # latent attention (an ``L`` layer)
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # multi-token prediction
+    num_nextn_predict_layers: int = 0
+    mtp_loss_weight: float = 0.3
 
     #: public spellings of the same number
     ALIASES = (("num_experts", "n_routed_experts"),
@@ -265,6 +295,48 @@ class HybridLMConfig:
             for kind, r in entries.items()))
 
     @staticmethod
+    def latent_family(params: Mapping[str, Any]) -> dict:
+        """What a configuration of the latent-attention family
+        (``kv_lora_rank`` among its keys) says without the keys the other
+        shapes say it with: the pattern from ``num_hidden_layers`` and
+        ``first_k_dense_replace``, the rotary entry from ``rope_theta``,
+        the shared expert's width from ``moe_intermediate_size``."""
+        if "layer_types" in params:
+            raise ValueError(
+                "kv_lora_rank beside layer_types: latent attention is the "
+                "attention of every block of its family, which has no "
+                "layer_types")
+        for key, only in (("rope_scaling", None), ("topk_method", "noaux_tc"),
+                          ("partial_rotary_factor", 1)):
+            if params.get(key, only) != only:
+                raise ValueError(
+                    f"{key}={params[key]!r} is not implemented with latent "
+                    f"attention ({only!r})")
+        if "num_hidden_layers" not in params:
+            raise ValueError(
+                "latent attention needs num_hidden_layers (and "
+                "first_k_dense_replace) for its pattern")
+        blocks = int(params["num_hidden_layers"])
+        dense = int(params.get("first_k_dense_replace", 0))
+        if not 0 <= dense <= blocks:
+            raise ValueError(
+                f"first_k_dense_replace={dense} of num_hidden_layers="
+                f"{blocks}")
+        said = {"hybrid_override_pattern":
+                "LD" * dense + "LE" * (blocks - dense),
+                "rope_parameters": {
+                    LATENT: {"rope_theta": params.get("rope_theta", 10000.0)}}}
+        if "moe_intermediate_size" in params:
+            said["moe_shared_expert_intermediate_size"] = params[
+                "moe_intermediate_size"]
+        for key, value in said.items():
+            if params.get(key, value) != value:
+                raise ValueError(
+                    f"{key}={params[key]!r} but the latent-attention "
+                    f"family's keys give {value!r}")
+        return said
+
+    @staticmethod
     def pattern_of(params: Mapping[str, Any]) -> str:
         """``layer_types`` + ``mlp_layer_types`` as a pattern string."""
         kinds = [str(k) for k in params["layer_types"]]
@@ -300,6 +372,9 @@ class HybridLMConfig:
                         f"{params[ours]} name the same number")
                 params.setdefault(ours, params[public])
         blocks = None
+        if "kv_lora_rank" in params:
+            params.update(cls.latent_family(params))
+            blocks = int(params["num_hidden_layers"])
         if "layer_types" in params:
             pattern = cls.pattern_of(params)
             if params.get("hybrid_override_pattern", pattern) != pattern:
@@ -346,12 +421,13 @@ class HybridLMConfig:
     def validate(self, params: Mapping[str, Any] = (),
                  blocks: "int | None" = None) -> None:
         pattern = self.hybrid_override_pattern
-        bad = set(pattern) - set("MEDW*")
+        bad = set(pattern) - set("MEDW*L")
         if bad or not pattern:
             raise ValueError(
                 "hybrid_override_pattern is a string of M (Mamba-2), E "
-                "(experts), D (dense gated feed-forward), * (attention) "
-                f"and W (attention inside sliding_window); got {sorted(bad)}")
+                "(experts), D (dense gated feed-forward), * (attention), "
+                "W (attention inside sliding_window) and L (latent "
+                f"attention); got {sorted(bad)}")
         layers = params.get("num_hidden_layers") if params else None
         expect = len(pattern) if blocks is None else blocks
         if layers is not None and int(layers) != expect:
@@ -409,18 +485,35 @@ class HybridLMConfig:
         if "W" in pattern and self.sliding_window <= 0:
             raise ValueError(
                 "a W (sliding_attention) layer needs sliding_window > 0")
+        if "L" in pattern:
+            self.validate_latent()
         unknown = sorted(set(dict(self.rope_parameters))
-                         - set(_ATTENTION_KINDS))
+                         - set(_PATTERN_LAYER_TYPES.values()))
         if unknown:
             raise ValueError(
                 f"rope_parameters for {unknown}: the layer types are "
                 f"{' | '.join(_ATTENTION_KINDS)}")
         for kind, rope in self.rope_parameters:
-            turning = rope.rotary_dim(self.head_dim)
+            of = self.qk_rope_head_dim if kind == LATENT else self.head_dim
+            turning = rope.rotary_dim(of)
             if turning % 2 or not turning:
                 raise ValueError(
                     f"rotary positions need an even number of dimensions: "
-                    f"{kind} turns {turning} of head_dim={self.head_dim}")
+                    f"{kind} turns {turning} of {of}")
+        if self.num_nextn_predict_layers not in (0, 1):
+            raise ValueError(
+                f"num_nextn_predict_layers={self.num_nextn_predict_layers}: "
+                "one multi-token prediction module is implemented (0 | 1)")
+        if self.num_nextn_predict_layers and (
+                len(pattern) < 2 or pattern[-2] not in "*WL"
+                or pattern[-1] not in "ED"):
+            raise ValueError(
+                "a multi-token prediction module is one more block of the "
+                "last block's kinds, attention then feed-forward; the "
+                f"pattern ends in {pattern[-2:]!r}")
+        if self.mtp_loss_weight < 0:
+            raise ValueError(
+                f"mtp_loss_weight={self.mtp_loss_weight} is negative")
         if self.mamba_num_heads % self.n_groups:
             raise ValueError("n_groups must divide mamba_num_heads")
         for heads in (self.num_attention_heads,
@@ -429,6 +522,36 @@ class HybridLMConfig:
                 raise ValueError(
                     f"num_key_value_heads={self.num_key_value_heads} must "
                     f"divide a layer's query heads ({heads})")
+
+    def validate_latent(self) -> None:
+        """What an ``L`` layer needs: both low-rank paths, a part of the
+        head that turns, and a value head as wide as the query's and key's
+        (the attention cores take one head size)."""
+        for key in ("q_lora_rank", "kv_lora_rank", "qk_rope_head_dim",
+                    "v_head_dim"):
+            if getattr(self, key) <= 0:
+                raise ValueError(
+                    f"an L (latent attention) layer needs {key} > 0; got "
+                    f"{getattr(self, key)} (a query without its low-rank "
+                    "path is not implemented)")
+        if self.qk_nope_head_dim < 0:
+            raise ValueError(
+                f"qk_nope_head_dim={self.qk_nope_head_dim} is negative")
+        if self.qk_nope_head_dim + self.qk_rope_head_dim != self.v_head_dim:
+            raise ValueError(
+                f"qk_nope_head_dim + qk_rope_head_dim = "
+                f"{self.qk_nope_head_dim + self.qk_rope_head_dim} but "
+                f"v_head_dim = {self.v_head_dim}: one head size for "
+                "queries, keys and values is implemented")
+        if self.num_key_value_heads != self.num_attention_heads:
+            raise ValueError(
+                f"num_key_value_heads={self.num_key_value_heads} under "
+                f"latent attention's {self.num_attention_heads} heads: every "
+                "head has its own key and value there")
+        if self.rope_for("L") is None:
+            raise ValueError(
+                "an L (latent attention) layer needs rope_parameters for "
+                f"{LATENT}: the part of its head that turns carries position")
 
 
 @dataclass(frozen=True)

@@ -38,14 +38,26 @@ such layers: config/model_config.py ``HybridLMConfig``):
   float32) and the program is lowered for the TPU, one kernel pass a
   tensor that reads q or k as its projection leaves it and writes it
   head-major, where the flash kernels read it; which one a compiled
-  step holds is in its op names (``rope_lanes``).
+  step holds is in its op names (``rope_lanes``);
+- ``L``  causal latent attention (:class:`LatentAttentionMixer`): queries
+  and keys / values through low-rank latents with an RMSNorm on each, a
+  head's query and key of two parts of which the second turns and, on the
+  key, is one for every head; the core is the ``*`` layer's, over every
+  earlier key, at the head size the two parts make.
+
+Where ``num_nextn_predict_layers`` is 1 a multi-token prediction module
+(:class:`MTPModule`) reads the last block's output ``h_i`` (before the
+final norm) and the embedding of the next token, runs one more block of
+the last block's kinds over ``[RMSNorm(h_i) ; RMSNorm(Emb(t_{i+1}))] W_m``,
+its own final norm and the shared head against ``t_{i+2}``; the step's
+loss is the next-token loss + ``mtp_loss_weight`` x the module's.
 
 Ingest compatibility (as models/sequence.py): a PSV row carries its
 ``S`` token ids in the float32 feature block; the model casts them on
 device.  The loss is the family's own — mean next-token cross-entropy over
-the rows whose weight is not 0 — so the module exposes :meth:`loss`
-beside ``__call__`` (logits) and the trainer's step builders take it
-through ``models/factory.py`` ``family_loss``.  Every layer and the head
+the rows whose weight is not 0 — so the module exposes :meth:`losses`
+beside ``__call__`` (logits) and the trainer's step builders take
+:func:`batch_loss` through ``models/factory.py`` ``family_loss``.  Every layer and the head
 are rematerialised in the backward pass.
 
 Initialisation: normal, ``initializer_range`` for every matrix; the token
@@ -59,9 +71,15 @@ The phase names (``jax.named_scope``; obs/profile.py ``PHASE_SCOPES``):
 ``embed.gather``, ``ssm.proj`` (in/out projections, gate and grouped
 norm), ``ssm.conv``, ``ssm.scan``, ``moe.route``, ``moe.experts``,
 ``moe.shared``, ``mlp.dense`` (a ``D`` layer), ``attn.proj`` (q, k, v, o),
-``attn.rope`` (the rotation of q and k), ``attn.core`` (a ``*`` layer's
-core), ``attn.window`` (a ``W`` layer's), ``lm.head``.  The residual
-stream's own norms and adds carry no scope.
+``attn.rope`` (the rotation of q and k), ``attn.core`` (a ``*`` or ``L``
+layer's core), ``attn.window`` (a ``W`` layer's), ``attn.latent`` (an ``L``
+layer's projections onto its two latents and their norms), ``attn.expand``
+(the latents' projections up to heads), ``lm.head``; and around all of
+those, where there is the module, ``mtp.merge`` (two norms, the next
+token's embedding, ``W_m``), ``mtp.block`` and ``mtp.head`` (the first
+scope on an op's path names its phase, so the module's block and head
+pass are told from the main model's).  The residual stream's own norms
+and adds carry no scope.
 """
 
 from __future__ import annotations
@@ -474,6 +492,51 @@ class AttentionMixer(nn.Module):
                 y.reshape(bsz, s, nq * hd))
 
 
+class LatentAttentionMixer(nn.Module):
+    """One ``L`` layer's mixer, in the expanded form training computes:
+    ``c_q = RMSNorm(x W_qa)``, ``[q_n ; q_r] = c_q W_qb`` a head;
+    ``[c_kv ; k_r] = x W_kva``, ``[k_n ; v] = RMSNorm(c_kv) W_kvb`` a head;
+    ``q = [q_n ; rot(q_r)]``, ``k = [k_n ; rot(k_r)]`` with the one ``k_r``
+    for every head (the broadcast's transpose sums the heads' gradients);
+    ``attention`` over every earlier key; ``W_o``."""
+
+    cfg: HybridLMConfig
+    attention: Callable
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        c, dt_ = self.cfg, self.dtype
+        n, d_c = c.num_attention_heads, c.kv_lora_rank
+        d_n, d_r, d_v = c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim
+        bsz, s, _ = x.shape
+        std, eps = c.initializer_range, c.layer_norm_epsilon
+        with jax.named_scope("attn.latent"):
+            c_q = RMSNorm(eps, dt_, name="q_a_norm")(
+                Kernel(c.q_lora_rank, std, dt_, name="q_a_proj")(x))
+            kv = Kernel(d_c + d_r, std, dt_, name="kv_a_proj")(x)
+            c_kv = RMSNorm(eps, dt_, name="kv_a_norm")(kv[..., :d_c])
+            k_r = kv[..., d_c:].reshape(bsz, s, 1, d_r)
+        with jax.named_scope("attn.expand"):
+            q = Kernel(n * (d_n + d_r), std, dt_, name="q_b_proj")(
+                c_q).reshape(bsz, s, n, d_n + d_r)
+            kv = Kernel(n * (d_n + d_v), std, dt_, name="kv_b_proj")(
+                c_kv).reshape(bsz, s, n, d_n + d_v)
+        with jax.named_scope("attn.rope"):
+            cos, sin = rope_tables(c.rope_for("L"), s, d_r)
+            q_r = apply_rope(q[..., d_n:], cos, sin)
+            k_r = apply_rope(k_r, cos, sin)
+        with jax.named_scope("attn.core"):
+            q = jnp.concatenate([q[..., :d_n], q_r], axis=-1)
+            k = jnp.concatenate(
+                [kv[..., :d_n], jnp.broadcast_to(k_r, (bsz, s, n, d_r))],
+                axis=-1)
+            y = self.attention(q, k, kv[..., d_n:])
+        with jax.named_scope("attn.proj"):
+            return Kernel(c.hidden_size, c.output_std, dt_, name="o_proj")(
+                y.reshape(bsz, s, n * d_v))
+
+
 class Layer(nn.Module):
     kind: str
     cfg: HybridLMConfig
@@ -495,6 +558,9 @@ class Layer(nn.Module):
                 y = FeedForward(c.intermediate_size, c.initializer_range,
                                 c.output_std, self.dtype, True,
                                 name="mixer")(h)
+        elif self.kind == "L":
+            y = LatentAttentionMixer(self.cfg, self.attention, self.dtype,
+                                     name="mixer")(h)
         else:
             attention = (self.window_attention if self.kind == "W"
                          else self.attention)
@@ -527,10 +593,62 @@ class LMHead(nn.Module):
             return jnp.sum(nll * live[:, None]), jnp.mean(nll, axis=-1)
 
 
+def fold_stats(stats, s):
+    """The step's counters with one more expert layer's: pairs summed, the
+    largest held expert's kept."""
+    return jnp.stack([stats[0] + s[0], jnp.maximum(stats[1], s[1])])
+
+
+class MTPMerge(nn.Module):
+    """``[RMSNorm_h(h) ; RMSNorm_e(e)] W_m``: what the module's block
+    reads."""
+
+    cfg: HybridLMConfig
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, h, e):
+        c = self.cfg
+        h = RMSNorm(c.layer_norm_epsilon, self.dtype, name="hnorm")(h)
+        e = RMSNorm(c.layer_norm_epsilon, self.dtype, name="enorm")(e)
+        return Kernel(c.hidden_size, c.initializer_range, self.dtype,
+                      name="proj")(jnp.concatenate([h, e], axis=-1))
+
+
+class MTPModule(nn.Module):
+    """One multi-token prediction module: position ``i`` reads the main
+    model's ``h_i`` and the embedding ``e_i`` of token ``i + 1``, and
+    hands the shared head the final-normed output of one more block
+    (attention then feed-forward, the last block's kinds), causal over
+    ``i`` at positions ``i``.  Returns ``(hidden states, counters)``."""
+
+    cfg: HybridLMConfig
+    attention: Callable
+    dtype: Any = jnp.float32
+    window_attention: "Callable | None" = None
+
+    @nn.compact
+    def __call__(self, h, e):
+        c = self.cfg
+        stats = jnp.zeros((2,), jnp.int32)
+        with jax.named_scope("mtp.merge"):
+            u = nn.remat(MTPMerge)(c, self.dtype, name="merge")(h, e)
+        with jax.named_scope("mtp.block"):
+            for name, kind in zip(("attn", "ffn"),
+                                  c.hybrid_override_pattern[-2:]):
+                u, s = nn.remat(Layer)(kind, c, self.attention, self.dtype,
+                                       self.window_attention, name=name)(u)
+                stats = fold_stats(stats, s)
+        with jax.named_scope("mtp.head"):
+            return RMSNorm(c.layer_norm_epsilon, self.dtype,
+                           name="final_norm")(u), stats
+
+
 class HybridLM(nn.Module):
-    """``__call__(x)``: logits (B, S, vocab held).  ``loss(x, w)``: what the
-    trainer differentiates (see :func:`batch_loss`).  ``window_attention``
-    is the ``W`` layers' core (``attention`` inside ``sliding_window``)."""
+    """``__call__(x)``: logits (B, S, vocab held).  ``losses(x, w)``: what
+    the trainer's loss is made of (see :func:`batch_loss`).
+    ``window_attention`` is the ``W`` layers' core (``attention`` inside
+    ``sliding_window``)."""
 
     cfg: HybridLMConfig
     attention: Callable
@@ -549,30 +667,63 @@ class HybridLM(nn.Module):
         self.final_norm = RMSNorm(c.layer_norm_epsilon, self.dtype)
         self.lm_head = nn.remat(LMHead)(c.vocab_size, c.hidden_size,
                                         c.initializer_range, self.dtype)
+        if c.num_nextn_predict_layers:
+            self.mtp = MTPModule(c, self.attention, self.dtype,
+                                 self.window_attention)
 
-    def hidden(self, x):
-        """(final-normed hidden states (B, S, d), ids (B, S), counters)."""
+    def trunk(self, x):
+        """(the last block's output (B, S, d), ids (B, S), counters)."""
         ids = x.astype(jnp.int32)
         with jax.named_scope("embed.gather"):
             h = jnp.take(self.embed.embedding, ids, axis=0).astype(self.dtype)
         stats = jnp.zeros((2,), jnp.int32)
         for layer in self.layers:
             h, s = layer(h)
-            stats = jnp.stack([stats[0] + s[0], jnp.maximum(stats[1], s[1])])
-        return self.final_norm(h), ids, stats
+            stats = fold_stats(stats, s)
+        return h, ids, stats
 
     def __call__(self, x):
-        h, _, _ = self.hidden(x)
-        return self.lm_head.logits(h)
+        h, ids, _ = self.trunk(x)
+        if self.cfg.num_nextn_predict_layers and self.is_initializing():
+            self.mtp_hidden(h, ids)  # ``init`` builds its parameters too
+        return self.lm_head.logits(self.final_norm(h))
 
-    def loss(self, x, w):
-        """``(mean token loss over the live rows, per-row mean loss (B, 1),
-        counters)``: a row of weight 0 is padding and joins neither sum."""
-        h, ids, stats = self.hidden(x)
+    def mtp_hidden(self, h, ids):
+        """The module's final-normed hidden states (B, S - 1, d) from the
+        trunk's ``h``, and its counters.  All ``S`` positions go through
+        its block, the last with the row's first token for a next one;
+        position ``S - 1`` is dropped here, and ``S - 2``, whose second
+        next token the row does not hold, where the head is read: both are
+        causal, so neither reaches an earlier position."""
+        with jax.named_scope("mtp.merge"):
+            e = jnp.take(self.embed.embedding, jnp.roll(ids, -1, axis=1),
+                         axis=0).astype(self.dtype)
+        g, stats = self.mtp(h, e)
+        return g[:, :-1], stats
+
+    def mtp_logits(self, x):
+        """(B, S - 2, vocab held): position ``i`` scores token ``i + 2``."""
+        h, ids, _ = self.trunk(x)
+        return self.lm_head.logits(self.mtp_hidden(h, ids)[0][:, :-1])
+
+    def losses(self, x, w):
+        """``(next-token loss, the module's loss or None, per-row mean
+        next-token loss (B, 1), counters)``, each loss a mean over the live
+        rows' positions: a row of weight 0 is padding and joins no sum."""
+        h, ids, stats = self.trunk(x)
         live = (w.reshape(-1) != 0.0).astype(jnp.float32)
-        total, per_row = self.lm_head(h, ids, live)
+        total, per_row = self.lm_head(self.final_norm(h), ids, live)
         count = jnp.sum(live) * (ids.shape[1] - 1)
-        return total / jnp.maximum(count, 1.0), per_row[:, None], stats
+        main, ahead = total / jnp.maximum(count, 1.0), None
+        if self.cfg.num_nextn_predict_layers:
+            g, s = self.mtp_hidden(h, ids)
+            stats = fold_stats(stats, s)
+            with jax.named_scope("mtp.head"):
+                # the head's own shift by one, over tokens 1 .. S - 1
+                total, _ = self.lm_head(g, ids[:, 1:], live)
+            count = jnp.sum(live) * (ids.shape[1] - 2)
+            ahead = total / jnp.maximum(count, 1.0)
+        return main, ahead, per_row[:, None], stats
 
 
 #: what the counters of a step are called where they surface
@@ -583,10 +734,17 @@ def batch_loss(model: HybridLM):
     """``(params, batch) -> (loss, per-row loss, {counter: value})`` for
     the trainer's step builders.  The counters sum (pairs that chose a held
     expert) and take the largest (pairs on one held expert) over the
-    step's expert layers."""
+    step's expert layers, the multi-token prediction module's included;
+    with that module the two losses the step's loss is made of ride
+    beside them (``main_loss``, ``mtp_loss``)."""
     def fn(params, batch):
-        loss, per_row, stats = model.apply(
-            {"params": params}, batch["x"], batch["w"], method="loss")
-        return loss, per_row, dict(zip(COUNTER_NAMES, stats))
+        main, ahead, per_row, stats = model.apply(
+            {"params": params}, batch["x"], batch["w"], method="losses")
+        counters = dict(zip(COUNTER_NAMES, stats))
+        if ahead is None:
+            return main, per_row, counters
+        counters.update(main_loss=main, mtp_loss=ahead)
+        return (main + model.cfg.mtp_loss_weight * ahead, per_row,
+                counters)
 
     return fn

@@ -192,7 +192,8 @@ TABULAR_SCOPES = ("embed.hash", "embed.gather", "wide.cross", "deep.mlp",
 HYBRID_LM_SCOPES = ("embed.gather", "ssm.proj", "ssm.conv", "ssm.scan",
                     "moe.route", "moe.experts", "moe.shared", "mlp.dense",
                     "attn.proj", "attn.rope", "attn.core", "attn.window",
-                    "lm.head", "optimizer.update")
+                    "attn.latent", "attn.expand", "mtp.merge", "mtp.block",
+                    "mtp.head", "lm.head", "optimizer.update")
 PHASE_SCOPES = TABULAR_SCOPES + tuple(
     s for s in HYBRID_LM_SCOPES if s not in TABULAR_SCOPES)
 #: the per-step program's module name (``jax.jit`` of ``train_step``);

@@ -122,6 +122,30 @@ _FOUR_CELLS = "assert len(cells) == 4 and"
 _LAST_ELEVEN = '[m["name"] for m in BENCH["per_layer"]][-len(MIXED_METRICS):]'
 
 
+#: the line of tests/benchmark/test_bench_mixed_lm.py's
+#: ``test_the_sibling_cell_still_meets_what_it_met_but_the_count_of_cells``
+#: that wants the Mellum and Laguna cells to be the list's last two; PR 36
+#: added the sixth.  Same rule, same retirement; the test's other checks
+#: run in test_bench_mla_lm.py
+_LAST_TWO_CELLS = 'assert [w["name"] for w in BENCH["workloads"]][-2:] == ['
+
+
+#: the line of tests/benchmark/test_bench_boundary.py's
+#: ``test_the_entry_lists_the_one_cell_and_stands_at_the_end_of_the_list``
+#: (four cases) that wants the boundary entries to be the list's last; PR
+#: 36 appended ten.  Same rule, same retirement; the test's other checks
+#: run in test_bench_mla_lm.py
+_LAST_FOUR = "assert names[-len(BOUNDARY_METRICS):] == list(BOUNDARY_METRICS)"
+
+
+#: the line of tests/benchmark/test_bench_boundary.py's
+#: ``test_the_laguna_cells_entries_stand_as_they_stood`` that wants the
+#: Laguna cell's eleven to be the last before the list's last four; PR 36's
+#: ten come after those four.  Same rule, same retirement; the test's other
+#: checks run in test_bench_mla_lm.py
+_ELEVEN_BEFORE_FOUR = "assert before[-len(MIXED_METRICS):] == list(MIXED_METRICS)"
+
+
 def _holds(name: str, line: str) -> bool:
     path = os.path.join(os.path.dirname(__file__), "benchmark", name)
     with open(path) as f:
@@ -138,6 +162,9 @@ def pytest_collection_modifyitems(items):
     lists = _holds("conftest.py", _FOUR_CHIP_LISTS_ONLY)
     cells = _holds("test_bench_swa_lm.py", _FOUR_CELLS)
     last = _holds("test_bench_mixed_lm.py", _LAST_ELEVEN)
+    two = _holds("test_bench_mixed_lm.py", _LAST_TWO_CELLS)
+    four = _holds("test_bench_boundary.py", _LAST_FOUR)
+    eleven = _holds("test_bench_boundary.py", _ELEVEN_BEFORE_FOUR)
     for item in items:
         module = getattr(getattr(item, "module", None), "__name__", "")
         if (lists and module in ("test_bench_run", "test_bench_contract")
@@ -158,6 +185,27 @@ def pytest_collection_modifyitems(items):
                 raises=AssertionError, strict=False,
                 reason="tests/benchmark/test_bench_mixed_lm.py: "
                        + _LAST_ELEVEN + " (PERF.md section 7)"))
+        if (two and module == "test_bench_mixed_lm" and item.name == (
+                "test_the_sibling_cell_still_meets_what_it_met_but_the_"
+                "count_of_cells")):
+            item.add_marker(pytest.mark.xfail(
+                raises=AssertionError, strict=False,
+                reason="tests/benchmark/test_bench_mixed_lm.py: "
+                       + _LAST_TWO_CELLS + " ... (PERF.md section 7)"))
+        if (four and module == "test_bench_boundary"
+                and item.name.startswith(
+                    "test_the_entry_lists_the_one_cell_and_stands_at_the_"
+                    "end_of_the_list[")):
+            item.add_marker(pytest.mark.xfail(
+                raises=AssertionError, strict=False,
+                reason="tests/benchmark/test_bench_boundary.py: "
+                       + _LAST_FOUR + " (PERF.md section 7)"))
+        if (eleven and module == "test_bench_boundary" and item.name
+                == "test_the_laguna_cells_entries_stand_as_they_stood"):
+            item.add_marker(pytest.mark.xfail(
+                raises=AssertionError, strict=False,
+                reason="tests/benchmark/test_bench_boundary.py: "
+                       + _ELEVEN_BEFORE_FOUR + " (PERF.md section 7)"))
 
 
 @pytest.fixture(scope="session")

@@ -150,13 +150,64 @@ def _lm_step_text() -> str:
     return _STEP_TEXTS["lm"]
 
 
+#: the scopes only a latent-attention decoder with a multi-token
+#: prediction module has, and every scope its step has
+LATENT_ONLY = ("attn.latent", "attn.expand", "mtp.merge", "mtp.block",
+               "mtp.head")
+LATENT_SCOPES = ("embed.gather", "moe.route", "moe.experts", "moe.shared",
+                 "mlp.dense", "attn.proj", "attn.rope", "attn.core",
+                 *LATENT_ONLY, "lm.head", "optimizer.update")
+
+
+def _latent_lm_step_text() -> str:
+    """The compiled per-step program of a tiny ``hybrid_lm`` trainer under
+    the ``glm4_moe_lite`` keys: latent attention over a dense and a sparse
+    block, and the multi-token prediction module in the loss."""
+    if "latent_lm" not in _STEP_TEXTS:
+        from shifu_tensorflow_tpu.config.model_config import ModelConfig
+        from shifu_tensorflow_tpu.train.trainer import HealthConfig, Trainer
+
+        mc = ModelConfig.from_json({"train": {"params": {
+            "ModelType": "hybrid_lm", "Optimizer": "adam",
+            "LearningRate": 1e-3, "hidden_size": 32, "vocab_size": 64,
+            "num_hidden_layers": 2, "first_k_dense_replace": 1,
+            "intermediate_size": 48, "hidden_act": "silu",
+            "num_attention_heads": 2, "num_key_value_heads": 2,
+            "q_lora_rank": 12, "kv_lora_rank": 8, "qk_nope_head_dim": 12,
+            "qk_rope_head_dim": 4, "v_head_dim": 16, "rope_theta": 100.0,
+            "n_routed_experts": 4, "experts_held": [0, 2],
+            "num_experts_per_tok": 2, "moe_intermediate_size": 16,
+            "n_shared_experts": 1, "topk_method": "noaux_tc",
+            "num_nextn_predict_layers": 1}}})
+        trainer = Trainer(mc, 16, health=HealthConfig())
+        batch = {"x": np.ones((2, 16), np.float32),
+                 "y": np.ones((2, 1), np.float32),
+                 "w": np.ones((2, 1), np.float32)}
+        _STEP_TEXTS["latent_lm"] = trainer._path.step.lower(
+            trainer.state, batch).compile().as_text()
+    return _STEP_TEXTS["latent_lm"]
+
+
 @pytest.mark.parametrize("scope", HYBRID_LM_SCOPES)
 def test_compiled_lm_step_carries_every_scope(scope):
-    text = _lm_step_text()
+    text = (_latent_lm_step_text() if scope in LATENT_ONLY
+            else _lm_step_text())
     assert re.search(r"^HloModule (\w+)", text, re.M).group(1) == \
         profile_mod.STEP_PROGRAM
     phases = {phase_of(n) for n in _op_names(text)}
     assert phases & {scope, scope + ".fwd", scope + ".bwd"}, phases
+
+
+@pytest.mark.parametrize("scope", LATENT_SCOPES)
+def test_compiled_latent_lm_step_carries_its_scopes_both_ways(scope):
+    """The lowered GLM-shaped step: every scope of the main model and of
+    the module, forward and backward, and none of another mixer's."""
+    phases = {phase_of(n) for n in _op_names(_latent_lm_step_text())}
+    if scope == "optimizer.update":
+        assert scope in phases
+    else:
+        assert {scope + ".fwd", scope + ".bwd"} <= phases, phases
+    assert not {p for p in phases if p.startswith(("ssm.", "attn.window"))}
 
 
 def test_phase_scopes_are_both_families_and_each_name_once():
@@ -166,6 +217,12 @@ def test_phase_scopes_are_both_families_and_each_name_once():
                     "checkpoint/mixer/ssm.scan/mul") == "ssm.scan.bwd"
     assert phase_of("jit(train_step)/jvp(HybridLM)/layers_1/mixer/"
                     "moe.experts/while/body/dot_general") == "moe.experts.fwd"
+    # the first scope on the path: the module's block keeps its inner
+    # mixers' ops, its head pass the shared head's
+    assert phase_of("jit(train_step)/transpose(jvp(HybridLM))/mtp/mtp.block/"
+                    "attn/checkpoint/mixer/attn.core/mul") == "mtp.block.bwd"
+    assert phase_of("jit(train_step)/jvp(HybridLM)/mtp.head/lm_head/"
+                    "lm.head/dot_general") == "mtp.head.fwd"
 
 
 @pytest.mark.parametrize("path", ["per_step", "scan", "accum"])
