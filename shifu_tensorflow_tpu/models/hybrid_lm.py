@@ -43,7 +43,14 @@ such layers: config/model_config.py ``HybridLMConfig``):
   and keys / values through low-rank latents with an RMSNorm on each, a
   head's query and key of two parts of which the second turns and, on the
   key, is one for every head; the core is the ``*`` layer's, over every
-  earlier key, at the head size the two parts make.
+  earlier key, at the head size the two parts make.  q is turned in place
+  (:func:`rotate` from the first dimension that turns) and the keys and
+  values come off the ``[k_n ; v]`` heads and the one rotary key
+  (:func:`latent_heads`): the expressions, or, where the static shapes
+  say so (ops/pallas/rope.py ``lanes_pay``, ``heads_pay``) and the
+  program is lowered for the TPU, one kernel pass each that writes
+  head-major, where the flash kernels read (op names ``rope_lanes``,
+  ``latent_lanes``; ``…_t`` their transposes).
 
 Where ``num_nextn_predict_layers`` is 1 a multi-token prediction module
 (:class:`MTPModule`) reads the last block's output ``h_i`` (before the
@@ -71,8 +78,11 @@ The phase names (``jax.named_scope``; obs/profile.py ``PHASE_SCOPES``):
 ``embed.gather``, ``ssm.proj`` (in/out projections, gate and grouped
 norm), ``ssm.conv``, ``ssm.scan``, ``moe.route``, ``moe.experts``,
 ``moe.shared``, ``mlp.dense`` (a ``D`` layer), ``attn.proj`` (q, k, v, o),
-``attn.rope`` (the rotation of q and k), ``attn.core`` (a ``*`` or ``L``
-layer's core), ``attn.window`` (a ``W`` layer's), ``attn.latent`` (an ``L``
+``attn.rope`` (the rotation of q and k; on an ``L`` layer every pass that
+lays q, k and v out for the core: q turned in place, the one rotary key
+turned and written into every head, ``[k_n ; v]`` taken apart), ``attn.core``
+(a ``*`` or ``L`` layer's core: on an ``L`` layer the attention call and
+nothing else), ``attn.window`` (a ``W`` layer's), ``attn.latent`` (an ``L``
 layer's projections onto its two latents and their norms), ``attn.expand``
 (the latents' projections up to heads), ``lm.head``; and around all of
 those, where there is the module, ``mtp.merge`` (two norms, the next
@@ -424,31 +434,63 @@ def rope_tables(rope: RopeParameters, seq: int, head_dim: int):
     return jnp.cos(angle) * scale, jnp.sin(angle) * scale
 
 
-def apply_rope(u, cos, sin):
-    """``u cos + rotate_half(u) sin`` over the first ``R = 2 x`` (the
-    tables' width) of the last axis of (B, S, H, D), ``rotate_half(u) =
-    (-u[R/2:R], u[:R/2])``: dimension ``m`` pairs with ``m + R/2``.  The
-    dimensions from ``R`` on pass through, unchanged and unscaled."""
+def apply_rope(u, cos, sin, offset: int = 0):
+    """``u cos + rotate_half(u) sin`` over ``R = 2 x`` (the tables' width)
+    dimensions of the last axis of (B, S, H, D), from ``offset`` on,
+    ``rotate_half(u) = (-u[R/2:R], u[:R/2])`` of those: dimension ``offset
+    + m`` pairs with ``offset + m + R/2``.  The dimensions before
+    ``offset`` and from ``offset + R`` on pass through, unchanged and
+    unscaled."""
     half = cos.shape[-1]
-    u1, u2 = u[..., :half], u[..., half:2 * half]
+    u1 = u[..., offset:offset + half]
+    u2 = u[..., offset + half:offset + 2 * half]
     cos = cos[None, :, None, :].astype(u.dtype)
     sin = sin[None, :, None, :].astype(u.dtype)
     parts = [u1 * cos - u2 * sin, u2 * cos + u1 * sin]
-    if 2 * half < u.shape[-1]:
-        parts.append(u[..., 2 * half:])
+    if offset:
+        parts.insert(0, u[..., :offset])
+    if offset + 2 * half < u.shape[-1]:
+        parts.append(u[..., offset + 2 * half:])
     return jnp.concatenate(parts, axis=-1)
 
 
-def rotate(u, cos, sin):
+def rotate(u, cos, sin, offset: int = 0):
     """:func:`apply_rope` by the path the static shapes pick: where
     ``rope_kernel.lanes_pay`` (a head of whole 128-lane registers,
     float32) a program lowered for the TPU gets the kernel, whose one pass
     leaves the result as the flash kernels read it; every other program,
     and every other head or dtype, the expression."""
-    if not rope_kernel.lanes_pay(u.shape[-1], 2 * cos.shape[-1], u.dtype):
-        return apply_rope(u, cos, sin)
+    if not rope_kernel.lanes_pay(u.shape[-1], 2 * cos.shape[-1], u.dtype,
+                                 offset):
+        return apply_rope(u, cos, sin, offset)
     return jax.lax.platform_dependent(
-        u, cos, sin, tpu=rope_kernel.rope_lanes, default=apply_rope)
+        u, cos, sin,
+        tpu=lambda *v: rope_kernel.rope_lanes(*v, None, offset),
+        default=lambda *v: apply_rope(*v, offset))
+
+
+def latent_heads(kv, k_r, cos, sin, nope: int):
+    """Latent attention's ``(keys, values)`` (B, S, H, ·) of ``kv`` (B, S,
+    H, nope + V), a head ``[k_n ; v]``, and the ONE rotary key ``k_r`` (B,
+    S, 1, R): a head's key is ``[k_n ; rot(k_r)]`` (the broadcast's
+    transpose sums the heads' gradients).  This expression, or, where
+    ``rope_kernel.heads_pay`` (float32, keys and values of whole 128-lane
+    registers) and the program is lowered for the TPU, one kernel pass
+    that reads ``kv`` as its projection leaves it and writes both
+    head-major, where the flash kernels read them."""
+    def expression(kv, k_r, cos, sin):
+        k_r = jnp.broadcast_to(apply_rope(k_r, cos, sin),
+                               kv.shape[:3] + k_r.shape[3:])
+        return (jnp.concatenate([kv[..., :nope], k_r], axis=-1),
+                kv[..., nope:])
+
+    if not rope_kernel.heads_pay(nope, k_r.shape[-1], kv.shape[-1] - nope,
+                                 kv.shape[2], kv.dtype):
+        return expression(kv, k_r, cos, sin)
+    return jax.lax.platform_dependent(
+        kv, k_r, cos, sin,
+        tpu=lambda *v: rope_kernel.latent_lanes(*v, nope),
+        default=expression)
 
 
 class AttentionMixer(nn.Module):
@@ -497,8 +539,12 @@ class LatentAttentionMixer(nn.Module):
     ``c_q = RMSNorm(x W_qa)``, ``[q_n ; q_r] = c_q W_qb`` a head;
     ``[c_kv ; k_r] = x W_kva``, ``[k_n ; v] = RMSNorm(c_kv) W_kvb`` a head;
     ``q = [q_n ; rot(q_r)]``, ``k = [k_n ; rot(k_r)]`` with the one ``k_r``
-    for every head (the broadcast's transpose sums the heads' gradients);
-    ``attention`` over every earlier key; ``W_o``."""
+    for every head (whose gradient is the sum of the heads');
+    ``attention`` over every earlier key; ``W_o``.  Neither q nor k is
+    built by concatenation nor ``[k_n ; v]`` sliced where the kernels run:
+    :func:`rotate` turns q's last dimensions in place and
+    :func:`latent_heads` hands back keys and values, each by one pass that
+    reads what the projection left and writes what the core reads."""
 
     cfg: HybridLMConfig
     attention: Callable
@@ -524,14 +570,10 @@ class LatentAttentionMixer(nn.Module):
                 c_kv).reshape(bsz, s, n, d_n + d_v)
         with jax.named_scope("attn.rope"):
             cos, sin = rope_tables(c.rope_for("L"), s, d_r)
-            q_r = apply_rope(q[..., d_n:], cos, sin)
-            k_r = apply_rope(k_r, cos, sin)
+            q = rotate(q, cos, sin, d_n)
+            k, v = latent_heads(kv, k_r, cos, sin, d_n)
         with jax.named_scope("attn.core"):
-            q = jnp.concatenate([q[..., :d_n], q_r], axis=-1)
-            k = jnp.concatenate(
-                [kv[..., :d_n], jnp.broadcast_to(k_r, (bsz, s, n, d_r))],
-                axis=-1)
-            y = self.attention(q, k, kv[..., d_n:])
+            y = self.attention(q, k, v)
         with jax.named_scope("attn.proj"):
             return Kernel(c.hidden_size, c.output_std, dt_, name="o_proj")(
                 y.reshape(bsz, s, n * d_v))

@@ -354,6 +354,82 @@ def test_the_rotary_keys_gradient_is_the_sum_over_the_heads():
     assert rel(got, one) > 0.3  # one head's share is not the sum
 
 
+# ---- the mixer against the form it had before its heads were laid out
+# for the flash kernels (PR 37)
+
+def _parents_mixer(params, x, c, attention):
+    """``LatentAttentionMixer`` as the parent commit computed it, written
+    out: the rotary parts sliced off and turned apart, q and k built by
+    concatenation with the one key broadcast to the heads, ``[k_n ; v]``
+    sliced."""
+    n, d_c = c.num_attention_heads, c.kv_lora_rank
+    d_n, d_r, d_v = c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim
+    bsz, s, _ = x.shape
+
+    def norm(h, scale):
+        return h * jax.lax.rsqrt(jnp.mean(h * h, axis=-1, keepdims=True)
+                                 + c.layer_norm_epsilon) * scale
+
+    c_q = norm(x @ params["q_a_proj"]["kernel"], params["q_a_norm"]["scale"])
+    kv = x @ params["kv_a_proj"]["kernel"]
+    c_kv = norm(kv[..., :d_c], params["kv_a_norm"]["scale"])
+    k_r = kv[..., d_c:].reshape(bsz, s, 1, d_r)
+    q = (c_q @ params["q_b_proj"]["kernel"]).reshape(bsz, s, n, d_n + d_r)
+    kv = (c_kv @ params["kv_b_proj"]["kernel"]).reshape(bsz, s, n, d_n + d_v)
+    cos, sin = hybrid_lm.rope_tables(c.rope_for("L"), s, d_r)
+    q_r = hybrid_lm.apply_rope(q[..., d_n:], cos, sin)
+    k_r = hybrid_lm.apply_rope(k_r, cos, sin)
+    q = jnp.concatenate([q[..., :d_n], q_r], axis=-1)
+    k = jnp.concatenate(
+        [kv[..., :d_n], jnp.broadcast_to(k_r, (bsz, s, n, d_r))], axis=-1)
+    y = attention(q, k, kv[..., d_n:])
+    return y.reshape(bsz, s, n * d_v) @ params["o_proj"]["kernel"]
+
+
+@pytest.mark.parametrize("over", [
+    {}, dict(num_attention_heads=2, num_key_value_heads=2, q_lora_rank=48,
+             kv_lora_rank=32, qk_nope_head_dim=192, qk_rope_head_dim=64,
+             v_head_dim=256, rope_theta=1000000, initializer_range=0.05)],
+    ids=["a-head-of-32", "the-published-head"])
+def test_the_mixer_is_the_parents_expression_on_the_same_parameters(over):
+    """Output and every parameter's gradient against the parent's form, to
+    float32 round-off, at the tiny head (which no rule picks) and at the
+    published one (which both rules pick: off the TPU the program still
+    holds the expressions); and the parameter tree the parent's by name
+    and shape."""
+    c = config_of(params_for(**over)).params.hybrid_lm
+    n, d_c, d_q = c.num_attention_heads, c.kv_lora_rank, c.q_lora_rank
+    d_n, d_r, d_v = c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim
+    x = jax.random.normal(jax.random.key(11), (2, SEQ, 64))
+    cot = jax.random.normal(jax.random.key(12), (2, SEQ, 64))
+    causal = jax.tree_util.Partial(ring.full_attention, causal=True)
+    mixer = hybrid_lm.LatentAttentionMixer(c, causal)
+    params = jax.jit(mixer.init)(jax.random.key(13), x)["params"]
+    assert jax.tree.map(jnp.shape, params) == {
+        "q_a_proj": {"kernel": (64, d_q)}, "q_a_norm": {"scale": (d_q,)},
+        "kv_a_proj": {"kernel": (64, d_c + d_r)},
+        "kv_a_norm": {"scale": (d_c,)},
+        "q_b_proj": {"kernel": (d_q, n * (d_n + d_r))},
+        "kv_b_proj": {"kernel": (d_c, n * (d_n + d_v))},
+        "o_proj": {"kernel": (n * d_v, 64)}}
+
+    def program(q):
+        return jnp.sum(mixer.apply({"params": q}, x) * cot)
+
+    def parents(q):
+        return jnp.sum(_parents_mixer(q, x, c, causal) * cot)
+
+    np.testing.assert_allclose(
+        jax.jit(mixer.apply)({"params": params}, x),
+        jax.jit(_parents_mixer, static_argnums=(2, 3))(params, x, c, causal),
+        atol=1e-6, rtol=1e-6)
+    grads = jax.jit(jax.grad(program))(params)
+    want = jax.jit(jax.grad(parents))(params)
+    for (path, g), w in zip(jax.tree_util.tree_flatten_with_path(grads)[0],
+                            jax.tree.leaves(want)):
+        assert rel(g, w) < 2e-6, (jax.tree_util.keystr(path), rel(g, w))
+
+
 # ---- the mixer through the kernels
 
 def test_the_latent_mixer_through_the_flash_kernels_at_a_head_of_256(
