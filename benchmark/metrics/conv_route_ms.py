@@ -1,0 +1,22 @@
+"""``conv_route_ms`` — layer: models models/ ops/.  Unit ``ms``, source
+``device_trace``; should move ``train_rows_per_s``.
+
+Device ms a step inside ``moe.route``, forward + backward summed (the
+backward's recomputed forward included): the four
+sparse blocks' gate product at full precision over 64 outputs, sigmoid,
+top-4, the sort of 65,536 pairs a layer and the tile plan.  From
+``obs.profile.phases`` on the run's own capture, handed on by the plane;
+``None`` on a reading without the phase or of another configuration's
+kind.
+"""
+
+LAYER = "models models/ ops/"
+UNIT = "ms"
+SOURCE = "device_trace"
+MOVES = "train_rows_per_s"
+
+from benchmark.conv_lm_readings import conv_phase_ms
+
+
+def read(r):
+    return conv_phase_ms(r, "moe.route")

@@ -1,0 +1,22 @@
+"""``qk_norm_rope_ms`` — layer: models models/ ops/.  Unit ``ms``, source
+``device_trace``; should move ``train_rows_per_s``.
+
+Device ms a step inside ``attn.qknorm`` + ``attn.rope``, forward + backward summed (the
+backward's recomputed forward included): the RMSNorm
+over each head's q and k and the rotation of both (at a head under 128
+lanes the ``jnp`` expression, not the kernel of ``ops/pallas/rope.py``).  From
+``obs.profile.phases`` on the run's own capture, handed on by the plane;
+``None`` on a reading without the phase or of another configuration's
+kind.
+"""
+
+LAYER = "models models/ ops/"
+UNIT = "ms"
+SOURCE = "device_trace"
+MOVES = "train_rows_per_s"
+
+from benchmark.conv_lm_readings import conv_phase_ms
+
+
+def read(r):
+    return conv_phase_ms(r, "attn.qknorm", "attn.rope")

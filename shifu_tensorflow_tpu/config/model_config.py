@@ -55,7 +55,11 @@ def require_servable(model_type: str, plane: str) -> None:
 
 
 #: ``layer_types`` / ``mlp_layer_types`` entries -> pattern characters
-_ATTENTION_KINDS = {"full_attention": "*", "sliding_attention": "W"}
+#: (``conv``, a gated short convolution, is a block's operator where the
+#: others are its attention)
+CONV = "conv"
+_ATTENTION_KINDS = {"full_attention": "*", "sliding_attention": "W",
+                    CONV: "C"}
 _MLP_KINDS = {"sparse": "E", "dense": "D"}
 #: a pattern's attention characters -> the layer type whose heads and rotary
 #: they take; ``L`` (latent attention) is no ``layer_types`` entry: its
@@ -121,7 +125,7 @@ class RopeParameters:
 class HybridLMConfig:
     """``ModelType: hybrid_lm`` — the keys of a public ``config.json``
     under their own names (``train.params`` carries them beside
-    ``ModelType``), plus the share this chip holds.  Three public shapes
+    ``ModelType``), plus the share this chip holds.  Five public shapes
     are read:
 
     - ``nemotron_h``: ``hybrid_override_pattern``, one mixer a layer (``M``
@@ -163,7 +167,25 @@ class HybridLMConfig:
       prediction modules (0 or 1: one more block of the last block's kinds
       over ``[RMSNorm(h_i) ; RMSNorm(Emb(t_{i+1}))] W_m``, its own final
       norm, the shared head; the step's loss is the next-token loss +
-      ``mtp_loss_weight`` x the module's, which is no public key).
+      ``mtp_loss_weight`` x the module's, which is no public key);
+    - ``lfm2_moe``: ``layer_types`` with ``conv`` entries marks the family
+      (:meth:`conv_family`).  A ``conv`` block's operator is a gated short
+      convolution (``C``: ``[B ; C ; u] = x W_in``, a depth-wise causal
+      convolution of ``conv_L_cache`` taps over ``B * u``, no bias
+      (``conv_bias: false``) and no activation, ``out = (C * conv) W_out``),
+      a ``full_attention`` block's grouped-query attention with an RMSNorm
+      over each head's q and k before the rotation (``qk_norm``; one scale
+      for the query heads and one for the key heads) at a head of
+      ``hidden_size / num_attention_heads`` where no ``head_dim`` says
+      otherwise; no ``mlp_layer_types``: the first ``num_dense_layers``
+      blocks' feed-forward dense (``D``), the others' sparse (``E``);
+      ``rope_parameters`` ONE parametrisation (``rope_theta``,
+      ``rope_type``) and no entry a layer type; ``norm_eps``;
+      ``use_expert_bias: true`` (sigmoid scores, top-k of score + a bias
+      that rests), gated ``silu`` experts and no shared expert, which the
+      family has no keys for; the head tied to the embedding
+      (``tie_word_embeddings`` / ``tie_embedding``) unless the
+      configuration says otherwise.
 
     ``n_routed_experts`` is the router's width (all the experts there
     are); ``experts_held`` = (first id, count) the experts whose weights
@@ -218,6 +240,10 @@ class HybridLMConfig:
     attention_heads_by_type: tuple = ()
     num_key_value_heads: int = 1
     head_dim: int = 16
+    #: an RMSNorm over each head's q and over each head's k, before the
+    #: rotation: one learned scale of ``head_dim`` for all query heads, one
+    #: for all key heads
+    qk_norm: bool = False
     sliding_window: int = 0  # keys a ``W`` layer's query sees, itself one
     #: ((layer type, RopeParameters), ...); a type without one: no rotary
     rope_parameters: tuple = ()
@@ -230,10 +256,17 @@ class HybridLMConfig:
     # multi-token prediction
     num_nextn_predict_layers: int = 0
     mtp_loss_weight: float = 0.3
+    # a gated short convolution (a ``C`` layer): taps a channel
+    conv_L_cache: int = 3
+    #: the head reads the token embedding's table (logits = h Emb^T) and
+    #: has no kernel of its own
+    tie_word_embeddings: bool = False
 
     #: public spellings of the same number
     ALIASES = (("num_experts", "n_routed_experts"),
                ("rms_norm_eps", "layer_norm_epsilon"),
+               ("norm_eps", "layer_norm_epsilon"),
+               ("tie_embedding", "tie_word_embeddings"),
                ("shared_expert_intermediate_size",
                 "moe_shared_expert_intermediate_size"),
                ("moe_routed_scaling_factor", "routed_scaling_factor"))
@@ -281,6 +314,13 @@ class HybridLMConfig:
         and the config's own ``partial_rotary_factor`` stand for the
         entries that state none."""
         entries = dict(params["rope_parameters"])
+        if entries and not any(isinstance(v, Mapping)
+                               for v in entries.values()):
+            # one parametrisation and no entry a layer type: that of every
+            # attention layer type the configuration has
+            kinds = sorted({str(k) for k in params.get("layer_types", ())}
+                           - {CONV}) or ["full_attention"]
+            entries = {kind: entries for kind in kinds}
         shared = {k: entries.pop(k) for k in list(entries)
                   if not isinstance(entries[k], Mapping)}
         unknown = sorted(set(shared) - {"original_max_position_embeddings"})
@@ -337,11 +377,52 @@ class HybridLMConfig:
         return said
 
     @staticmethod
+    def conv_family(params: Mapping[str, Any]) -> dict:
+        """What a configuration of the gated-short-convolution family (a
+        ``conv`` entry among its ``layer_types``) says without the keys
+        the other shapes say it with: a norm on every head's q and k,
+        gated ``silu`` experts and no shared expert (the family has no
+        keys for them: stated otherwise is an error), and, unless the
+        configuration says, a head of ``hidden_size /
+        num_attention_heads`` and the head tied to the embedding (the
+        family's public configuration class defaults to tied)."""
+        for key, only in (("conv_bias", False), ("use_expert_bias", True)):
+            if _parse_bool(params.get(key, only)) != only:
+                raise ValueError(
+                    f"{key}={params[key]!r} is not implemented with conv "
+                    f"layers ({only!r}: the taps carry no bias; the choice "
+                    "of experts is by sigmoid score + a bias that rests)")
+        said = {"qk_norm": True, "hidden_act": "silu", "n_shared_experts": 0}
+        for key, value in said.items():
+            if params.get(key, value) != value:
+                raise ValueError(
+                    f"{key}={params[key]!r} but the conv family's blocks "
+                    f"give {value!r}")
+        if "head_dim" not in params and "num_attention_heads" in params:
+            said["head_dim"] = (int(params["hidden_size"])
+                                // int(params["num_attention_heads"]))
+        if "tie_word_embeddings" not in params:
+            said["tie_word_embeddings"] = True
+        return said
+
+    @staticmethod
     def pattern_of(params: Mapping[str, Any]) -> str:
-        """``layer_types`` + ``mlp_layer_types`` as a pattern string."""
+        """``layer_types`` + ``mlp_layer_types`` as a pattern string; with
+        ``num_dense_layers`` in their place, the first that many blocks'
+        feed-forward dense and the others' sparse."""
         kinds = [str(k) for k in params["layer_types"]]
         mlps = [str(k) for k in params.get("mlp_layer_types",
                                            ["sparse"] * len(kinds))]
+        if "num_dense_layers" in params:
+            dense = int(params["num_dense_layers"])
+            if "mlp_layer_types" in params:
+                raise ValueError(
+                    "num_dense_layers beside mlp_layer_types: one of the "
+                    "two says which blocks are dense")
+            if not 0 <= dense <= len(kinds):
+                raise ValueError(
+                    f"num_dense_layers={dense} of {len(kinds)} layer_types")
+            mlps = ["dense"] * dense + ["sparse"] * (len(kinds) - dense)
         if len(mlps) != len(kinds):
             raise ValueError(
                 f"mlp_layer_types has {len(mlps)} entries, layer_types "
@@ -375,6 +456,8 @@ class HybridLMConfig:
         if "kv_lora_rank" in params:
             params.update(cls.latent_family(params))
             blocks = int(params["num_hidden_layers"])
+        if CONV in params.get("layer_types", ()):
+            params.update(cls.conv_family(params))
         if "layer_types" in params:
             pattern = cls.pattern_of(params)
             if params.get("hybrid_override_pattern", pattern) != pattern:
@@ -421,13 +504,14 @@ class HybridLMConfig:
     def validate(self, params: Mapping[str, Any] = (),
                  blocks: "int | None" = None) -> None:
         pattern = self.hybrid_override_pattern
-        bad = set(pattern) - set("MEDW*L")
+        bad = set(pattern) - set("MEDW*LC")
         if bad or not pattern:
             raise ValueError(
                 "hybrid_override_pattern is a string of M (Mamba-2), E "
                 "(experts), D (dense gated feed-forward), * (attention), "
-                "W (attention inside sliding_window) and L (latent "
-                f"attention); got {sorted(bad)}")
+                "W (attention inside sliding_window), L (latent "
+                "attention) and C (gated short convolution); got "
+                f"{sorted(bad)}")
         layers = params.get("num_hidden_layers") if params else None
         expect = len(pattern) if blocks is None else blocks
         if layers is not None and int(layers) != expect:
@@ -487,6 +571,10 @@ class HybridLMConfig:
                 "a W (sliding_attention) layer needs sliding_window > 0")
         if "L" in pattern:
             self.validate_latent()
+        if "C" in pattern and self.conv_L_cache < 1:
+            raise ValueError(
+                f"conv_L_cache={self.conv_L_cache}: a C (gated short "
+                "convolution) layer needs at least one tap")
         unknown = sorted(set(dict(self.rope_parameters))
                          - set(_PATTERN_LAYER_TYPES.values()))
         if unknown:

@@ -1,9 +1,10 @@
 """Hybrid decoder family (``ModelType: hybrid_lm``): a causal language
 model whose layers are each ONE mixer in a pre-norm residual,
 ``x <- x + mixer(RMSNorm(x))``, the mixer chosen per layer by a pattern
-string as the public ``nemotron_h`` configuration writes it (a ``mellum``
-or ``laguna`` configuration's block, attention then feed-forward, is two
-such layers: config/model_config.py ``HybridLMConfig``):
+string as the public ``nemotron_h`` configuration writes it (a ``mellum``,
+``laguna``, ``glm4_moe_lite`` or ``lfm2_moe`` configuration's block,
+operator then feed-forward, is two such layers: config/model_config.py
+``HybridLMConfig``):
 
 - ``M``  Mamba-2 (``ssm.conv`` + ``ssm.scan``: the chunked scan by the
   path :func:`chunked_scan` picks: ops/ssm_scan.py's expression, or,
@@ -32,7 +33,10 @@ such layers: config/model_config.py ``HybridLMConfig``):
   positions where the layer's type has ``rope_parameters`` (``default``
   or ``yarn``: :func:`rope_tables`) over the first
   ``partial_rotary_factor`` of a head's dimensions; none otherwise (the
-  Mamba layers carry position).  The rotation (:func:`rotate`) is
+  Mamba layers carry position).  Where the configuration has ``qk_norm``
+  an RMSNorm over each head's q and over each head's k comes before the
+  rotation, one learned scale of ``head_dim`` for all query heads and one
+  for all key heads.  The rotation (:func:`rotate`) is
   :func:`apply_rope`'s expression, or, where the static shapes say so
   (ops/pallas/rope.py ``lanes_pay``: a head of whole 128-lane registers,
   float32) and the program is lowered for the TPU, one kernel pass a
@@ -50,7 +54,18 @@ such layers: config/model_config.py ``HybridLMConfig``):
   say so (ops/pallas/rope.py ``lanes_pay``, ``heads_pay``) and the
   program is lowered for the TPU, one kernel pass each that writes
   head-major, where the flash kernels read (op names ``rope_lanes``,
-  ``latent_lanes``; ``…_t`` their transposes).
+  ``latent_lanes``; ``…_t`` their transposes);
+- ``C``  a gated short convolution (:class:`ShortConvMixer`): ``[B ; C ;
+  u] = x W_in``, a depth-wise causal convolution of ``conv_L_cache`` taps
+  a channel over ``B * u`` (no bias, no activation; the last tap is the
+  present token's), ``(C * conv) W_out``.
+
+The head is its own ``kernel`` or, where the configuration ties it
+(``tie_word_embeddings``), the token embedding's table read transposed
+(:class:`LMHead`): the table's gradient is then the lookup's scatter-add
+plus the head's dense product.  The norm after the last block is
+``final_norm`` under every family's keys (the ``lfm2_moe`` family's public
+code calls it ``embedding_norm``; it is the final norm).
 
 Where ``num_nextn_predict_layers`` is 1 a multi-token prediction module
 (:class:`MTPModule`) reads the last block's output ``h_i`` (before the
@@ -78,13 +93,16 @@ The phase names (``jax.named_scope``; obs/profile.py ``PHASE_SCOPES``):
 ``embed.gather``, ``ssm.proj`` (in/out projections, gate and grouped
 norm), ``ssm.conv``, ``ssm.scan``, ``moe.route``, ``moe.experts``,
 ``moe.shared``, ``mlp.dense`` (a ``D`` layer), ``attn.proj`` (q, k, v, o),
+``attn.qknorm`` (the two head norms, where the configuration has them),
 ``attn.rope`` (the rotation of q and k; on an ``L`` layer every pass that
 lays q, k and v out for the core: q turned in place, the one rotary key
 turned and written into every head, ``[k_n ; v]`` taken apart), ``attn.core``
 (a ``*`` or ``L`` layer's core: on an ``L`` layer the attention call and
 nothing else), ``attn.window`` (a ``W`` layer's), ``attn.latent`` (an ``L``
 layer's projections onto its two latents and their norms), ``attn.expand``
-(the latents' projections up to heads), ``lm.head``; and around all of
+(the latents' projections up to heads), ``conv.proj`` (a ``C`` layer's
+``W_in`` and ``W_out``), ``conv.mix`` (``B * u``, the taps, ``C *``),
+``lm.head`` (the head's pass, tied or not); and around all of
 those, where there is the module, ``mtp.merge`` (two norms, the next
 token's embedding, ``W_m``), ``mtp.block`` and ``mtp.head`` (the first
 scope on an op's path names its phase, so the module's block and head
@@ -186,8 +204,12 @@ def _dt_bias_init(cfg: HybridLMConfig):
 
 
 class ConvParams(nn.Module):
+    """``(kernel (width, channels), bias or None)`` of a depth-wise
+    convolution: tap ``width - 1`` is the one on the present token."""
+
     width: int
     channels: int
+    bias: bool = True
 
     @nn.compact
     def __call__(self):
@@ -197,8 +219,8 @@ class ConvParams(nn.Module):
             lambda k, s, d=jnp.float32: jax.random.uniform(
                 k, s, d, -bound, bound),
             (self.width, self.channels), jnp.float32)
-        bias = self.param("bias", nn.initializers.zeros, (self.channels,),
-                          jnp.float32)
+        bias = (self.param("bias", nn.initializers.zeros, (self.channels,),
+                           jnp.float32) if self.bias else None)
         return kernel, bias
 
 
@@ -270,6 +292,34 @@ class MambaMixer(nn.Module):
                                name="norm")(y)
             return Kernel(c.hidden_size, c.output_std, dt_,
                           name="out_proj")(y)
+
+
+class ShortConvMixer(nn.Module):
+    """One ``C`` layer's mixer, a gated short convolution: ``[B ; C ; u] =
+    x W_in`` (three contiguous thirds), ``g = B * u``, ``c_t = sum_j w_j *
+    g_{t - (K - 1) + j}`` with ``g`` zero before the row's first token (a
+    depth-wise causal convolution of ``K = conv_L_cache`` taps written as
+    its shifted products, ``w_{K-1}`` the tap on the present token, no
+    bias, no activation), ``out = (C * c) W_out``."""
+
+    cfg: HybridLMConfig
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        c, dt_ = self.cfg, self.dtype
+        d, taps, s = c.hidden_size, c.conv_L_cache, x.shape[1]
+        with jax.named_scope("conv.proj"):
+            bcu = Kernel(3 * d, c.initializer_range, dt_, name="in_proj")(x)
+        kernel, _ = ConvParams(taps, d, bias=False, name="conv")()
+        with jax.named_scope("conv.mix"):
+            gated = bcu[..., :d] * bcu[..., 2 * d:]
+            padded = jnp.pad(gated, ((0, 0), (taps - 1, 0), (0, 0)))
+            conv = sum(padded[:, j:j + s] * kernel[j].astype(dt_)
+                       for j in range(taps))
+            y = bcu[..., d:2 * d] * conv
+        with jax.named_scope("conv.proj"):
+            return Kernel(d, c.output_std, dt_, name="out_proj")(y)
 
 
 class GroupNormScale(nn.Module):
@@ -517,6 +567,12 @@ class AttentionMixer(nn.Module):
                 bsz, s, nkv, hd)
             v = Kernel(nkv * hd, std, dt_, name="v_proj")(x).reshape(
                 bsz, s, nkv, hd)
+        if c.qk_norm:
+            with jax.named_scope("attn.qknorm"):
+                # over each head's dimensions, before the rotation: one
+                # scale for all query heads, one for all key heads
+                q = RMSNorm(c.layer_norm_epsilon, dt_, name="q_norm")(q)
+                k = RMSNorm(c.layer_norm_epsilon, dt_, name="k_norm")(k)
         rope = c.rope_for(self.kind)
         if rope is not None:
             with jax.named_scope("attn.rope"):
@@ -603,6 +659,8 @@ class Layer(nn.Module):
         elif self.kind == "L":
             y = LatentAttentionMixer(self.cfg, self.attention, self.dtype,
                                      name="mixer")(h)
+        elif self.kind == "C":
+            y = ShortConvMixer(self.cfg, self.dtype, name="mixer")(h)
         else:
             attention = (self.window_attention if self.kind == "W"
                          else self.attention)
@@ -612,25 +670,33 @@ class Layer(nn.Module):
 
 
 class LMHead(nn.Module):
-    """Untied head + next-token cross-entropy (``lm.head``): ``__call__``
-    gives ``(sum of the live rows' token losses, per-row mean loss (B,))``
-    and is what the backward pass rematerialises; ``logits`` the scores."""
+    """Head + next-token cross-entropy (``lm.head``): ``__call__`` gives
+    ``(sum of the live rows' token losses, per-row mean loss (B,))`` and is
+    what the backward pass rematerialises; ``logits`` the scores.  Untied
+    it holds its own ``kernel``; ``tied`` it holds nothing and is handed
+    the token embedding's ``table`` (vocab, hidden): the scores are ``h
+    table^T``, and the table's gradient is the lookup's scatter-add plus
+    this product's."""
 
     vocab: int
     hidden: int
     std: float
     dtype: Any = jnp.float32
+    tied: bool = False
 
     def setup(self):
-        self.kernel = self.param("kernel", _normal(self.std),
-                                 (self.hidden, self.vocab), jnp.float32)
+        if not self.tied:
+            self.kernel = self.param("kernel", _normal(self.std),
+                                     (self.hidden, self.vocab), jnp.float32)
 
-    def logits(self, h):
-        return _matmul(h, self.kernel, self.dtype).astype(jnp.float32)
+    def logits(self, h, table=None):
+        kernel = table.T if self.tied else self.kernel
+        return _matmul(h, kernel, self.dtype).astype(jnp.float32)
 
-    def __call__(self, h, ids, live):
+    def __call__(self, h, ids, live, table=None):
         with jax.named_scope("lm.head"):
-            logp = jax.nn.log_softmax(self.logits(h[:, :-1]), axis=-1)
+            logp = jax.nn.log_softmax(self.logits(h[:, :-1], table),
+                                      axis=-1)
             nll = -jnp.take_along_axis(logp, ids[:, 1:, None], axis=-1)[..., 0]
             return jnp.sum(nll * live[:, None]), jnp.mean(nll, axis=-1)
 
@@ -708,7 +774,8 @@ class HybridLM(nn.Module):
                        for kind in c.hybrid_override_pattern]
         self.final_norm = RMSNorm(c.layer_norm_epsilon, self.dtype)
         self.lm_head = nn.remat(LMHead)(c.vocab_size, c.hidden_size,
-                                        c.initializer_range, self.dtype)
+                                        c.initializer_range, self.dtype,
+                                        c.tie_word_embeddings)
         if c.num_nextn_predict_layers:
             self.mtp = MTPModule(c, self.attention, self.dtype,
                                  self.window_attention)
@@ -728,7 +795,13 @@ class HybridLM(nn.Module):
         h, ids, _ = self.trunk(x)
         if self.cfg.num_nextn_predict_layers and self.is_initializing():
             self.mtp_hidden(h, ids)  # ``init`` builds its parameters too
-        return self.lm_head.logits(self.final_norm(h))
+        return self.lm_head.logits(self.final_norm(h), self.head_table())
+
+    def head_table(self):
+        """What a tied head reads: the token embedding's table; nothing
+        where the head has its own kernel."""
+        return (self.embed.embedding if self.cfg.tie_word_embeddings
+                else None)
 
     def mtp_hidden(self, h, ids):
         """The module's final-normed hidden states (B, S - 1, d) from the
@@ -746,7 +819,8 @@ class HybridLM(nn.Module):
     def mtp_logits(self, x):
         """(B, S - 2, vocab held): position ``i`` scores token ``i + 2``."""
         h, ids, _ = self.trunk(x)
-        return self.lm_head.logits(self.mtp_hidden(h, ids)[0][:, :-1])
+        return self.lm_head.logits(self.mtp_hidden(h, ids)[0][:, :-1],
+                                   self.head_table())
 
     def losses(self, x, w):
         """``(next-token loss, the module's loss or None, per-row mean
@@ -754,7 +828,8 @@ class HybridLM(nn.Module):
         rows' positions: a row of weight 0 is padding and joins no sum."""
         h, ids, stats = self.trunk(x)
         live = (w.reshape(-1) != 0.0).astype(jnp.float32)
-        total, per_row = self.lm_head(self.final_norm(h), ids, live)
+        table = self.head_table()
+        total, per_row = self.lm_head(self.final_norm(h), ids, live, table)
         count = jnp.sum(live) * (ids.shape[1] - 1)
         main, ahead = total / jnp.maximum(count, 1.0), None
         if self.cfg.num_nextn_predict_layers:
@@ -762,7 +837,7 @@ class HybridLM(nn.Module):
             stats = fold_stats(stats, s)
             with jax.named_scope("mtp.head"):
                 # the head's own shift by one, over tokens 1 .. S - 1
-                total, _ = self.lm_head(g, ids[:, 1:], live)
+                total, _ = self.lm_head(g, ids[:, 1:], live, table)
             count = jnp.sum(live) * (ids.shape[1] - 2)
             ahead = total / jnp.maximum(count, 1.0)
         return main, ahead, per_row[:, None], stats

@@ -193,7 +193,8 @@ HYBRID_LM_SCOPES = ("embed.gather", "ssm.proj", "ssm.conv", "ssm.scan",
                     "moe.route", "moe.experts", "moe.shared", "mlp.dense",
                     "attn.proj", "attn.rope", "attn.core", "attn.window",
                     "attn.latent", "attn.expand", "mtp.merge", "mtp.block",
-                    "mtp.head", "lm.head", "optimizer.update")
+                    "mtp.head", "conv.proj", "conv.mix", "attn.qknorm",
+                    "lm.head", "optimizer.update")
 PHASE_SCOPES = TABULAR_SCOPES + tuple(
     s for s in HYBRID_LM_SCOPES if s not in TABULAR_SCOPES)
 #: the per-step program's module name (``jax.jit`` of ``train_step``);

@@ -188,9 +188,48 @@ def _latent_lm_step_text() -> str:
     return _STEP_TEXTS["latent_lm"]
 
 
+#: the scopes only a short-convolution decoder has, and every scope its
+#: step has
+CONV_ONLY = ("conv.proj", "conv.mix", "attn.qknorm")
+CONV_SCOPES = ("embed.gather", "moe.route", "moe.experts", "mlp.dense",
+               "attn.proj", "attn.rope", "attn.core", *CONV_ONLY, "lm.head",
+               "optimizer.update")
+
+
+def _conv_lm_step_text() -> str:
+    """The compiled per-step program of a tiny ``hybrid_lm`` trainer under
+    the ``lfm2_moe`` keys: a gated short convolution over a dense block,
+    attention with its head norms and a convolution over sparse ones, the
+    head tied to the embedding."""
+    if "conv_lm" not in _STEP_TEXTS:
+        from shifu_tensorflow_tpu.config.model_config import ModelConfig
+        from shifu_tensorflow_tpu.train.trainer import HealthConfig, Trainer
+
+        mc = ModelConfig.from_json({"train": {"params": {
+            "ModelType": "hybrid_lm", "Optimizer": "adam",
+            "LearningRate": 1e-3, "hidden_size": 32, "vocab_size": 64,
+            "num_hidden_layers": 3,
+            "layer_types": ["conv", "full_attention", "conv"],
+            "num_dense_layers": 1, "intermediate_size": 48,
+            "num_attention_heads": 2, "num_key_value_heads": 1,
+            "conv_L_cache": 3, "conv_bias": False, "norm_eps": 1e-5,
+            "rope_parameters": {"rope_theta": 100.0, "rope_type": "default"},
+            "num_experts": 4, "experts_held": [0, 2],
+            "num_experts_per_tok": 2, "moe_intermediate_size": 16,
+            "use_expert_bias": True}}})
+        trainer = Trainer(mc, 16, health=HealthConfig())
+        batch = {"x": np.ones((2, 16), np.float32),
+                 "y": np.ones((2, 1), np.float32),
+                 "w": np.ones((2, 1), np.float32)}
+        _STEP_TEXTS["conv_lm"] = trainer._path.step.lower(
+            trainer.state, batch).compile().as_text()
+    return _STEP_TEXTS["conv_lm"]
+
+
 @pytest.mark.parametrize("scope", HYBRID_LM_SCOPES)
 def test_compiled_lm_step_carries_every_scope(scope):
     text = (_latent_lm_step_text() if scope in LATENT_ONLY
+            else _conv_lm_step_text() if scope in CONV_ONLY
             else _lm_step_text())
     assert re.search(r"^HloModule (\w+)", text, re.M).group(1) == \
         profile_mod.STEP_PROGRAM
@@ -208,6 +247,20 @@ def test_compiled_latent_lm_step_carries_its_scopes_both_ways(scope):
     else:
         assert {scope + ".fwd", scope + ".bwd"} <= phases, phases
     assert not {p for p in phases if p.startswith(("ssm.", "attn.window"))}
+
+
+@pytest.mark.parametrize("scope", CONV_SCOPES)
+def test_compiled_conv_lm_step_carries_its_scopes_both_ways(scope):
+    """The lowered LFM2-shaped step: every scope of its blocks, forward
+    and backward, and none of another mixer's; the tied head's pass stays
+    ``lm.head`` and the lookup ``embed.gather``."""
+    phases = {phase_of(n) for n in _op_names(_conv_lm_step_text())}
+    if scope == "optimizer.update":
+        assert scope in phases
+    else:
+        assert {scope + ".fwd", scope + ".bwd"} <= phases, phases
+    assert not {p for p in phases if p.startswith((
+        "ssm.", "attn.window", "attn.latent", "mtp.", "moe.shared"))}
 
 
 def test_phase_scopes_are_both_families_and_each_name_once():
