@@ -669,6 +669,38 @@ class Layer(nn.Module):
         return x + y, stats
 
 
+#: the widest row whose maximum XLA:TPU turns into a ``reduce-window``
+#: (:func:`log_softmax`)
+WINDOWED_ROW = 8192
+
+
+def log_softmax(x):
+    """``jax.nn.log_softmax(x, axis=-1)``, its values to the bit, in a
+    form whose row maximum the chip's compiler reduces plainly.
+
+    At a row of at most :data:`WINDOWED_ROW` columns XLA:TPU compiles the
+    maximum, broadcast back over the row, to a ``reduce-window`` ``2V -
+    1`` wide (``EmitReduceWindowLane``): 47 ms a pass at 2 x 8,191 rows of
+    8,192 columns, where a row of 8,320 takes a plain reduce (PERF.md
+    section 6, PR 38 and PR 39).  The sweep of the head alone through the
+    v5e compiler (PR 39; ``tests/test_conv_lm_lowers.py``): every width from
+    128 to 8,192 windows (the powers of two, 384, 640, 1,000, 1,536,
+    3,072, 6,144, 7,680, 8,064, 8,184, 8,191), none above (8,193, 8,194,
+    8,200, 8,256, 8,320, 8,448, 9,216, 10,240, 12,288, 12,544, 16,256,
+    16,384, 19,360, 24,576, 32,768), at 16, 512 and 16,382 rows and a
+    hidden size of 64 or 2,048 alike.  There an optimization barrier
+    stands between the maximum and the subtraction, which the rewrite
+    cannot see through; wider rows take jax's own expression, and trace to
+    the program they did."""
+    if x.shape[-1] > WINDOWED_ROW:
+        return jax.nn.log_softmax(x, axis=-1)
+    top = jax.lax.optimization_barrier(jax.lax.stop_gradient(
+        jnp.max(x, axis=-1, keepdims=True)))
+    shifted = x - top
+    return shifted - jnp.log(jnp.sum(jnp.exp(shifted), axis=-1,
+                                     keepdims=True))
+
+
 class LMHead(nn.Module):
     """Head + next-token cross-entropy (``lm.head``): ``__call__`` gives
     ``(sum of the live rows' token losses, per-row mean loss (B,))`` and is
@@ -676,7 +708,7 @@ class LMHead(nn.Module):
     it holds its own ``kernel``; ``tied`` it holds nothing and is handed
     the token embedding's ``table`` (vocab, hidden): the scores are ``h
     table^T``, and the table's gradient is the lookup's scatter-add plus
-    this product's."""
+    this product's.  The scores' log-softmax is :func:`log_softmax`."""
 
     vocab: int
     hidden: int
@@ -695,8 +727,7 @@ class LMHead(nn.Module):
 
     def __call__(self, h, ids, live, table=None):
         with jax.named_scope("lm.head"):
-            logp = jax.nn.log_softmax(self.logits(h[:, :-1], table),
-                                      axis=-1)
+            logp = log_softmax(self.logits(h[:, :-1], table))
             nll = -jnp.take_along_axis(logp, ids[:, 1:, None], axis=-1)[..., 0]
             return jnp.sum(nll * live[:, None]), jnp.mean(nll, axis=-1)
 

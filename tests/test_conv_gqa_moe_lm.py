@@ -323,6 +323,53 @@ def test_the_tables_gradient_is_the_lookups_plus_the_heads():
     assert bool(jnp.all(jnp.any(by_head != 0, axis=1)))
 
 
+@pytest.mark.parametrize("tied", [True, False], ids=["tied", "untied"])
+def test_the_heads_barrier_maximum_is_the_plain_log_softmax(tied,
+                                                            monkeypatch):
+    """At the shipped head's 8,192 columns, a width whose row maximum the
+    chip's compiler would window, ``LMHead`` takes the maximum through an
+    optimization barrier (``hybrid_lm.log_softmax``): its loss and every
+    gradient leaf are the plain expression's, finite, and a target at the
+    last column is picked as float64 arithmetic picks it."""
+    vocab, hidden = hybrid_lm.WINDOWED_ROW, 64
+    head = hybrid_lm.LMHead(vocab, hidden, 0.15, tied=tied)
+    k = jax.random.split(jax.random.key(39), 3)
+    h = jax.random.normal(k[0], (1, 9, hidden))
+    ids = jax.random.randint(k[1], (1, 9), 0, vocab).at[0, 4].set(vocab - 1)
+    live = jnp.ones((1,))
+    table = jax.random.normal(k[2], (vocab, hidden)) * 0.15
+    variables = head.init(jax.random.key(0), h, ids, live,
+                          table if tied else None)
+
+    def grads():
+        """d loss / d (h, the table) tied, d loss / d (the kernel, h)
+        untied."""
+        def loss(v, h, table):
+            return head.apply(v, h, ids, live, table if tied else None)[0]
+        return jax.jit(jax.value_and_grad(loss, (1, 2) if tied else (0, 1)))(
+            variables, h, table)
+
+    loss, grad = grads()
+    monkeypatch.setattr(hybrid_lm, "log_softmax",
+                        lambda x: jax.nn.log_softmax(x, axis=-1))
+    plain_loss, plain_grad = grads()
+    leaves = jax.tree.leaves(grad)
+    assert len(leaves) == 2
+    assert all(bool(jnp.all(jnp.isfinite(g))) for g in leaves)
+    assert float(loss) == pytest.approx(float(plain_loss), rel=1e-6)
+    for g, want in zip(leaves, jax.tree.leaves(plain_grad)):
+        assert float(jnp.linalg.norm(want)) > 0
+        assert rel(g, want) < 1e-6
+    w = np.asarray(table, np.float64).T if tied else np.asarray(
+        variables["params"]["kernel"], np.float64)
+    logits = np.asarray(h, np.float64)[0, :-1] @ w
+    top = logits.max(axis=-1, keepdims=True)
+    logp = logits - top - np.log(np.exp(logits - top).sum(-1, keepdims=True))
+    picked = logp[np.arange(8), np.asarray(ids)[0, 1:]]
+    assert float(loss) == pytest.approx(-picked.sum(), rel=1e-5)
+    assert picked[3] == logp[3, vocab - 1]
+
+
 def test_the_head_norms_scale_every_head_alike_before_the_rotation():
     """One scale of ``head_dim`` for all query heads and one for all key
     heads: the mixer against the reference's operator on scales that are
