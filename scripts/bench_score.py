@@ -17,7 +17,7 @@ Three phases, one artifact (``BENCH_SCORE.json``):
   On a wide host two scanners ≈ 2x; on this repo's 2-core CI host both
   workers and the driver contend for the same cores, so the measured
   ratio is reported honestly and the gate falls back to the kill-drill
-  criterion (``host_capped: true`` — the BENCH_SERVE_SCALE discipline).
+  criterion (``host_capped: true``).
 - **kill drill**: REAL scorer processes under
   ``score.read:slow300@1.0,score.commit:torn-write@3``; one scorer is
   SIGKILLed while it provably holds an uncommitted lease.  Gates (never

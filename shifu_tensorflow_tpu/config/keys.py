@@ -94,9 +94,9 @@ DEFAULT_DTYPE = "float32"  # tabular nets are tiny; bf16 is opt-in
 # streaming TRANSPORT dtype for features (decoupled from compute dtype):
 # "auto" ships bf16 over the host->device link whenever it is SAFE — no
 # column feeds a hash AND ZSCALE normalization stats exist (raw
-# un-normalized magnitudes would lose mantissa silently) — at 4.6x the
-# fp32 device_put rate (BENCH_TRANSFER.json); the jitted step widens back
-# to the params' precision on device; "float32"/"bfloat16" force it
+# un-normalized magnitudes would lose mantissa silently) — half the fp32
+# bytes a batch; the jitted step widens back to the params' precision on
+# device; "float32"/"bfloat16" force it
 STREAM_FEATURE_DTYPE = TPU_PREFIX + "stream-feature-dtype"
 DEFAULT_STREAM_FEATURE_DTYPE = "auto"
 PREFETCH_DEPTH = TPU_PREFIX + "prefetch-depth"

@@ -681,9 +681,8 @@ class TrainParams:
     seq_d_model: int = 64
     seq_heads: int = 4
     seq_blocks: int = 2
-    # "auto": ring attention when the mesh has a seq axis >1, else full
-    # (the measured single-device winner; STPU_CHUNKED_MIN_SEQ opts into
-    # the chunked cutover — models/sequence.py)
+    # "auto": ring attention when the mesh has a seq axis >1, else full;
+    # chunked and flash are explicit choices (models/sequence.py)
     seq_attention: str = "auto"  # auto|full|chunked|flash|ring|ulysses
     # rematerialize encoder blocks: backward recomputes each block's
     # activations instead of storing them — the standard long-context

@@ -7,13 +7,13 @@ epochs over the same shards — re-pays the decompress+parse tax every epoch.
 
 On the single-core TPU bench host that tax is the entire ingest budget:
 measured ~206 MB/s gzip inflate + ~350 MB/s parse caps text ingest at
-~0.5M rows/s, while page-cache reads run at ~1.8 GB/s (scripts/
-profile_ingest.py).  So the first pass over a shard writes its *finalized*
-tensors (ZSCALE applied, weights clamped — reader._finalize output) plus the
-per-row train/valid routing hashes to flat binary slabs; every later epoch
-memory-maps the slabs and serves batches as zero-copy views.  The file
-format is deliberately dumb — one raw little-endian array per file — so a
-reader is ``np.memmap`` and nothing else.
+~0.5M rows/s, while page-cache reads run at ~1.8 GB/s.  So the first
+pass over a shard writes its *finalized* tensors (ZSCALE applied,
+weights clamped — reader._finalize output) plus the per-row train/valid
+routing hashes to flat binary slabs; every later epoch memory-maps the
+slabs and serves batches as zero-copy views.  The file format is
+deliberately dumb — one raw little-endian array per file — so a reader
+is ``np.memmap`` and nothing else.
 
 Layout, per source shard, under ``cache_dir``::
 

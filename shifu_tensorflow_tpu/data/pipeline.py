@@ -4,7 +4,7 @@ PAPERS.md: "tf.data: A Machine Learning Data Processing Framework").
 
 The previous ``ShardStream`` was one producer thread per file bucket doing
 read → inflate → parse → finalize → batch serially, so the ``n_readers``
-knob bought nothing (BENCH_INGEST_HOST cold scaling 1.0×/0.99×/1.02×) and
+knob bought nothing (cold reads went no faster at 2 or 4 readers) and
 reader count changed the batch order (parallel ingest was opt-in and
 irreproducible).  This module splits the work into composable stages with
 bounded queues, each timed under its own obs span:

@@ -26,9 +26,7 @@ Attention selection (``train.params.SeqAttention``):
   over the mesh 'seq' axis;
 - ``ulysses`` — all-to-all head-parallel attention (requires P | heads);
 - ``auto``  — ring when the mesh has a 'seq' axis of size > 1, else
-  full (no single-device regime is measured on the attached chip;
-  ``STPU_CHUNKED_MIN_SEQ`` re-enables the chunked cutover from data —
-  see ``_chunked_min_seq``).
+  full: chunked and flash are explicit choices, for their memory.
 """
 
 from __future__ import annotations
@@ -156,22 +154,15 @@ def make_attention(
     seq_axis = mesh.shape.get(ring.SEQ_AXIS, 1) if mesh is not None else 1
     has_seq = seq_axis > 1
     if impl == "auto":
-        cut = _chunked_min_seq()
-        if has_seq:
-            impl = "ring"
-        elif seq_len and cut > 0 and seq_len >= cut:
-            impl = "chunked"
-        else:
-            impl = "full"
+        impl = "ring" if has_seq else "full"
     if impl == "full":
         if causal:
             return partial(ring.full_attention, causal=True, window=window)
         return ring.full_attention
     if impl == "chunked":
         def attention(q, k, v):
-            return ring.chunked_attention(
-                q, k, v, causal=causal, block_size=_chunked_block(),
-                window=window)
+            return ring.chunked_attention(q, k, v, causal=causal,
+                                          window=window)
 
         return attention
     if impl == "flash":
@@ -222,36 +213,9 @@ def make_attention(
     )
 
 
-# Single-device attention cutover, measured not guessed (same policy as
-# the Pallas embedding constant, models/embeddings.py).  DEFAULT 0 =
-# ``auto`` NEVER swaps full -> chunked: where chunked or flash beats
-# XLA's fused full attention is not measured on the attached chip, so no
-# measured win region exists yet.  chunked/flash stay as explicit
-# SeqAttention opt-ins: their value is MEMORY (no S×S materialization —
-# full attention physically cannot run once B·H·S² bytes approach HBM),
-# and a measured deployment sets STPU_CHUNKED_MIN_SEQ to its own
-# feasibility/win boundary.
-def _chunked_min_seq() -> int:
-    import os
-
-    try:
-        return int(os.environ.get("STPU_CHUNKED_MIN_SEQ", "0"))
-    except ValueError:
-        return 0
-
-
 #: query and key rows of a flash-kernel tile on the causal path.  The
 #: kernel's own default, 128, makes a grid step per 128 x 128 scores: at
 #: S 4,096 that is 528 steps a head and pass over the causal triangle
 #: (1,024 over the square), and the steps' overhead outweighs their
 #: products; at 512 it is 36, and a tile's scores are 1 MB of VMEM.
 CAUSAL_FLASH_BLOCK = 512
-
-
-def _chunked_block() -> int:
-    import os
-
-    try:
-        return int(os.environ.get("STPU_CHUNKED_BLOCK", "512"))
-    except ValueError:
-        return 512

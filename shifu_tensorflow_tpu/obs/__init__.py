@@ -20,8 +20,7 @@ package is the one instrumentation layer all three planes share:
   serve.  ``python -m shifu_tensorflow_tpu.obs tail|summary`` reads it.
 
 Everything is off-by-default-cheap: with no ``shifu.tpu.obs-*`` key set,
-the module-level hooks are a single ``is None`` check per call site
-(measured <2% step-time overhead even fully enabled — BENCH_OBS.json).
+the module-level hooks are a single ``is None`` check per call site.
 stdlib-only by design: the observability plane must import in every
 process (CLI ``--help`` included) without paying for jax.
 """

@@ -1344,8 +1344,8 @@ def _diff_bench(args) -> int:
                 if isinstance(e, dict) and e.get("name"):
                     entries.append(e)
     except OSError:
-        print(f"no bench history at {args.history!r} — `python bench.py "
-              "<name>` appends one line per run", file=sys.stderr)
+        print(f"no bench history at {args.history!r} — `python bench.py` "
+              "appends one line per run", file=sys.stderr)
         return 1
     # failed runs (rc != 0) carry no trustworthy metrics — they stay in
     # the history as the record of the failure, but a diff must compare

@@ -46,9 +46,7 @@ The backward pass is a true Pallas FlashAttention-2 backward (new in
 r05; the forward now also emits per-row logsumexp): one kernel
 accumulates dQ over key blocks, a second accumulates dK/dV over query
 blocks, P reconstructed per tile from the saved logsumexp — no S×S
-matrix in either pass.  ``STPU_FLASH_BWD=chunked`` selects the previous
-chunked-XLA-scan gradient for A/B measurement
-(scripts/bench_flash_sweep.py).  Parity vs full attention is asserted
+matrix in either pass.  Parity vs full attention is asserted
 in tests/test_flash.py in interpret mode, which only a test asks for
 (``interpret=True``): the program never picks it, so on a host without
 a TPU the kernel fails instead of running in the interpreter.  That it
@@ -631,17 +629,7 @@ def flash_attention(q, k, v, causal: bool = False, block_q: int = 128,
     Forward: the Pallas kernel above.
     Backward: the Pallas FlashAttention-2 backward (_flash_backward) —
     P reconstructed per tile from the forward's saved logsumexp, dQ/dK/dV
-    accumulated blockwise, no S×S matrix in either pass.  Set
-    ``STPU_FLASH_BWD=chunked`` to fall back to the chunked-XLA-scan
-    gradient (the pre-r05 behavior) for A/B measurement
-    (scripts/bench_flash_sweep.py).
-
-    ``STPU_FLASH_BWD`` is read at TRACE time: when the gradient is taken
-    inside a jitted train step, the chosen branch is baked into the cached
-    jaxpr, so flipping the env var mid-process silently keeps whichever
-    backward was traced first.  To actually switch, start a new process
-    (how bench_flash_sweep.py runs its subprocess-per-case A/B) or clear
-    the jit caches (``jax.clear_caches()``) before the next call.
+    accumulated blockwise, no S×S matrix in either pass.
     """
     return _flash_forward(q, k, v, causal=causal, block_q=block_q,
                           block_k=block_k, interpret=interpret,
@@ -668,25 +656,7 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, interpret, window):
 
 
 def _flash_bwd(causal, block_q, block_k, interpret, window, res, g):
-    import os
-
     q, k, v, out, lse = res
-    # trace-time read: under jit this branch is frozen into the cached
-    # jaxpr — see the flash_attention docstring for the switching contract
-    if os.environ.get("STPU_FLASH_BWD", "pallas") == "chunked":
-        from shifu_tensorflow_tpu.parallel.ring import chunked_attention
-
-        # chunked fallback: never SMALLER than 512, chunked_attention's
-        # own default (default callers pass block_q=block_k=128, which
-        # must not shrink the backward chunk)
-        block = max(512, block_q, block_k)
-        _, vjp = jax.vjp(
-            lambda q_, k_, v_: chunked_attention(
-                q_, k_, v_, causal=causal, block_size=block,
-                window=window),
-            q, k, v,
-        )
-        return vjp(g)
     return _flash_backward(q, k, v, out, lse, g, causal=causal,
                            block_q=block_q, block_k=block_k,
                            interpret=interpret, window=window)

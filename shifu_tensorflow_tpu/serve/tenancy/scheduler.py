@@ -24,9 +24,8 @@ Long-run device rows are therefore shared proportionally to weight
 among backlogged tenants, with single-batch granularity — one tenant at
 sustained overload delays another's dispatch by at most the in-flight
 batch plus its own next quantum, which is the p99-isolation property
-``tests/test_tenancy.py`` and ``BENCH_SERVE_TENANTS.json`` pin.  When
-only one tenant has work it gets the whole device: work-conserving, no
-reserved idle shares.
+``tests/test_tenancy.py`` pins.  When only one tenant has work it gets
+the whole device: work-conserving, no reserved idle shares.
 
 Weights come from ``shifu.tpu.serve-tenant-weight-<model>`` (default 1);
 the quantum is rows per visit — small enough to interleave tenants

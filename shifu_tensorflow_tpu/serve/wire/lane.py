@@ -2,14 +2,14 @@
 
 With ``--serve-workers N``, each SO_REUSEPORT worker used to own a
 private DeviceScheduler: N workers fragment the device into N
-uncoordinated batchers, so the coalescing lever (the 9.25x from
-BENCH_SERVE) and the DRR tenant weights only ever saw 1/N of the
-traffic.  The shared lane re-centralizes DISPATCH without
-re-centralizing ingress: the lowest-index worker (the "lane owner",
-re-elected deterministically by the supervisor because a crashed worker
-restarts at its own index and scale-down always evicts the HIGHEST
-index) listens on a UNIX domain socket; every sibling keeps admitting,
-coalescing and scattering locally, but instead of dispatching its
+uncoordinated batchers, so the coalescing lever and the DRR tenant
+weights only ever saw 1/N of the traffic.  The shared lane
+re-centralizes DISPATCH without re-centralizing ingress: the
+lowest-index worker (the "lane owner", re-elected deterministically by
+the supervisor because a crashed worker restarts at its own index and
+scale-down always evicts the HIGHEST index) listens on a UNIX domain
+socket; every sibling keeps admitting, coalescing and scattering
+locally, but instead of dispatching its
 packed batch to its private scheduler it forwards the batch — tenant
 name + pre-padding row matrix, one frame — down the lane.  The owner
 admits the forwarded matrix into its OWN tenant batcher, where it

@@ -199,8 +199,7 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--obs", action="store_true", default=None,
                    help="enable the observability plane: per-epoch "
                         "infeed/host/dispatch/block step breakdown and "
-                        "lifecycle spans (<2%% step overhead, "
-                        "BENCH_OBS.json)")
+                        "lifecycle spans")
     p.add_argument("--obs-journal", default=None, dest="obs_journal",
                    help="event-journal base path (implies --obs); fleet "
                         "workers write <path>.w<i>; read with "
@@ -842,7 +841,7 @@ def run_single(args, conf, model_config: ModelConfig, schema: RecordSchema) -> i
         "final_valid_loss": history[-1].valid_loss if history else None,
         "final_ks": history[-1].ks if history else None,
         # which device actually trained — scripts wrapping the CLI (e.g.
-        # bench_e2e, chip_smoke) record it in their artifacts
+        # chip_smoke) record it in their artifacts
         "platform": devices[0].platform,
         "device_kind": devices[0].device_kind,
         "device_count": len(devices),

@@ -1,5 +1,5 @@
 """JAX backend-environment helpers: the CPU pin shared by the test
-conftest and the CPU-only bench scripts, and the one-process-per-chip
+conftest and the CPU-only drill scripts, and the one-process-per-chip
 check of the entry points that start local worker processes."""
 
 from __future__ import annotations

@@ -24,12 +24,6 @@ def _bench_env(**extra):
         "JAX_PLATFORMS": "cpu",
         "BENCH_BATCH": "512",
         "BENCH_SECONDS": "0.2",
-        "BENCH_STREAM_ROWS": "20000",
-        "BENCH_STREAM_SHARDS": "2",
-        "BENCH_SCAN_STEPS": "2",
-        "BENCH_DEVICE_EPOCH_ROWS": "10000",
-        "BENCH_DEVICE_EPOCH_EPOCHS": "2",
-        "BENCH_TPU_ATTEMPTS": "1",
         "BENCH_TOTAL_BUDGET_S": "400",
         "BENCH_TPU_TIMEOUT": "180",
     })

@@ -7,8 +7,7 @@ processes); the end-to-end leg runs a thread-launcher fleet with a real
 standby worker through an injected failure; the autoscaler policy is
 pure (injectable clock) and unit-tested for hysteresis, cooldown,
 rebalance-before-scale ordering, and empty-window neutrality.  The
-process-fleet kill drill lives in ``python bench.py elastic`` /
-tier1.yml (BENCH_ELASTIC.json gates).
+process-fleet kill drill is ``python scripts/bench_elastic.py``.
 """
 
 import json

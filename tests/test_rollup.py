@@ -787,7 +787,7 @@ def test_obs_diff_bench_needs_two_entries(tmp_path, capsys):
 
 def test_bench_history_append_helper(tmp_path, monkeypatch):
     """bench.py's history hook: one JSONL line with host fingerprint,
-    scalar metrics from the artifact, and the caller-supplied ts."""
+    scalar metrics from the result, and the caller-supplied ts."""
     import importlib.util
     import os
 
@@ -801,11 +801,10 @@ def test_bench_history_append_helper(tmp_path, monkeypatch):
         bench.os.path, "abspath",
         lambda p: str(tmp_path / "bench.py") if p.endswith("bench.py")
         else os.path.abspath(p))
-    with open(tmp_path / "BENCH_X.json", "w") as f:
-        json.dump({"value": 3.5, "acceptance_ok": True,
-                   "unit": "x", "nested": {"a": 1}}, f)
     monkeypatch.setenv("BENCH_TS", "2026-08-04T00:00:00")
-    bench._append_bench_history("x", "BENCH_X.json", rc=0)
+    bench._append_bench_history(
+        "x", rc=0, result={"value": 3.5, "acceptance_ok": True,
+                           "unit": "x", "nested": {"a": 1}})
     lines = (tmp_path / "BENCH_HISTORY.jsonl").read_text().splitlines()
     assert len(lines) == 1
     rec = json.loads(lines[0])
