@@ -1,0 +1,21 @@
+"""``loop_dense_mlp_ms`` — layer: models models/ ops/.  Unit ``ms``, source
+``device_trace``; should move ``train_rows_per_s``.
+
+Device ms a step inside ``mlp.dense``, forward + backward summed (the
+backward's recomputed forward included): each block's gated feed-forward
+of width 5,632 over every token, 24 applications a step.  From
+``obs.profile.phases`` on the run's own capture, handed on by the plane;
+``None`` on a reading without the phase or of another configuration's
+kind.
+"""
+
+LAYER = "models models/ ops/"
+UNIT = "ms"
+SOURCE = "device_trace"
+MOVES = "train_rows_per_s"
+
+from benchmark.looped_lm_readings import looped_phase_ms
+
+
+def read(r):
+    return looped_phase_ms(r, "mlp.dense")

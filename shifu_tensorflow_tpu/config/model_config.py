@@ -125,7 +125,7 @@ class RopeParameters:
 class HybridLMConfig:
     """``ModelType: hybrid_lm`` — the keys of a public ``config.json``
     under their own names (``train.params`` carries them beside
-    ``ModelType``), plus the share this chip holds.  Five public shapes
+    ``ModelType``), plus the share this chip holds.  Six public shapes
     are read:
 
     - ``nemotron_h``: ``hybrid_override_pattern``, one mixer a layer (``M``
@@ -185,7 +185,19 @@ class HybridLMConfig:
       that rests), gated ``silu`` experts and no shared expert, which the
       family has no keys for; the head tied to the embedding
       (``tie_word_embeddings`` / ``tie_embedding``) unless the
-      configuration says otherwise.
+      configuration says otherwise;
+    - ``ouro``: ``total_ut_steps`` marks the family (:meth:`looped_family`).
+      The whole stack of blocks runs ``total_ut_steps`` times a forward
+      pass with the same weights, the final norm after every pass (what
+      the next pass reads); every block ``full_attention`` over a dense
+      feed-forward of ``intermediate_size`` (no ``mlp_layer_types``), an
+      RMSNorm on each sub-layer's output before its residual add
+      (``post_norms``, set by the family: no public key), rotary from
+      ``rope_theta``.  After each pass the head and an exit gate read the
+      normed state, and the step's loss is the next-token loss expected
+      under the gates' exit distribution less ``exit_entropy_weight`` x
+      that distribution's entropy (no public key); ``early_exit_threshold``
+      is inference's and changes nothing in training.
 
     ``n_routed_experts`` is the router's width (all the experts there
     are); ``experts_held`` = (first id, count) the experts whose weights
@@ -261,6 +273,11 @@ class HybridLMConfig:
     #: the head reads the token embedding's table (logits = h Emb^T) and
     #: has no kernel of its own
     tie_word_embeddings: bool = False
+    # a looped stack: the layers run this many times a forward pass
+    total_ut_steps: int = 1
+    exit_entropy_weight: float = 0.1  # beta of the expected-exit loss
+    #: an RMSNorm on every mixer's output before its residual add
+    post_norms: bool = False
 
     #: public spellings of the same number
     ALIASES = (("num_experts", "n_routed_experts"),
@@ -278,6 +295,12 @@ class HybridLMConfig:
     @property
     def output_std(self) -> float:
         return self.output_initializer_range or self.initializer_range
+
+    @property
+    def looped(self) -> bool:
+        """The stack runs more than once a forward pass, read by an exit
+        gate after each pass."""
+        return self.total_ut_steps > 1
 
     def rope_for(self, kind: str) -> "RopeParameters | None":
         """The rotary parametrisation of a ``*`` or ``W`` layer."""
@@ -406,6 +429,52 @@ class HybridLMConfig:
         return said
 
     @staticmethod
+    def looped_family(params: Mapping[str, Any]) -> dict:
+        """What a configuration of the looped family (``total_ut_steps``
+        among its keys) says without the keys the other shapes say it
+        with: every block full attention over a dense gated feed-forward,
+        rotary from ``rope_theta``, and, unless the configuration says,
+        the norms on the sub-layers' outputs.  What the family's keys ask
+        for and this program does not implement is an error by name:
+        sliding windows, a threshold that is no probability, scaled rotary
+        (``total_ut_steps < 1`` is :meth:`validate`'s).
+        ``early_exit_threshold`` is inference's and read for its range
+        alone."""
+        threshold = float(params.get("early_exit_threshold", 1.0))
+        if not 0.0 < threshold <= 1.0:
+            raise ValueError(
+                f"early_exit_threshold={threshold} is not a probability in "
+                "(0, 1]")
+        if _parse_bool(params.get("use_sliding_window", False)):
+            raise ValueError(
+                "use_sliding_window=true is not implemented with "
+                "total_ut_steps (every block attends over all earlier keys)")
+        if params.get("rope_scaling") is not None:
+            raise ValueError(
+                f"rope_scaling={params['rope_scaling']!r} is not implemented "
+                "with total_ut_steps (plain rotary from rope_theta)")
+        kinds = [str(k) for k in params.get("layer_types") or (
+            ["full_attention"] * int(params.get("num_hidden_layers", 0)))]
+        if not kinds or set(kinds) != {"full_attention"}:
+            raise ValueError(
+                f"layer_types {sorted(set(kinds))} with total_ut_steps: the "
+                "looped family's blocks are full_attention")
+        said = {"layer_types": kinds,
+                "mlp_layer_types": ["dense"] * len(kinds),
+                "hidden_act": "silu"}
+        if "rope_parameters" not in params:
+            said["rope_parameters"] = {
+                "rope_theta": params.get("rope_theta", 10000.0)}
+        for key, value in said.items():
+            if params.get(key, value) != value:
+                raise ValueError(
+                    f"{key}={params[key]!r} but the looped family's blocks "
+                    f"give {value!r}")
+        if "post_norms" not in params:
+            said["post_norms"] = True
+        return said
+
+    @staticmethod
     def pattern_of(params: Mapping[str, Any]) -> str:
         """``layer_types`` + ``mlp_layer_types`` as a pattern string; with
         ``num_dense_layers`` in their place, the first that many blocks'
@@ -458,6 +527,8 @@ class HybridLMConfig:
             blocks = int(params["num_hidden_layers"])
         if CONV in params.get("layer_types", ()):
             params.update(cls.conv_family(params))
+        if "total_ut_steps" in params:
+            params.update(cls.looped_family(params))
         if "layer_types" in params:
             pattern = cls.pattern_of(params)
             if params.get("hybrid_override_pattern", pattern) != pattern:
@@ -602,6 +673,17 @@ class HybridLMConfig:
         if self.mtp_loss_weight < 0:
             raise ValueError(
                 f"mtp_loss_weight={self.mtp_loss_weight} is negative")
+        if self.total_ut_steps < 1:
+            raise ValueError(
+                f"total_ut_steps={self.total_ut_steps}: the stack runs at "
+                "least once")
+        if self.exit_entropy_weight < 0:
+            raise ValueError(
+                f"exit_entropy_weight={self.exit_entropy_weight} is negative")
+        if self.looped and self.num_nextn_predict_layers:
+            raise ValueError(
+                "a multi-token prediction module beside total_ut_steps > 1 "
+                "is not implemented")
         if self.mamba_num_heads % self.n_groups:
             raise ValueError("n_groups must divide mamba_num_heads")
         for heads in (self.num_attention_heads,

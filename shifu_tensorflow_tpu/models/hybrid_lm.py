@@ -74,6 +74,17 @@ the last block's kinds over ``[RMSNorm(h_i) ; RMSNorm(Emb(t_{i+1}))] W_m``,
 its own final norm and the shared head against ``t_{i+2}``; the step's
 loss is the next-token loss + ``mtp_loss_weight`` x the module's.
 
+Where ``total_ut_steps`` is T > 1 the stack is looped
+(:meth:`HybridLM.passes`): the same layers run T times a forward pass,
+``h_t = final_norm(layers(h_{t-1}))`` from ``h_0`` the embedding, and after
+every pass the head and an exit gate (:class:`ExitGate`, ``lambda_t =
+sigmoid(h_t w_g + b_g)``) read ``h_t``.  The exit distribution is ``p_t =
+lambda_t prod_{j<t} (1 - lambda_j)``, the last pass the remainder, and the
+step's loss is the mean over positions of ``sum_t p_t L_t -
+exit_entropy_weight x H(p)`` (:meth:`HybridLM.loop_losses`).  Where the
+configuration has ``post_norms`` a layer is ``x + RMSNorm_post(
+mixer(RMSNorm(x)))``.
+
 Ingest compatibility (as models/sequence.py): a PSV row carries its
 ``S`` token ids in the float32 feature block; the model casts them on
 device.  The loss is the family's own — mean next-token cross-entropy over
@@ -109,8 +120,11 @@ layer's projections onto its two latents and their norms), ``attn.expand``
 those, where there is the module, ``mtp.merge`` (two norms, the next
 token's embedding, ``W_m``), ``mtp.block`` and ``mtp.head`` (the first
 scope on an op's path names its phase, so the module's block and head
-pass are told from the main model's).  The residual stream's own norms
-and adds carry no scope.
+pass are told from the main model's); in a looped stack ``loop.norm``
+(the norms on the mixers' outputs and the final norm between passes) and
+``loop.exit`` (the gates, the exit distribution, the mixture of the passes'
+losses and its entropy; each pass's head stays ``lm.head``).  The residual
+stream's own norms and adds carry no scope.
 """
 
 from __future__ import annotations
@@ -670,6 +684,10 @@ class Layer(nn.Module):
                          else self.attention)
             y = AttentionMixer(self.cfg, attention, self.dtype, self.kind,
                                name="mixer")(h)
+        if self.cfg.post_norms:
+            with jax.named_scope("loop.norm"):
+                y = RMSNorm(self.cfg.layer_norm_epsilon, self.dtype,
+                            name="post_norm")(y)
         return x + y, stats
 
 
@@ -741,15 +759,66 @@ class LMHead(nn.Module):
             self.kernel = self.param("kernel", _normal(self.std),
                                      (self.hidden, self.vocab), jnp.float32)
 
+    def kernel_of(self, table=None):
+        """(hidden, vocab): what the scores multiply the states by."""
+        return table.T if self.tied else self.kernel
+
     def logits(self, h, table=None):
-        kernel = table.T if self.tied else self.kernel
-        return _matmul(h, kernel, self.dtype).astype(jnp.float32)
+        return _matmul(h, self.kernel_of(table), self.dtype).astype(
+            jnp.float32)
 
     def __call__(self, h, ids, live, table=None):
         with jax.named_scope("lm.head"):
-            logp = log_softmax(self.logits(h[:, :-1], table))
-            nll = -jnp.take_along_axis(logp, ids[:, 1:, None], axis=-1)[..., 0]
+            nll = next_token_nll(self.logits(h[:, :-1], table), ids)
             return jnp.sum(nll * live[:, None]), jnp.mean(nll, axis=-1)
+
+
+def next_token_nll(scores, ids):
+    """Each position's next-token loss (B, S - 1) from the scores of
+    positions 0 .. S - 2."""
+    logp = log_softmax(scores)
+    return -jnp.take_along_axis(logp, ids[:, 1:, None], axis=-1)[..., 0]
+
+
+def passes_nll(states, kernel, ids, dtype):
+    """Each pass's next-token losses (T, B, S - 1) from the passes' states
+    (T, B, S, d): :class:`LMHead`'s scores and loss, one pass after the
+    other (``lax.map``) and each rematerialised, so that one pass's scores
+    exist at a time, in the backward pass too (four passes of 4,096 x
+    49,152 scores side by side are 3.2 GB)."""
+    @jax.checkpoint
+    def one(h):
+        with jax.named_scope("lm.head"):
+            return next_token_nll(
+                _matmul(h[:, :-1], kernel, dtype).astype(jnp.float32), ids)
+
+    return jax.lax.map(one, states)
+
+
+class ExitGate(nn.Module):
+    """``h w_g + b_g`` (B, S): the logit of the exit gate ``lambda =
+    sigmoid(h w_g + b_g)`` at each position of a pass's normed state."""
+
+    std: float
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, h):
+        kernel = self.param("kernel", _normal(self.std), (h.shape[-1], 1),
+                            jnp.float32)
+        bias = self.param("bias", nn.initializers.zeros, (1,), jnp.float32)
+        return (_matmul(h, kernel, self.dtype)
+                + bias.astype(self.dtype))[..., 0]
+
+
+def exit_log_probs(z):
+    """``log p`` (T, ...) of the exit distribution from the gates' logits
+    ``z`` (T - 1, ...) of passes 1 .. T - 1: ``p_t = lambda_t prod_{j<t}
+    (1 - lambda_j)`` and the last pass the remainder, ``p_T = prod_{j<T}
+    (1 - lambda_j)``, each a sum of log-sigmoids."""
+    stayed = jnp.cumsum(jax.nn.log_sigmoid(-z), axis=0)
+    before = jnp.concatenate([jnp.zeros_like(z[:1]), stayed[:-1]])
+    return jnp.concatenate([jax.nn.log_sigmoid(z) + before, stayed[-1:]])
 
 
 def fold_stats(stats, s):
@@ -830,19 +899,88 @@ class HybridLM(nn.Module):
         if c.num_nextn_predict_layers:
             self.mtp = MTPModule(c, self.attention, self.dtype,
                                  self.window_attention)
+        if c.looped:
+            self.exit_gate = ExitGate(c.initializer_range, self.dtype)
 
-    def trunk(self, x):
-        """(the last block's output (B, S, d), ids (B, S), counters)."""
+    def embedded(self, x):
+        """(the token embeddings (B, S, d), ids (B, S), zero counters)."""
         ids = x.astype(jnp.int32)
         with jax.named_scope("embed.gather"):
             h = jnp.take(self.embed.embedding, ids, axis=0).astype(self.dtype)
-        stats = jnp.zeros((2,), jnp.int32)
+        return h, ids, jnp.zeros((2,), jnp.int32)
+
+    def stack(self, h, stats):
+        """One pass through every layer: (its output, counters)."""
         for layer in self.layers:
             h, s = layer(h)
             stats = fold_stats(stats, s)
+        return h, stats
+
+    def trunk(self, x):
+        """(the last block's output (B, S, d), ids (B, S), counters)."""
+        h, ids, stats = self.embedded(x)
+        h, stats = self.stack(h, stats)
         return h, ids, stats
 
+    def passes(self, x):
+        """(the final-normed state after each of the ``total_ut_steps``
+        passes of the stack, ids, counters): the same layers every pass,
+        each pass reading the one before's normed state."""
+        h, ids, stats = self.embedded(x)
+        states = []
+        for _ in range(self.cfg.total_ut_steps):
+            h, stats = self.stack(h, stats)
+            with jax.named_scope("loop.norm"):
+                h = self.final_norm(h)
+            states.append(h)
+        return states, ids, stats
+
+    def exits(self, x):
+        """(each pass's next-token losses (T, B, S - 1), the log of the exit
+        distribution over the passes at each position with a target (T, B,
+        S - 1), counters).  The gates read passes 1 .. T - 1: the last pass
+        takes the remainder."""
+        states, ids, stats = self.passes(x)
+        nll = passes_nll(jnp.stack(states),
+                         self.lm_head.kernel_of(self.head_table()), ids,
+                         self.dtype)
+        with jax.named_scope("loop.exit"):
+            z = jnp.stack([self.exit_gate(h[:, :-1]) for h in states[:-1]])
+            return nll, exit_log_probs(z), stats
+
+    def loop_losses(self, x, w):
+        """``(loss, per-row loss (B, 1), counters, exit counters)`` of a
+        looped stack: the mean over the live rows' positions of ``sum_t p_t
+        L_t - exit_entropy_weight x H(p)``, the gates' gradient through
+        ``p``.  The exit counters are the same means of the expected exit
+        step ``sum_t t p_t`` (``exit_steps_mean``), of ``H(p)``
+        (``exit_entropy``) and of the last pass's loss
+        (``loop_last_loss``)."""
+        nll, logp, stats = self.exits(x)
+        live = (w.reshape(-1) != 0.0).astype(jnp.float32)[:, None]
+        count = jnp.maximum(jnp.sum(live) * nll.shape[-1], 1.0)
+        with jax.named_scope("loop.exit"):
+            p = jnp.exp(logp)
+            entropy = -jnp.sum(p * logp, axis=0)
+            each = (jnp.sum(p * nll, axis=0)
+                    - self.cfg.exit_entropy_weight * entropy)
+            step = jnp.arange(1, nll.shape[0] + 1, dtype=p.dtype)
+            expected = jnp.sum(p * step[:, None, None], axis=0)
+
+            def mean(v):
+                return jnp.sum(v * live) / count
+
+            return (mean(each), jnp.mean(each, axis=-1, keepdims=True), stats,
+                    {"exit_steps_mean": mean(expected),
+                     "exit_entropy": mean(entropy),
+                     "loop_last_loss": mean(nll[-1])})
+
     def __call__(self, x):
+        if self.cfg.looped:  # the last pass's scores
+            states, _, _ = self.passes(x)
+            if self.is_initializing():
+                self.exit_gate(states[0])
+            return self.lm_head.logits(states[-1], self.head_table())
         h, ids, _ = self.trunk(x)
         if self.cfg.num_nextn_predict_layers and self.is_initializing():
             self.mtp_hidden(h, ids)  # ``init`` builds its parameters too
@@ -904,8 +1042,15 @@ def batch_loss(model: HybridLM):
     expert) and take the largest (pairs on one held expert) over the
     step's expert layers, the multi-token prediction module's included;
     with that module the two losses the step's loss is made of ride
-    beside them (``main_loss``, ``mtp_loss``)."""
+    beside them (``main_loss``, ``mtp_loss``); with a looped stack its
+    exit counters do (``HybridLM.loop_losses``)."""
     def fn(params, batch):
+        if model.cfg.looped:
+            loss, per_row, stats, extra = model.apply(
+                {"params": params}, batch["x"], batch["w"],
+                method="loop_losses")
+            return loss, per_row, {**dict(zip(COUNTER_NAMES, stats)),
+                                   **extra}
         main, ahead, per_row, stats = model.apply(
             {"params": params}, batch["x"], batch["w"], method="losses")
         counters = dict(zip(COUNTER_NAMES, stats))

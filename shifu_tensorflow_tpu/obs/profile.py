@@ -194,7 +194,7 @@ HYBRID_LM_SCOPES = ("embed.gather", "ssm.proj", "ssm.conv", "ssm.scan",
                     "attn.proj", "attn.rope", "attn.core", "attn.window",
                     "attn.latent", "attn.expand", "mtp.merge", "mtp.block",
                     "mtp.head", "conv.proj", "conv.mix", "attn.qknorm",
-                    "lm.head", "optimizer.update")
+                    "lm.head", "loop.exit", "loop.norm", "optimizer.update")
 PHASE_SCOPES = TABULAR_SCOPES + tuple(
     s for s in HYBRID_LM_SCOPES if s not in TABULAR_SCOPES)
 #: the per-step program's module name (``jax.jit`` of ``train_step``);

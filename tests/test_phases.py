@@ -226,10 +226,42 @@ def _conv_lm_step_text() -> str:
     return _STEP_TEXTS["conv_lm"]
 
 
+#: the scopes only a looped decoder has, and every scope its step has
+LOOP_ONLY = ("loop.exit", "loop.norm")
+LOOP_SCOPES = ("embed.gather", "mlp.dense", "attn.proj", "attn.rope",
+               "attn.core", *LOOP_ONLY, "lm.head", "optimizer.update")
+
+
+def _loop_lm_step_text() -> str:
+    """The compiled per-step program of a tiny ``hybrid_lm`` trainer under
+    the ``ouro`` keys: two blocks of attention over a dense feed-forward
+    with sandwich norms, run three times, each pass read by the exit gate
+    and the head."""
+    if "loop_lm" not in _STEP_TEXTS:
+        from shifu_tensorflow_tpu.config.model_config import ModelConfig
+        from shifu_tensorflow_tpu.train.trainer import HealthConfig, Trainer
+
+        mc = ModelConfig.from_json({"train": {"params": {
+            "ModelType": "hybrid_lm", "Optimizer": "adam",
+            "LearningRate": 1e-3, "hidden_size": 32, "vocab_size": 64,
+            "num_hidden_layers": 2, "intermediate_size": 48,
+            "num_attention_heads": 2, "num_key_value_heads": 2,
+            "head_dim": 16, "rope_theta": 100.0, "rms_norm_eps": 1e-6,
+            "total_ut_steps": 3, "early_exit_threshold": 1}}})
+        trainer = Trainer(mc, 16, health=HealthConfig())
+        batch = {"x": np.ones((2, 16), np.float32),
+                 "y": np.ones((2, 1), np.float32),
+                 "w": np.ones((2, 1), np.float32)}
+        _STEP_TEXTS["loop_lm"] = trainer._path.step.lower(
+            trainer.state, batch).compile().as_text()
+    return _STEP_TEXTS["loop_lm"]
+
+
 @pytest.mark.parametrize("scope", HYBRID_LM_SCOPES)
 def test_compiled_lm_step_carries_every_scope(scope):
     text = (_latent_lm_step_text() if scope in LATENT_ONLY
             else _conv_lm_step_text() if scope in CONV_ONLY
+            else _loop_lm_step_text() if scope in LOOP_ONLY
             else _lm_step_text())
     assert re.search(r"^HloModule (\w+)", text, re.M).group(1) == \
         profile_mod.STEP_PROGRAM
@@ -261,6 +293,21 @@ def test_compiled_conv_lm_step_carries_its_scopes_both_ways(scope):
         assert {scope + ".fwd", scope + ".bwd"} <= phases, phases
     assert not {p for p in phases if p.startswith((
         "ssm.", "attn.window", "attn.latent", "mtp.", "moe.shared"))}
+
+
+@pytest.mark.parametrize("scope", LOOP_SCOPES)
+def test_compiled_loop_lm_step_carries_its_scopes_both_ways(scope):
+    """The lowered Ouro-shaped step: every scope of its blocks, of the
+    exits and of the norms between and after the sub-layers, forward and
+    backward, and none of another mixer's; the four head passes stay
+    ``lm.head``."""
+    phases = {phase_of(n) for n in _op_names(_loop_lm_step_text())}
+    if scope == "optimizer.update":
+        assert scope in phases
+    else:
+        assert {scope + ".fwd", scope + ".bwd"} <= phases, phases
+    assert not {p for p in phases if p.startswith((
+        "ssm.", "attn.window", "attn.latent", "mtp.", "moe.", "conv."))}
 
 
 def test_phase_scopes_are_both_families_and_each_name_once():
